@@ -51,7 +51,7 @@ def _bench_backend(name: str, quick: bool) -> BenchResult:
     lines = []
     for l in sizes:
         V = l // code.m
-        G = jnp.asarray(rng.standard_normal((code.d, V, code.m)), jnp.float32)
+        G = jnp.asarray(rng.standard_normal((code.d, code.m, V)), jnp.float32)
         C = jnp.asarray(code.C[0], jnp.float32)
         F = jnp.asarray(rng.standard_normal((code.n, V)), jnp.float32)
         W = jnp.asarray(code.decode_weights(range(1, 16)), jnp.float32)
@@ -137,10 +137,17 @@ def _bench_solve(quick: bool) -> BenchResult:
     )
 
 
+def _kernel_backend() -> str:
+    """The Pallas kernels: compiled on a TPU, interpreted elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+
+
 def bench_results(quick: bool = False,
-                  backends: tuple[str, ...] = ("ref", "pallas")) -> list[BenchResult]:
+                  backends: tuple[str, ...] | None = None) -> list[BenchResult]:
     if quick:
         backends = ("ref",)
+    elif backends is None:
+        backends = ("ref", _kernel_backend())
     out = [_bench_backend(name, quick) for name in backends]
     out.append(_bench_fused_decode(quick))
     out.append(_bench_solve(quick))
@@ -155,7 +162,7 @@ register(BenchSpec(
 ))
 
 
-def run(backends: tuple[str, ...] = ("ref", "pallas")) -> list[str]:
+def run(backends: tuple[str, ...] | None = None) -> list[str]:
     out: list[str] = []
     for r in bench_results(False, backends=backends):
         out.extend(r.extra["lines"])
@@ -165,8 +172,8 @@ def run(backends: tuple[str, ...] = ("ref", "pallas")) -> list[str]:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="both",
-                    choices=["ref", "pallas", "both"])
+                    choices=["ref", "pallas", "interpret", "both"])
     args = ap.parse_args()
-    names = ("ref", "pallas") if args.backend == "both" else (args.backend,)
+    names = None if args.backend == "both" else (args.backend,)
     for line in run(names):
         print(line)

@@ -20,7 +20,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 
 from repro import coding  # noqa: E402
-from repro.compat import NATIVE_SHARD_MAP  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.core import make_code, make_hetero_code  # noqa: E402
 from repro.data import synthetic_lm_stream  # noqa: E402
@@ -38,9 +37,7 @@ def main() -> None:
     #    master (here: every chip, SPMD) tolerates any 1 straggler.
 
     cfg = get_config("qwen3-1.7b").reduced()   # 2-layer, d_model=256 smoke model
-    # old-jax shard_map cannot lower the model's scan-over-layers with a >1
-    # GSPMD-auto model axis; collapse it there so the demo runs everywhere
-    mesh = make_local_mesh(n_data=4, n_model=2 if NATIVE_SHARD_MAP else 1)
+    mesh = make_local_mesh(n_data=4, n_model=2)
     spec = coding.SchemeSpec(schedule="gather")   # paper-faithful decode
     trainer = Trainer(cfg, code, mesh,
                       optimizer=get_optimizer("adamw", 3e-3), spec=spec,

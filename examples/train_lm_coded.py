@@ -37,7 +37,6 @@ def main() -> None:
                           f"--xla_force_host_platform_device_count={ndev}")
 
     from repro import coding
-    from repro.compat import NATIVE_SHARD_MAP
     from repro.configs import get_config
     from repro.core import make_code
     from repro.data import synthetic_lm_stream
@@ -57,10 +56,6 @@ def main() -> None:
             base.reduced(), name="coded-lm-demo", n_layers=4, d_model=256,
             vocab=2048)
 
-    if not NATIVE_SHARD_MAP and args.n_model > 1:
-        print(f"note: this jax cannot lower model scans under a >1 model "
-              f"axis inside shard_map; using --n-model 1 (was {args.n_model})")
-        args.n_model = 1
     code = make_code(args.n_data, args.d, args.s, args.m)
     mesh = make_local_mesh(args.n_data, args.n_model)
     trainer = Trainer(cfg, code, mesh, get_optimizer("adamw", 3e-4),

@@ -1,8 +1,8 @@
 """Environment capture for benchmark records.
 
 Everything that makes two measurements comparable (or not): interpreter and
-library versions, the JAX backend and device inventory, the compat-layer mode
-(native vs experimental shard_map), and the XLA flags in effect.  Keys are
+library versions, the JAX backend and device inventory, and the XLA flags in
+effect.  Keys are
 stable so JSON diffs stay readable.
 """
 
@@ -17,8 +17,6 @@ def capture_env(mesh: Any | None = None) -> dict[str, Any]:
     """Snapshot the software/hardware context of a benchmark run."""
     import jax
 
-    from repro.compat import NATIVE_SHARD_MAP
-
     devices = jax.devices()
     env: dict[str, Any] = {
         "python": platform.python_version(),
@@ -29,7 +27,6 @@ def capture_env(mesh: Any | None = None) -> dict[str, Any]:
         "backend": jax.default_backend(),
         "device_count": len(devices),
         "device_kind": devices[0].device_kind if devices else "none",
-        "native_shard_map": NATIVE_SHARD_MAP,
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
     }
     if mesh is not None:

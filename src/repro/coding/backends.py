@@ -3,17 +3,21 @@ interchangeable implementations.
 
 Canonical shapes (the leaf <-> canonical reshaping lives in ``codec.py``):
 
-  encode: G (d, V, m[, R]) x C (d, m)  ->  (V[, R])      (paper eq. 17/18)
-  decode: F (n, V[, R])   x W (n, m)   ->  (V, m[, R])   (paper eq. 19-21)
+  encode: G (d, m, V[, R]) x C (d, m)  ->  (V[, R])      (paper eq. 17/18)
+  decode: F (n, V[, R])   x W (n, m)   ->  (m, V[, R])   (paper eq. 19-21)
+
+The group axis m always leads: it is never the TPU's lane axis.
 
 Backends:
-  ``ref``    — pure jnp einsum/tensordot; runs anywhere, XLA-fused.
-  ``pallas`` — the TPU Mosaic kernels in ``repro.kernels``; on non-TPU hosts
-               the same kernels execute in Pallas interpret mode (bit-exact
-               semantics, slow — meant for tests and small problems).
+  ``ref``       — pure jnp multiply-and-sum (``repro.kernels.ref``); runs
+                  anywhere, XLA-fused.
+  ``pallas``    — the TPU Mosaic kernels in ``repro.kernels``, compiled;
+                  needs an attached TPU.
+  ``interpret`` — the same kernels in Pallas interpret mode (same f32
+                  sequence, slow — tests and kernel debugging off-TPU).
 
 ``resolve_backend`` implements the dispatch policy: ``auto`` -> pallas on TPU,
-ref elsewhere; explicit names force a backend.
+ref elsewhere; explicit names force a backend and never fall back.
 """
 from __future__ import annotations
 
@@ -28,6 +32,7 @@ import importlib
 
 _encode_mod = importlib.import_module("repro.kernels.coded_encode")
 _decode_mod = importlib.import_module("repro.kernels.coded_decode")
+_ref_mod = importlib.import_module("repro.kernels.ref")
 
 BACKEND_NAMES = ("auto", "ref", "pallas", "interpret")
 
@@ -38,11 +43,11 @@ class CodecBackend:
     name: str = "abstract"
 
     def encode(self, G: jax.Array, C: jax.Array, *, out_dtype=None) -> jax.Array:
-        """Encode contraction: G (d, V, m[, R]) x C (d, m) -> (V[, R])."""
+        """Encode contraction: G (d, m, V[, R]) x C (d, m) -> (V[, R])."""
         raise NotImplementedError
 
     def decode(self, F: jax.Array, W: jax.Array, *, out_dtype=None) -> jax.Array:
-        """Decode contraction: F (n, V[, R]) x W (n, m) -> (V, m[, R])."""
+        """Decode contraction: F (n, V[, R]) x W (n, m) -> (m, V[, R])."""
         raise NotImplementedError
 
     def encode_acc(self, acc: jax.Array, G: jax.Array,
@@ -62,7 +67,7 @@ class CodecBackend:
         """Fused decode + SGD-momentum apply over one packed bucket.
 
         F (n, L) x W (n, m) -> g = scale * decode; then
-        ``mu' = momentum * MU + g``, ``p' = P - lr * mu'`` on the (L, m)
+        ``mu' = momentum * MU + g``, ``p' = P - lr * mu'`` on the (m, L)
         f32 bucket-layout views.  Returns ``(p', mu', sum(g*g))`` — the
         gradient-norm partial rides along so the step never rebuilds g.
         """
@@ -71,30 +76,27 @@ class CodecBackend:
 
 @dataclasses.dataclass(frozen=True)
 class RefBackend(CodecBackend):
-    """Pure-jnp einsum reference backend: runs anywhere, XLA-fused, and
-    serves as the numerical oracle for the Pallas kernels."""
+    """Pure-jnp reference backend (``repro.kernels.ref``): runs anywhere,
+    XLA-fused, and serves as the numerical oracle for the Pallas kernels."""
     name: str = "ref"
 
     def encode(self, G, C, *, out_dtype=None):
-        """Encode via einsum, f32 accumulation, cast to ``out_dtype``."""
-        out_dtype = out_dtype or G.dtype
-        sub = "jvur,ju->vr" if G.ndim == 4 else "jvu,ju->v"
-        return jnp.einsum(sub, G.astype(jnp.float32),
-                          C.astype(jnp.float32)).astype(out_dtype)
+        """Encode via multiply-and-sum, f32 accumulation, cast to
+        ``out_dtype``."""
+        return _ref_mod.coded_encode_ref(G, C, out_dtype)
 
     def decode(self, F, W, *, out_dtype=None):
-        """Decode via einsum, f32 accumulation, cast to ``out_dtype``."""
-        out_dtype = out_dtype or F.dtype
-        sub = "nvr,nu->vur" if F.ndim == 3 else "nv,nu->vu"
-        return jnp.einsum(sub, F.astype(jnp.float32),
-                          W.astype(jnp.float32)).astype(out_dtype)
+        """Decode via multiply-and-sum, f32 accumulation, cast to
+        ``out_dtype``."""
+        return _ref_mod.coded_decode_ref(F, W, out_dtype)
 
     def encode_acc(self, acc, G, C):
         """``acc + encode(G, C)`` — XLA fuses the add into the contraction."""
         return acc + self.encode(G, C, out_dtype=jnp.float32)
 
     def decode_apply(self, F, W, P, MU, *, lr, momentum, scale):
-        """Decode einsum + elementwise SGD-momentum apply (see interface)."""
+        """Reference decode + elementwise SGD-momentum apply (see
+        interface)."""
         g = self.decode(F, W, out_dtype=jnp.float32) * scale
         mu = momentum * MU + g
         return P - lr * mu, mu, jnp.sum(g * g)
@@ -103,8 +105,8 @@ class RefBackend(CodecBackend):
 @dataclasses.dataclass(frozen=True)
 class PallasBackend(CodecBackend):
     """The TPU Mosaic kernels in ``repro.kernels``; ``interpret=True`` runs
-    the same kernels in Pallas interpret mode (bit-exact, slow — tests and
-    non-TPU hosts)."""
+    the same kernels in Pallas interpret mode (same f32 sequence, slow —
+    tests and kernel debugging)."""
     name: str = "pallas"
     interpret: bool = False
 
@@ -126,10 +128,9 @@ class PallasBackend(CodecBackend):
 
     def decode_apply(self, F, W, P, MU, *, lr, momentum, scale):
         """Fuse via the ``coded_decode_apply`` Pallas kernel."""
-        pn, mun, ss = _decode_mod.coded_decode_apply(
+        return _decode_mod.coded_decode_apply(
             F, W, P, MU, lr=lr, momentum=momentum, scale=scale,
             interpret=self.interpret)
-        return pn, mun, ss[0, 0]
 
 
 def _on_tpu() -> bool:
@@ -138,8 +139,8 @@ def _on_tpu() -> bool:
 
 def resolve_backend(backend: str | CodecBackend | None) -> CodecBackend:
     """Dispatch policy.  ``auto``: pallas on TPU, ref elsewhere.  ``pallas``:
-    the kernels, in interpret mode when no TPU is attached.  ``interpret``:
-    force interpret mode even on TPU (kernel debugging)."""
+    the compiled kernels — raises when no TPU is attached.  ``interpret``:
+    the kernels in interpret mode (tests and kernel debugging)."""
     if isinstance(backend, CodecBackend):
         return backend
     name = backend or "auto"
@@ -148,7 +149,13 @@ def resolve_backend(backend: str | CodecBackend | None) -> CodecBackend:
     if name == "ref":
         return RefBackend()
     if name == "pallas":
-        return PallasBackend(interpret=not _on_tpu())
+        if not _on_tpu():
+            raise RuntimeError(
+                f"backend='pallas' compiles the Mosaic kernels and needs a "
+                f"TPU, but JAX's default backend is "
+                f"{jax.default_backend()!r}; use 'interpret' to run the "
+                f"kernels in interpret mode or 'ref' for the einsum path")
+        return PallasBackend()
     if name == "interpret":
         return PallasBackend(interpret=True)
     raise ValueError(f"unknown codec backend {backend!r}; "

@@ -47,14 +47,14 @@ def encode_leaf(g: jax.Array, coef: jax.Array, plan: LeafPlan,
     """Fold one subset's gradient leaf into the l/m-sized encoding.
 
     g: (..., Dg, ...);  coef: (m,)  ->  (Dg/m, *rest) contribution.
-    The fold is the d=1 slice of the canonical (d, V, m[, R]) contraction, so
+    The fold is the d=1 slice of the canonical (d, m, V[, R]) contraction, so
     both backends serve it.
     """
     assert plan.coded
     m = coef.shape[0]
-    x = leaf_to_groups(g, plan, m)                  # (V, m, *rest)
+    x = leaf_to_groups(g, plan, m)                  # (m, V, *rest)
     rest = x.shape[2:]
-    G = flatten_rest(x, 2)[None]                    # (1, V, m[, R])
+    G = flatten_rest(x, 2)[None]                    # (1, m, V[, R])
     out = backend.encode(G, coef.reshape(1, m), out_dtype=g.dtype)
     return unflatten_rest(out, 1, rest)             # (V, *rest)
 
@@ -145,9 +145,9 @@ class Codec:
         the per-leaf path's ``encoding_zero`` carry); returns the updated
         flat buffer."""
         m = coef.shape[0]
-        x = leaf_to_groups(g, slot.plan, m)             # (V, m, *rest)
+        x = leaf_to_groups(g, slot.plan, m)             # (m, V, *rest)
         rest = x.shape[2:]
-        G = flatten_rest(x, 2)[None]                    # (1, V, m[, R])
+        G = flatten_rest(x, 2)[None]                    # (1, m, V[, R])
         acc = jax.lax.slice_in_dim(buf, slot.offset, slot.offset + slot.size)
         if rest:
             acc = acc.reshape(slot.enc_shape[0], math.prod(rest))
@@ -176,14 +176,14 @@ class Codec:
                 for b in pplan.buckets]
 
     def unpack(self, decoded_bufs, pplan: PackPlan) -> dict[int, jax.Array]:
-        """Per-bucket (L, m) decoded buffers -> {leaf_index: gradient leaf}."""
+        """Per-bucket (m, L) decoded buffers -> {leaf_index: gradient leaf}."""
         out: dict[int, jax.Array] = {}
         for dec, b in zip(decoded_bufs, pplan.buckets):
             out.update(unpack_bucket(dec, b))
         return out
 
     def pack_params(self, flat_leaves, pplan: PackPlan) -> list[jax.Array]:
-        """Param/momentum leaves -> one (L, m) f32 bucket-layout view per
+        """Param/momentum leaves -> one (m, L) f32 bucket-layout view per
         bucket, row-aligned with the decoded gradient buffers (the fused
         decode-plus-apply operands; see ``packing.pack_param_groups``)."""
         return [pack_param_groups(flat_leaves, b, self.code.m)
@@ -191,7 +191,7 @@ class Codec:
 
     def unpack_params(self, bufs, pplan: PackPlan,
                       flat_like) -> dict[int, jax.Array]:
-        """Updated (L, m) buffers -> {leaf_index: leaf}, cast back to each
+        """Updated (m, L) buffers -> {leaf_index: leaf}, cast back to each
         leaf's dtype (``flat_like`` supplies the originals)."""
         out: dict[int, jax.Array] = {}
         for buf, b in zip(bufs, pplan.buckets):
@@ -216,34 +216,27 @@ class Codec:
         return self.code.decode_weights(responders)
 
     def decode_leaf(self, f_leaf: jax.Array, W: jax.Array, plan: LeafPlan,
-                    axis_names, *, W_row: jax.Array | None = None,
-                    emulate: bool = False) -> jax.Array:
+                    axis_names) -> jax.Array:
         """Decode one coded leaf via the bound schedule's choreography
-        (``emulate=True`` selects the collective-free psum fallback for
-        degraded runtimes; see ``Schedule.decode_leaf``)."""
+        (see ``Schedule.decode_leaf``)."""
         return self.schedule.decode_leaf(f_leaf, W, plan, axis_names,
-                                         self.code.n, self.backend,
-                                         W_row=W_row, emulate=emulate)
+                                         self.code.n, self.backend)
 
-    def decode_packed(self, buf: jax.Array, W: jax.Array, axis_names, *,
-                      W_row: jax.Array | None = None,
-                      emulate: bool = False) -> jax.Array:
-        """One bucket's collective + fused contraction: (L,) -> (L, m) f32."""
+    def decode_packed(self, buf: jax.Array, W: jax.Array,
+                      axis_names) -> jax.Array:
+        """One bucket's collective + fused contraction: (L,) -> (m, L) f32."""
         return self.schedule.decode_packed(buf, W, axis_names, self.code.n,
-                                           self.backend, W_row=W_row,
-                                           emulate=emulate)
+                                           self.backend)
 
     def decode_apply_packed(self, buf: jax.Array, W: jax.Array, P: jax.Array,
                             MU: jax.Array, axis_names, *, lr: float,
-                            momentum: float, scale: float,
-                            W_row: jax.Array | None = None,
-                            emulate: bool = False):
+                            momentum: float, scale: float):
         """One bucket's collective + fused decode-and-SGD-momentum apply on
-        its (L, m) param/momentum views: returns (p', mu', sum(g*g)).  See
+        its (m, L) param/momentum views: returns (p', mu', sum(g*g)).  See
         ``Schedule.decode_apply_packed``."""
         return self.schedule.decode_apply_packed(
             buf, W, P, MU, axis_names, self.code.n, self.backend, lr=lr,
-            momentum=momentum, scale=scale, W_row=W_row, emulate=emulate)
+            momentum=momentum, scale=scale)
 
 
 def make_codec(code: GradCode, *, schedule: str | Schedule = "gather",

@@ -268,32 +268,32 @@ def psum_fallback(flat_leaves: Sequence[jax.Array], flat_plans,
 def pack_param_groups(flat_leaves: Sequence[jax.Array],
                       bucket: WireBucket, m: int) -> jax.Array:
     """Lay the bucket's *parameter* (or optimizer-state) leaves out in the
-    decoded-buffer layout: an ``(bucket.size, m)`` f32 view whose rows
+    decoded-buffer layout: an ``(m, bucket.size)`` f32 view whose columns
     ``[slot.offset, slot.offset + slot.size)`` hold leaf ``slot.leaf_index``
     exactly where ``unpack_bucket`` reads that leaf's decoded gradient.
 
     This is the fused decode-plus-apply path's input: with params and
     momentum in this layout, the per-bucket kernel can run the optimizer
-    update right after the decode contraction without unpacking.  Rows in
-    the alignment gaps and the tail are zeros (their decoded gradient is
+    update right after the decode contraction without unpacking.  Columns
+    in the alignment gaps and the tail are zeros (their decoded gradient is
     zero too, so the update fixes them at zero)."""
     parts: list[jax.Array] = []
     pos = 0
     for s in bucket.slots:
         if s.offset > pos:
-            parts.append(jnp.zeros((s.offset - pos, m), jnp.float32))
+            parts.append(jnp.zeros((m, s.offset - pos), jnp.float32))
         x = leaf_to_groups(
             flat_leaves[s.leaf_index].astype(jnp.float32), s.plan, m)
-        parts.append(jnp.moveaxis(x, 1, -1).reshape(s.size, m))
+        parts.append(x.reshape(m, s.size))
         pos = s.offset + s.size
     if bucket.size > pos:
-        parts.append(jnp.zeros((bucket.size - pos, m), jnp.float32))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        parts.append(jnp.zeros((m, bucket.size - pos), jnp.float32))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def unpack_param_groups(buf: jax.Array, bucket: WireBucket,
                         flat_like: Sequence[Any]) -> dict[int, jax.Array]:
-    """Invert ``pack_param_groups``: slice the updated ``(bucket.size, m)``
+    """Invert ``pack_param_groups``: slice the updated ``(m, bucket.size)``
     buffer back into leaf layouts, cast to each leaf's original dtype
     (``flat_like`` supplies the dtypes).  Returns {leaf_index: leaf}."""
     out = unpack_bucket(buf, bucket)
@@ -301,16 +301,14 @@ def unpack_param_groups(buf: jax.Array, bucket: WireBucket,
 
 
 def unpack_bucket(decoded: jax.Array, bucket: WireBucket) -> dict[int, jax.Array]:
-    """Invert the packing on the decoded ``(bucket.size, m)`` buffer: static
+    """Invert the packing on the decoded ``(m, bucket.size)`` buffer: static
     slices from the slot table, reshaped back through ``groups_to_leaf`` into
     each leaf's original layout.  Returns {leaf_index: gradient leaf}."""
-    m = decoded.shape[1]
+    m = decoded.shape[0]
     out: dict[int, jax.Array] = {}
     for s in bucket.slots:
         seg = jax.lax.slice_in_dim(decoded, s.offset, s.offset + s.size,
-                                   axis=0)                    # (size, m)
-        V, rest = s.enc_shape[0], s.enc_shape[1:]
-        x = seg.reshape(V, *rest, m)
-        x = jnp.moveaxis(x, -1, 1)                            # (V, m, *rest)
+                                   axis=1)                    # (m, size)
+        x = seg.reshape(m, *s.enc_shape)                      # (m, V, *rest)
         out[s.leaf_index] = groups_to_leaf(x, s.plan)
     return out

@@ -63,6 +63,23 @@ class ModelConfig:
         if self.family == "moe" and (self.n_experts < 1 or self.top_k < 1):
             raise ValueError(f"{self.name}: moe needs n_experts/top_k")
 
+    def cut(self, n_layers: int, vocab_share: int = 1) -> "ModelConfig":
+        """This chip's share of the published model: every width as
+        published, depth cut to ``n_layers`` and the vocabulary to one
+        ``1/vocab_share`` slice of its rows (the slices of a vocabulary
+        split over ``vocab_share`` chips; the data draws its ids from the
+        slice).  The cut is named, e.g. ``qwen3-1.7b-4l-vocab18992``."""
+        if not 1 <= n_layers <= self.n_layers:
+            raise ValueError(f"{self.name}: n_layers={n_layers} is outside "
+                             f"1..{self.n_layers}")
+        if vocab_share < 1 or self.vocab % vocab_share:
+            raise ValueError(f"{self.name}: vocab {self.vocab} does not "
+                             f"split into {vocab_share} equal slices")
+        vocab = self.vocab // vocab_share
+        return dataclasses.replace(
+            self, n_layers=n_layers, vocab=vocab,
+            name=f"{self.name}-{n_layers}l-vocab{vocab}")
+
     def reduced(self) -> "ModelConfig":
         """Family-preserving smoke-test variant (2 layers, d_model <= 512,
         <= 4 experts) that runs a real fwd/train step on CPU."""

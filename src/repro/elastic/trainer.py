@@ -44,7 +44,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.core import make_code
 from repro.core.hetero import HeteroCode, plan_hetero
 from repro.train import Trainer
@@ -318,7 +317,7 @@ class ElasticTrainer(Trainer):
         self.mesh = mesh
         self._arts_cache = arts_cache
         self._jitted = jitted
-        with set_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             self.params = jax.tree.map(jnp.asarray, state["params"])
             self.opt_state = jax.tree.map(jnp.asarray, state["opt_state"])
         schedule = plan.schedule if plan is not None else self.schedule

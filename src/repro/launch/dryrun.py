@@ -23,7 +23,8 @@ import pathlib       # noqa: E402
 import time          # noqa: E402
 import traceback     # noqa: E402
 
-from repro.compat import set_mesh                   # noqa: E402
+import jax           # noqa: E402
+
 from repro.configs import ARCHS                     # noqa: E402
 from repro.launch import lowering                   # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
@@ -119,7 +120,7 @@ def run_one(arch: str, shape: str, mesh_name: str, schedule: str,
     except lowering.SkipLowering as e:
         rec.update(status="skipped", reason=str(e))
         return rec
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

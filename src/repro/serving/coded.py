@@ -54,7 +54,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import coding
-from repro.compat import collectives_ok, shard_map
 from repro.core import GradCode
 from repro.models import api as model_api
 from repro.train import sharding
@@ -147,7 +146,7 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
     ms = mesh.shape["model"]
     partial = spec.partial
     codec = spec.make_codec(code)
-    degraded = not collectives_ok(mesh, data_axes)
+    manual = sharding.manual_axes(mesh, data_axes, codec.backend)
     forward_fn = model_api.make_forward(cfg, window=window)
 
     k = getattr(code, "num_subsets", n)
@@ -163,7 +162,7 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
     out_abs = jax.eval_shape(forward_fn, pshapes, sub_shapes)
     out_shape = tuple(out_abs.shape[1:])
     s_out = b * int(np.prod(out_shape, dtype=np.int64))
-    q = -(-s_out // m)                       # ceil: rows of m per subset
+    q = -(-s_out // m)                       # ceil: m blocks of q per subset
     align = math.lcm(coding.WIRE_ALIGN, n)   # a2a slices the wire n ways
     L = -(-(k * q) // align) * align
 
@@ -177,10 +176,9 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
         dynamic-update anyway; d is small by design)."""
         return [f(jax.tree.map(lambda x: x[i], lb), i) for i in range(d)]
 
-    def body(params, batch, W, mask, rho, Csh, Wsh, blksh, vsh, ef=None):
+    def body(params, batch, W, mask, rho, Csh, blksh, vsh, ef=None):
         lb = jax.tree.map(lambda x: x[0], batch)   # (d, b, ...)
         Ci = Csh[0]          # (d, m)
-        W_row = Wsh[0]       # (m,)
         rho_i = rho[0]       # (d,)
         mask_i = mask[0]     # ()
         blk_i = blksh[0]     # (d,) subset id per slot
@@ -189,7 +187,7 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
         def enc_slot(sub, slot):
             y = forward_fn(params, sub).astype(jnp.float32)       # (b, *out)
             flat = y.reshape(-1)
-            G = jnp.pad(flat, (0, q * m - s_out)).reshape(1, q, m)
+            G = jnp.pad(flat, (0, q * m - s_out)).reshape(1, m, q)
             enc = codec.backend.encode(G, Ci[slot][None],
                                        out_dtype=jnp.float32)     # (q,)
             ss = rho_i[slot] * jnp.sum(flat * flat)
@@ -205,16 +203,16 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
             buf = jax.lax.dynamic_update_slice(buf, cur + enc, (off,))
             ss_acc = ss_acc + ss
         wire = codec.to_wire(buf, mask_i)
-        dec = codec.decode_packed(wire, W, data_axes, W_row=W_row,
-                                  emulate=degraded)               # (L, m)
-        flat = dec[:k * q].reshape(k, q * m)[:, :s_out]
+        dec = codec.decode_packed(wire, W, data_axes)             # (m, L)
+        flat = dec[:, :k * q].reshape(m, k, q).transpose(1, 0, 2)
+        flat = flat.reshape(k, m * q)[:, :s_out]
         out = flat.reshape(k * b, *out_shape)
         if partial:
             bound = ef * jnp.sqrt(jax.lax.psum(ss_acc, data_axes))
             return out, bound
         return out
 
-    def body_psum(params, batch, W, mask, rho, Csh, Wsh, blksh, vsh,
+    def body_psum(params, batch, W, mask, rho, Csh, blksh, vsh,
                   ef=None):
         # replicated baseline: live holders contribute raw outputs, the rho
         # equal-split makes duplicated subsets average exactly (matching the
@@ -245,21 +243,21 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
         dspec = P(data_axes if len(data_axes) > 1 else data_axes[0])
         in_specs = (pspecs, bspecs, P(), P(), P())
         out_specs = P() if not partial else (P(), P())
-        smapped = shard_map(
+        smapped = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(_strip_data(pspecs, data_axes),
                       _strip_data(bspecs, data_axes), P())
-                     + (dspec,) * 6      # mask rho C Wsh blk valid
+                     + (dspec,) * 5      # mask rho C blk valid
                      + ((P(),) if partial else ()),
-            out_specs=out_specs, axis_names=set(data_axes), check_vma=False)
+            out_specs=out_specs, axis_names=manual, check_vma=False)
 
         if partial:
             def stepfn(params, batch, W, mask, rho, err_factor):
-                return smapped(params, batch, W, mask, rho, C, W, blk,
+                return smapped(params, batch, W, mask, rho, C, blk,
                                valid, err_factor)
         else:
             def stepfn(params, batch, W, mask, rho):
-                return smapped(params, batch, W, mask, rho, C, W, blk, valid)
+                return smapped(params, batch, W, mask, rho, C, blk, valid)
 
         return stepfn, in_specs, out_specs
 

@@ -19,7 +19,6 @@ from typing import Any, Callable
 
 import jax
 
-from repro.compat import set_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -128,7 +127,7 @@ class BatchedEngine:
 
     def generate(self, prompts: np.ndarray, max_new: int) -> np.ndarray:
         """prompts: (batch, prompt_len) int32 -> (batch, max_new)."""
-        with set_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             batch = {"tokens": jnp.asarray(prompts)}
             if self.cfg.family in ("vlm", "encdec"):
                 batch["embeds"] = jnp.zeros(

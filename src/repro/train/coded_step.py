@@ -24,7 +24,7 @@ All coding phases are delegated to a ``repro.coding.Codec``: ``schedule``
 picks the collective choreography (gather / a2a / psum — see
 ``repro.coding.schedules``), ``backend`` the encode/decode implementation
 ("auto" -> Pallas kernels on TPU, einsum reference elsewhere; "pallas" forces
-the kernels, in interpret mode off-TPU).
+the compiled kernels and needs a TPU; "interpret" runs them interpreted).
 """
 from __future__ import annotations
 
@@ -38,7 +38,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import coding
-from repro.compat import collectives_ok, shard_map
 from repro.core import GradCode
 from repro.models import api as model_api
 from repro.optim import Optimizer
@@ -217,15 +216,10 @@ def _axis_prod(mesh, axes) -> int:
 
 
 def pipelining_supported(mesh, schedule: str = "gather") -> bool:
-    """Whether the async pipelined step is available on this runtime/scheme:
-    the schedule must carry an encoding (psum has no wire to double-buffer)
-    and the runtime must lower native collectives inside shard_map — the
-    degraded old-jax psum-emulated path still *builds* a correct pipeline
-    (tests exercise its parity) but gains nothing from overlap, so drivers
-    use this predicate to skip it gracefully."""
+    """Whether the async pipelined step is available for this scheme: the
+    schedule must carry an encoding (psum has no wire to double-buffer)."""
     from repro.coding import get_schedule
-    return (get_schedule(schedule).uses_encoding
-            and collectives_ok(mesh, _data_axes(mesh)))
+    return get_schedule(schedule).uses_encoding
 
 
 def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
@@ -323,10 +317,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
     codec = coding.make_codec(code, schedule=schedule, backend=backend,
                               wire_dtype=encode_dtype)
-    # Old-jax shard_map partial-auto cannot lower scan/all_gather/all_to_all
-    # inside the manual region when a >1 auto (model) axis remains: unroll the
-    # subset loop and decode via the schedules' psum emulation there.
-    degraded = not collectives_ok(mesh, data_axes)
+    manual = sharding.manual_axes(mesh, data_axes, codec.backend)
 
     if pipelined:
         if not codec.schedule.uses_encoding:
@@ -351,14 +342,6 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             f"fuse_apply supports optimizer.kind='sgd' only (the fused "
             f"kernel replicates the SGD-momentum rule); got "
             f"{optimizer.kind or 'opaque'!r}")
-
-    def scan_subsets(f, init, xs):
-        if not degraded:
-            return jax.lax.scan(f, init, xs)
-        carry = init
-        for i in range(code.d):
-            carry, _ = f(carry, jax.tree.map(lambda x: x[i], xs))
-        return carry, None
 
     # --- shapes / specs ------------------------------------------------
     pshapes = jax.eval_shape(lambda: model_api.init(jax.random.PRNGKey(0), cfg))
@@ -398,11 +381,10 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     # the data axes (dim 0), so each worker reads its own row locally — no
     # axis_index/dynamic gather in the step (axis_index lowers to PartitionId,
     # which SPMD partitioning rejects when GSPMD-auto axes remain).
-    def body(params, opt_state, batch, W, mask, rho, Csh, Wsh, ef=None):
+    def body(params, opt_state, batch, W, mask, rho, Csh, ef=None):
         # local batch leaves: (1, d, b, ...) -> (d, b, ...)
         lb = jax.tree.map(lambda x: x[0], batch)
         Ci = Csh[0]       # (d, m)   this worker's coefficient rows
-        W_row = Wsh[0]    # (m,)     this worker's decode-weight row
         rho_i = rho[0]    # (d,)
         mask_i = mask[0]  # ()
 
@@ -436,10 +418,10 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                 None, jnp.zeros((), jnp.float32))
         if partial:
             init = init + (jnp.zeros((), jnp.float32),)
-            (enc, _, loss_sum, gss_sum), _ = scan_subsets(
+            (enc, _, loss_sum, gss_sum), _ = jax.lax.scan(
                 per_subset, init, (lb, Ci, rho_i))
         else:
-            (enc, _, loss_sum), _ = scan_subsets(per_subset, init,
+            (enc, _, loss_sum), _ = jax.lax.scan(per_subset, init,
                                                  (lb, Ci, rho_i))
 
         # stragglers transmit nothing — zero the payload to prove independence
@@ -465,8 +447,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             flat_enc, td = jax.tree.flatten(enc)
             flat_grads = list(flat_enc)
             bufs = codec.pack(flat_enc, pplan)
-            decs = [codec.decode_packed(b, W, data_axes, W_row=W_row,
-                                        emulate=degraded) for b in bufs]
+            decs = [codec.decode_packed(b, W, data_axes) for b in bufs]
             for i, g_ in codec.unpack(decs, pplan).items():
                 flat_grads[i] = g_
             for i, g_ in coding.psum_fallback(flat_enc, flat_plans,
@@ -477,8 +458,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             def dec_one(e, pl):
                 if not pl.coded:
                     return jax.lax.psum(e, data_axes)
-                return codec.decode_leaf(e, W, pl, data_axes,
-                                         W_row=W_row, emulate=degraded)
+                return codec.decode_leaf(e, W, pl, data_axes)
 
             grads = jax.tree.map(dec_one, enc, plans)
         grads = jax.tree.map(lambda g_: g_ * grad_scale, grads)
@@ -494,7 +474,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         return new_params, new_opt, metrics
 
     # psum baseline: plain rho-weighted all-reduce (uncoded / straggler-aware)
-    def body_psum(params, opt_state, batch, W, mask, rho, Csh, Wsh, ef=None):
+    def body_psum(params, opt_state, batch, W, mask, rho, Csh, ef=None):
         lb = jax.tree.map(lambda x: x[0], batch)
         rho_i = rho[0]
         mask_i = mask[0]
@@ -508,7 +488,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
         init = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
                 jnp.zeros((), jnp.float32))
-        (acc, loss_sum), _ = scan_subsets(per_subset, init, (lb, rho_i))
+        (acc, loss_sum), _ = jax.lax.scan(per_subset, init, (lb, rho_i))
         grads = jax.tree.map(lambda a: jax.lax.psum(a, data_axes) * grad_scale, acc)
         gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_) for g_ in jax.tree.leaves(grads)))
         loss_global = jax.lax.psum(loss_sum * mask_i, data_axes) / k_subsets
@@ -561,14 +541,14 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                       for b in pplan.buckets),
                 tuple(jnp.zeros(sh, jnp.float32) for sh in small_shapes),
                 jnp.zeros((), jnp.float32))
-        (accs, smalls, loss_sum), _ = scan_subsets(per_subset, init,
+        (accs, smalls, loss_sum), _ = jax.lax.scan(per_subset, init,
                                                    (lb, Ci, rho_i))
         wires = tuple(codec.to_wire(a, mask_i) for a in accs)
         side = jnp.concatenate([s_.reshape(-1) for s_ in smalls]
                                + [(loss_sum * mask_i)[None]])
         return wires, side
 
-    def _decode_update(params, opt_state, W, W_row, wires, side):
+    def _decode_update(params, opt_state, W, wires, side):
         """Decode the in-flight wire + side buffers and apply the update:
         the synchronous step's phases 4-5 operating on state instead of
         locally produced encodings.  Op-for-op identical to the sync body
@@ -585,8 +565,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             off += sz
 
         if not fuse:
-            decs = [codec.decode_packed(w, W, data_axes, W_row=W_row,
-                                        emulate=degraded) for w in wires]
+            decs = [codec.decode_packed(w, W, data_axes) for w in wires]
             flat_grads: list = [None] * len(flat_params)
             for i, g_ in codec.unpack(decs, pplan).items():
                 flat_grads[i] = g_ * grad_scale
@@ -605,8 +584,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             for w, pb, mb in zip(wires, p_bufs, mu_bufs):
                 pn, mn, ss = codec.decode_apply_packed(
                     w, W, pb, mb, data_axes, lr=hy["lr"],
-                    momentum=hy["momentum"], scale=grad_scale,
-                    W_row=W_row, emulate=degraded)
+                    momentum=hy["momentum"], scale=grad_scale)
                 new_p_bufs.append(pn)
                 new_mu_bufs.append(mn)
                 ss_parts.append(ss)
@@ -642,7 +620,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         wires, side = _encode_wire(params, lb, Csh[0], rho[0], mask[0])
         return tuple(w[None] for w in wires) + (side[None],)
 
-    def body_steady(params, opt_state, batch, W, mask, rho, Csh, Wsh,
+    def body_steady(params, opt_state, batch, W, mask, rho, Csh,
                     *wire_state):
         """Steady state: decode the in-flight wire (pattern of the PREVIOUS
         call — its W arrives now) and apply the stale-by-one update, while
@@ -653,21 +631,21 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         prev_wires = tuple(w[0] for w in wire_state[:-1])
         prev_side = wire_state[-1][0]
         new_params, new_opt, metrics = _decode_update(
-            params, opt_state, W, Wsh[0], prev_wires, prev_side)
+            params, opt_state, W, prev_wires, prev_side)
         wires, side = _encode_wire(params, lb, Csh[0], rho[0], mask[0])
         return ((new_params, new_opt, metrics)
                 + tuple(w[None] for w in wires) + (side[None],))
 
-    def body_drain(params, opt_state, W, Wsh, *wire_state):
+    def body_drain(params, opt_state, W, *wire_state):
         """Drain: retire the last in-flight buffers — decode + update only."""
         prev_wires = tuple(w[0] for w in wire_state[:-1])
         prev_side = wire_state[-1][0]
-        return _decode_update(params, opt_state, W, Wsh[0],
-                              prev_wires, prev_side)
+        return _decode_update(params, opt_state, W, prev_wires, prev_side)
 
-    # --- wrap in shard_map over the data axes (model stays auto/GSPMD) --
-    # shard_map's in/out_specs may only mention the manual (data) axes; the
-    # 'model' placement is carried by the jit in_shardings (GSPMD auto).
+    # --- wrap in shard_map over the data axes (a model axis wider than one
+    # stays auto/GSPMD; see sharding.manual_axes) --------------------------
+    # shard_map's in/out_specs mention only the data axes; the 'model'
+    # placement is carried by the jit in_shardings (GSPMD auto).
     def _strip(tree):
         keep = set(data_axes)
 
@@ -692,23 +670,23 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             in_specs = in_specs + (P(),)          # the err_factor scalar
             mspecs["decode_err_bound"] = P()
         out_specs = (pspecs, ospecs, mspecs)
-        smapped = shard_map(fn, mesh=mesh,
-                            in_specs=(_strip((pspecs, ospecs, bspecs, P()))
-                                      + (dspec, dspec, dspec, dspec)
-                                      + ((P(),) if partial else ())),
-                            out_specs=_strip(out_specs),
-                            axis_names=set(data_axes), check_vma=False)
+        smapped = jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(_strip((pspecs, ospecs, bspecs, P()))
+                      + (dspec, dspec, dspec)
+                      + ((P(),) if partial else ())),
+            out_specs=_strip(out_specs),
+            axis_names=manual, check_vma=False)
 
-        # W enters twice: replicated (decode needs all n rows) and split
-        # over workers (each worker's own row, for the emulated decode);
-        # mask/rho/C are split so each worker sees only its own row
+        # W enters replicated (decode needs all n rows); mask/rho/C are
+        # split so each worker sees only its own row
         if partial:
             def stepfn(params, opt_state, batch, W, mask, rho, err_factor):
-                return smapped(params, opt_state, batch, W, mask, rho, C, W,
+                return smapped(params, opt_state, batch, W, mask, rho, C,
                                err_factor)
         else:
             def stepfn(params, opt_state, batch, W, mask, rho):
-                return smapped(params, opt_state, batch, W, mask, rho, C, W)
+                return smapped(params, opt_state, batch, W, mask, rho, C)
 
         return stepfn, in_specs, out_specs
 
@@ -725,32 +703,32 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         nbuf = len(pplan.buckets) + 1          # bucket buffers + side buffer
         wire_specs = (dspec,) * nbuf
 
-        fill_sm = shard_map(
+        fill_sm = jax.shard_map(
             body_fill, mesh=mesh,
             in_specs=_strip((pspecs, bspecs)) + (dspec, dspec, dspec),
             out_specs=wire_specs,
-            axis_names=set(data_axes), check_vma=False)
-        steady_sm = shard_map(
+            axis_names=manual, check_vma=False)
+        steady_sm = jax.shard_map(
             body_steady, mesh=mesh,
             in_specs=(_strip((pspecs, ospecs, bspecs, P()))
-                      + (dspec, dspec, dspec, dspec) + wire_specs),
+                      + (dspec, dspec, dspec) + wire_specs),
             out_specs=_strip((pspecs, ospecs, mspecs)) + wire_specs,
-            axis_names=set(data_axes), check_vma=False)
-        drain_sm = shard_map(
+            axis_names=manual, check_vma=False)
+        drain_sm = jax.shard_map(
             body_drain, mesh=mesh,
-            in_specs=(_strip((pspecs, ospecs, P())) + (dspec,) + wire_specs),
+            in_specs=_strip((pspecs, ospecs, P())) + wire_specs,
             out_specs=_strip((pspecs, ospecs, mspecs)),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual, check_vma=False)
 
         def fillfn(params, batch, mask, rho):
             return fill_sm(params, batch, mask, rho, C)
 
         def steadyfn(params, opt_state, batch, W, mask, rho, *wire):
-            return steady_sm(params, opt_state, batch, W, mask, rho, C, W,
+            return steady_sm(params, opt_state, batch, W, mask, rho, C,
                              *wire)
 
         def drainfn(params, opt_state, W, *wire):
-            return drain_sm(params, opt_state, W, W, *wire)
+            return drain_sm(params, opt_state, W, *wire)
 
         return PipelineFns(fill=fillfn, steady=steadyfn, drain=drainfn,
                            num_buffers=nbuf)

@@ -18,6 +18,24 @@ from jax.sharding import PartitionSpec as P
 
 PyTree = Any
 
+def manual_axes(mesh, data_axes, backend=None) -> set[str]:
+    """The axes a coded step's shard_map makes manual: the data axes, and
+    every other axis of size one.  A wider model axis stays GSPMD-auto, and
+    there the compiled Pallas kernels cannot run (a Mosaic call compiles
+    only where no axis is left to the compiler), so ``backend`` — the
+    codec's — is refused."""
+    manual = set(data_axes) | {a for a in mesh.axis_names
+                               if mesh.shape[a] == 1}
+    auto = set(mesh.axis_names) - manual
+    if auto and backend is not None and backend.name == "pallas" \
+            and not backend.interpret:
+        raise ValueError(
+            f"the compiled Pallas kernels need every mesh axis manual, but "
+            f"{sorted(auto)} (size > 1) is left to GSPMD; use a model axis "
+            f"of one or the 'ref' backend")
+    return manual
+
+
 # leaves that live under a stacked-layer container get one leading stack dim
 _STACKS = ("layers", "pairs", "mamba", "enc_layers", "dec_layers")
 

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.coding import SchemeSpec, make_step_inputs, resolve_scheme_spec
-from repro.compat import set_mesh
 from repro.core import GradCode, make_code
 from repro.data import CodedBatcher
 from repro.optim import Optimizer
@@ -155,7 +154,7 @@ class Trainer:
         self._driver: PipelineDriver | None = None
         self.batcher = CodedBatcher(self.code)
         key = jax.random.PRNGKey(self.seed)
-        with set_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             self.params = model_api.init(key, self.cfg)
             self.opt_state = self.optimizer.init(self.params)
         self._jitted = {}
@@ -179,7 +178,7 @@ class Trainer:
                 {"params": self.params, "opt_state": self.opt_state})
             if restored is not None:
                 state, meta = restored
-                with set_mesh(self.mesh):
+                with jax.sharding.set_mesh(self.mesh):
                     self.params = jax.tree.map(jnp.asarray, state["params"])
                     self.opt_state = jax.tree.map(jnp.asarray, state["opt_state"])
                 self._step_count = int(meta.get("step", 0))
@@ -447,7 +446,7 @@ class Trainer:
         if part:
             args.append(jnp.asarray(inp["err_factor"]))
         t0 = time.perf_counter()
-        with set_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             if pipelined:
                 # the driver fills on first use (metrics None — no update
                 # retired yet) and runs overlapped steady steps after; its

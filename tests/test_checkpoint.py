@@ -12,7 +12,6 @@ from repro.checkpoint import store as ckpt_store
 from repro.configs import get_config
 from repro.core import make_code
 from repro.data import make_synthetic_batch
-from repro.compat import NATIVE_SHARD_MAP
 from repro.launch.mesh import make_local_mesh
 from repro.models import api as model_api
 from repro.optim import get_optimizer
@@ -117,9 +116,7 @@ def test_failed_save_never_prunes_older_snapshots(tmp_path, monkeypatch):
 def test_trainer_resume(tmp_path):
     cfg = get_config("qwen3-1.7b").reduced()
     code = make_code(4, 3, 1, 2)
-    # old-jax shard_map partial-auto cannot lower model scans with a >1
-    # auto axis (see repro.compat.collectives_ok)
-    mesh = make_local_mesh(4, 2 if NATIVE_SHARD_MAP else 1)
+    mesh = make_local_mesh(4, 2)
     kw = dict(checkpoint_dir=str(tmp_path), checkpoint_every=2, seed=0)
     tr = Trainer(cfg, code, mesh, get_optimizer("sgd", 1e-2), **kw)
     rng = np.random.default_rng(0)

@@ -1,8 +1,9 @@
-"""Codec subsystem tests: backend parity (ref vs pallas), schedule
-equivalence (gather / a2a / psum) across wire dtypes and backends on a
-multi-device CPU mesh, and the regression test that ``backend='pallas'``
-really executes the Pallas kernels inside the train step (the old
-``use_kernels`` flag imported them and silently never called them)."""
+"""Codec subsystem tests: backend parity (ref vs the Pallas kernels,
+interpreted on the CPU), schedule equivalence (gather / a2a / psum) across
+wire dtypes and backends on a multi-device CPU mesh, and the regression
+test that the kernel backend really executes the Pallas kernels inside the
+train step (the old ``use_kernels`` flag imported them and silently never
+called them)."""
 import functools
 
 import jax
@@ -12,7 +13,6 @@ import pytest
 
 import repro.coding as coding
 from repro.coding import backends as coding_backends
-from repro.compat import make_mesh, shard_map
 from repro.configs import get_config
 from repro.core import make_code
 from repro.data import CodedBatcher, make_synthetic_batch
@@ -60,7 +60,7 @@ def _max_diff(a, b):
                                            - y.astype(jnp.float32)))), a, b)))
 
 
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
 @pytest.mark.parametrize("wire", ["float32", "bfloat16"])
 @pytest.mark.parametrize("schedule", ["gather", "a2a"])
 def test_schedule_equivalence(schedule, backend, wire):
@@ -78,13 +78,13 @@ def test_backends_bitwise_equal_across_schedules():
     accumulate in f32), per schedule."""
     for schedule in ("gather", "a2a"):
         a, _ = _step_outputs(schedule, "ref", "float32")
-        b, _ = _step_outputs(schedule, "pallas", "float32")
+        b, _ = _step_outputs(schedule, "interpret", "float32")
         assert _max_diff(a, b) < 1e-6, f"{schedule}: ref vs pallas diverge"
 
 
 # ------------------------------------------------- pallas really executes
 def test_pallas_backend_executes_kernels(monkeypatch):
-    """backend='pallas' must invoke the Pallas kernel entry points when the
+    """The kernel backend must invoke the Pallas kernel entry points when the
     step is traced — the regression the dead use_kernels flag shipped with."""
     calls = {"encode": 0, "decode": 0}
     real_enc = coding_backends._encode_mod.coded_encode
@@ -105,7 +105,7 @@ def test_pallas_backend_executes_kernels(monkeypatch):
     mesh = make_local_mesh(4, 1)
     opt = get_optimizer("sgd", 1e-2)
     arts = make_coded_train_step(cfg, CODE, mesh, opt,
-                                 spec=coding.SchemeSpec(backend="pallas"))
+                                 spec=coding.SchemeSpec(backend="interpret"))
     assert arts.codec.backend.name == "pallas"
     rng = np.random.default_rng(5)
     placed = jax.tree.map(jnp.asarray, CodedBatcher(CODE).place(
@@ -140,7 +140,7 @@ def test_use_kernels_flag_is_gone():
         make_coded_train_step(cfg, CODE, mesh, opt, use_kernels=True)
     # the replacement spelling selects the same backends
     arts = make_coded_train_step(
-        cfg, CODE, mesh, opt, spec=coding.SchemeSpec(backend="pallas"))
+        cfg, CODE, mesh, opt, spec=coding.SchemeSpec(backend="interpret"))
     assert arts.codec.backend.name == "pallas"
     arts = make_coded_train_step(
         cfg, CODE, mesh, opt, spec=coding.SchemeSpec(backend="ref"))
@@ -150,17 +150,17 @@ def test_use_kernels_flag_is_gone():
 # ---------------------------------------------------------- unit-level parity
 @pytest.mark.parametrize("shape,gdim", [((64,), 0), ((6, 8, 5), 1),
                                         ((16, 3), 0)])
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
 def test_encode_leaf_backend_parity(shape, gdim, backend):
     g = jnp.asarray(RNG.standard_normal(shape), jnp.float32)
     plan = coding.plan_leaf(shape, None, 2)
     assert plan.coded and plan.group_dim == gdim
     coef = jnp.asarray(RNG.standard_normal(2), jnp.float32)
     got = coding.encode_leaf(g, coef, plan, coding.resolve_backend(backend))
-    # oracle: moveaxis + tensordot (the original coded_allreduce fold)
+    # oracle: moveaxis + tensordot over the m leading blocks of the group dim
     x = jnp.moveaxis(g, plan.group_dim, 0)
-    x = x.reshape(x.shape[0] // 2, 2, *x.shape[1:])
-    want = jnp.tensordot(coef, x, axes=[[0], [1]])
+    x = x.reshape(2, x.shape[0] // 2, *x.shape[1:])
+    want = jnp.tensordot(coef, x, axes=[[0], [0]])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -170,35 +170,9 @@ def test_decode_stack_backend_parity(wire):
     F = jnp.asarray(RNG.standard_normal((4, 16, 5)), wire)
     W = jnp.asarray(RNG.standard_normal((4, 2)), jnp.float32)
     a = coding.RefBackend().decode(F, W, out_dtype=jnp.float32)
-    b = coding.resolve_backend("pallas").decode(F, W, out_dtype=jnp.float32)
+    b = coding.resolve_backend("interpret").decode(F, W, out_dtype=jnp.float32)
     assert a.dtype == b.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_emulated_decode_matches_reference():
-    """The psum-emulated decode (old-jax fallback) equals the gathered
-    contraction, on a data-only mesh."""
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 devices")
-    mesh = make_mesh((4,), ("data",))
-    from jax.sharding import PartitionSpec as P
-    n, V, m = 4, 16, 2
-    F = jnp.asarray(RNG.standard_normal((n, V)), jnp.float32)
-    W = jnp.asarray(RNG.standard_normal((n, m)), jnp.float32)
-    plan = coding.LeafPlan(coded=True, group_dim=0)
-    sched = coding.get_schedule("gather")
-
-    def body(f, Wsh):
-        return sched.decode_leaf(f[0], W, plan, ("data",), n,
-                                 coding.RefBackend(), W_row=Wsh[0],
-                                 emulate=True)
-
-    sm = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=P(), axis_names={"data"}, check_vma=False)
-    got = jax.jit(sm)(F, W)
-    want = jnp.einsum("nv,nu->vu", F, W).reshape(-1)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -210,6 +184,19 @@ def test_unknown_backend_and_schedule_rejected():
         coding.get_schedule("ring")
     with pytest.raises(ValueError):
         coding.make_codec(CODE, schedule="nope")
+
+
+def test_pallas_backend_needs_a_tpu():
+    """``pallas`` means the compiled kernels: without a TPU it raises
+    instead of falling back to interpret mode or to the einsum reference;
+    ``interpret`` is the explicit spelling of the interpreted kernels and
+    ``auto`` picks the reference off-TPU."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        coding.resolve_backend("pallas")
+    bk = coding.resolve_backend("interpret")
+    assert bk.name == "pallas" and bk.interpret
+    assert coding.resolve_backend("auto").name == "ref"
 
 
 def test_coded_allreduce_shim_removed():

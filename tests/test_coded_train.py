@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import NATIVE_SHARD_MAP
 from repro.configs import get_config
 from repro.core import make_code
 import repro.coding as coding
@@ -30,11 +29,9 @@ from repro.train.coded_step import make_coded_train_step
 N, D_, S_, M_ = 4, 3, 1, 2
 CODE = make_code(N, D_, S_, M_)
 
-# Old-jax shard_map partial-auto cannot lower the models' scan-over-layers
-# with a >1-sized auto (model) axis (see repro.compat.collectives_ok), so the
-# LM integration meshes collapse the model axis there; the linear-workload
-# test below keeps (4, 2) — scan-free model — to exercise the degraded path.
-MS = 2 if NATIVE_SHARD_MAP else 1
+# model-axis size of the LM integration meshes: the model's scan-over-layers
+# runs GSPMD-auto over 'model' inside the data-manual shard_map
+MS = 2
 
 
 @functools.lru_cache(maxsize=None)
@@ -158,11 +155,9 @@ def test_multiaxis_data_mesh():
     reproduce the single-data-axis result for the same code + stragglers."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices")
-    if not NATIVE_SHARD_MAP:
-        pytest.skip("old-jax partial-auto cannot lower model scans")
-    from repro.compat import AXIS_TYPE_AUTO, make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
-                     axis_types=(AXIS_TYPE_AUTO,) * 3)
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     cfg = get_config("qwen3-1.7b").reduced()
     opt = get_optimizer("sgd", 1e-2)
     arts = make_coded_train_step(cfg, CODE, mesh, opt,

@@ -8,9 +8,8 @@ Four layers:
      a hypothesis property test widens it when hypothesis is installed),
      and the exact path refusing over-budget patterns;
   3. full-step integration — the hetero coded step equals uncoded psum
-     training on the linear workload for gather and a2a, the partial step
-     completes past s with a finite reported bound, and the degraded
-     (psum-emulated old-jax) route agrees too;
+     training on the linear workload for gather and a2a, and the partial
+     step completes past s with a finite reported bound;
   4. the straggler-bench contract — the skewed-cluster plan search prefers
      the hetero plan over every uniform triple.
 """
@@ -211,20 +210,6 @@ def test_hetero_step_equals_uncoded():
         assert _max_diff(got, ref) < 5e-5, f"stragglers {st_}"
     assert arts.loads == code.loads
     got, _, _ = _run_step(code, "a2a", (1,))
-    assert _max_diff(got, ref) < 5e-5
-
-
-def test_hetero_step_degraded_psum_emulated_route():
-    """Old-jax partial-auto cannot lower collectives with a >1 model axis:
-    the (4, 2) mesh forces the psum-emulated decode + unrolled subset loop
-    (repro.compat.collectives_ok) — hetero plans must survive it too."""
-    from repro.compat import collectives_ok
-    cfg, mesh, opt, batch, params = _linear_setup(2)
-    if collectives_ok(mesh, ("data",)):
-        pytest.skip("native collectives available; degraded route not taken")
-    ref, _, _ = _run_step(make_code(N, 1, 0, 1), "psum", (), n_model=2)
-    code = make_hetero_code(SPEEDS, s=1, m=2)
-    got, _, _ = _run_step(code, "gather", (2,), n_model=2)
     assert _max_diff(got, ref) < 5e-5
 
 

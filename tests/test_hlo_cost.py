@@ -4,9 +4,8 @@ collectives inside scanned bodies (which XLA's cost_analysis misses)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.compat import AXIS_TYPE_AUTO, make_mesh, shard_map
 from repro.launch import hlo_cost
 
 
@@ -24,16 +23,14 @@ def test_scan_flops_multiplied_by_trip_count():
     assert r["flops"] == want
     # XLA's own counter sees the body once — document the discrepancy
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # old jax: one entry per device
-        ca = ca[0]
     assert ca["flops"] < want / (L / 2)
 
 
 def _mesh():
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices")
-    return make_mesh((2, 2), ("data", "model"),
-                     axis_types=(AXIS_TYPE_AUTO,) * 2)
+    return jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_collective_bytes_from_shapes():
@@ -44,9 +41,9 @@ def test_collective_bytes_from_shapes():
         def body(h):
             g = jax.lax.all_gather(h, "data")
             return jax.lax.psum(g.sum(0), "data")
-        return shard_map(body, mesh=mesh, in_specs=P("data"),
-                         out_specs=P(), axis_names={"data", "model"},
-                         check_vma=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                             out_specs=P(), axis_names={"data", "model"},
+                             check_vma=False)(x)
 
     c = jax.jit(coll, in_shardings=(NamedSharding(mesh, P("data", None)),)) \
         .lower(x).compile()
@@ -64,9 +61,10 @@ def test_collective_inside_scan_multiplied():
         def body(h):
             return jax.lax.scan(lambda c, _: (jax.lax.psum(c, "data"), None),
                                 h, None, length=T)[0]
-        return shard_map(body, mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"), axis_names={"data", "model"},
-                         check_vma=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                             out_specs=P("data"),
+                             axis_names={"data", "model"},
+                             check_vma=False)(x)
 
     c = jax.jit(collscan, in_shardings=(NamedSharding(mesh, P("data", None)),)) \
         .lower(x).compile()

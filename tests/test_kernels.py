@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import coded_decode, coded_encode, ops, ref
+from repro.kernels import (coded_decode, coded_decode_apply, coded_encode,
+                           coded_encode_acc, ref)
 
 RNG = np.random.default_rng(7)
 
@@ -18,7 +19,7 @@ def _tol(dtype):
 @pytest.mark.parametrize("d,V,m", [(1, 8, 1), (3, 64, 2), (5, 640, 4),
                                    (8, 1024, 8), (31, 96, 3)])
 def test_encode_2d_sweep(d, V, m, dtype):
-    G = jnp.asarray(RNG.standard_normal((d, V, m)), dtype)
+    G = jnp.asarray(RNG.standard_normal((d, m, V)), dtype)
     C = jnp.asarray(RNG.standard_normal((d, m)), dtype)
     got = coded_encode(G, C, interpret=True)
     want = ref.coded_encode_ref(G, C)
@@ -31,10 +32,10 @@ def test_encode_2d_sweep(d, V, m, dtype):
 @pytest.mark.parametrize("d,V,m,R", [(3, 16, 2, 128), (4, 256, 2, 64),
                                      (2, 40, 5, 96)])
 def test_encode_3d_sweep(d, V, m, R, dtype):
-    G = jnp.asarray(RNG.standard_normal((d, V, m, R)), dtype)
+    G = jnp.asarray(RNG.standard_normal((d, m, V, R)), dtype)
     C = jnp.asarray(RNG.standard_normal((d, m)), dtype)
     got = coded_encode(G, C, interpret=True)
-    want = ref.coded_encode_batch_ref(G, C)
+    want = ref.coded_encode_ref(G, C)
     assert got.shape == (V, R)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -48,7 +49,7 @@ def test_decode_2d_sweep(n, V, m, dtype):
     W = jnp.asarray(RNG.standard_normal((n, m)), dtype)
     got = coded_decode(F, W, interpret=True)
     want = ref.coded_decode_ref(F, W)
-    assert got.shape == (V, m)
+    assert got.shape == (m, V)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
 
@@ -58,8 +59,8 @@ def test_decode_3d_sweep(n, V, m, R):
     F = jnp.asarray(RNG.standard_normal((n, V, R)), jnp.float32)
     W = jnp.asarray(RNG.standard_normal((n, m)), jnp.float32)
     got = coded_decode(F, W, interpret=True)
-    want = ref.coded_decode_batch_ref(F, W)
-    assert got.shape == (V, m, R)
+    want = ref.coded_decode_ref(F, W)
+    assert got.shape == (m, V, R)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -77,14 +78,53 @@ def test_kernel_end_to_end_roundtrip():
     F = []
     for i in range(code.n):
         rows = [(i + j) % code.n for j in range(code.d)]
-        G = jnp.asarray(Gfull[rows].reshape(code.d, V, code.m))
+        G = jnp.asarray(Gfull[rows].reshape(code.d, code.m, V))
         C = jnp.asarray(code.C[i], jnp.float32)
         F.append(np.asarray(coded_encode(G, C, interpret=True)))
     F = jnp.asarray(np.stack(F))
     W = jnp.asarray(code.decode_weights([0, 1, 3, 4, 5, 7]), jnp.float32)
-    dec = coded_decode(F, W, interpret=True)          # (V, m)
+    dec = coded_decode(F, W, interpret=True)          # (m, V)
     got = np.asarray(dec).reshape(-1)
     np.testing.assert_allclose(got, Gfull.sum(0), rtol=1e-4, atol=1e-4)
+
+
+# ragged shapes: an R past one lane block that is no multiple of 128 (the
+# 1/8 qwen3 vocabulary), and 2-D lengths that need lane padding
+@pytest.mark.parametrize("shape", [(1, 2, 16, 18992), (2, 3, 40, 300),
+                                   (3, 2, 1000), (1, 2, 77)])
+def test_encode_acc_ragged_blocks(shape):
+    G = jnp.asarray(RNG.standard_normal(shape), jnp.float32)
+    C = jnp.asarray(RNG.standard_normal(shape[:2]), jnp.float32)
+    acc = jnp.asarray(RNG.standard_normal(shape[2:]), jnp.float32)
+    want = acc + coded_encode(G, C, interpret=True)
+    got = coded_encode_acc(acc, G, C, interpret=True)
+    # the fused fold is bit-identical to acc + encode (shared f32 sequence)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    oracle = ref.coded_encode_ref(G, C)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(acc + oracle),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n,m,L", [(4, 2, 600 * 128), (3, 1, 1024),
+                                   (8, 4, 384)])
+def test_decode_apply_matches_decode_then_update(n, m, L):
+    """Fused decode + SGD-momentum: params/momentum equal the unfused
+    decode -> update up to where the compiler contracts a multiply-add into
+    one FMA (an ulp), and the masked sum(g^2) partials (the ragged last
+    block at L = 600*128 carries padding rows) agree with the plain sum."""
+    F = jnp.asarray(RNG.standard_normal((n, L)), jnp.float32)
+    W = jnp.asarray(RNG.standard_normal((n, m)), jnp.float32)
+    P = jnp.asarray(RNG.standard_normal((m, L)), jnp.float32)
+    MU = jnp.asarray(RNG.standard_normal((m, L)), jnp.float32)
+    kw = dict(lr=0.1, momentum=0.9, scale=0.25)
+    pn, mun, ss = coded_decode_apply(F, W, P, MU, interpret=True, **kw)
+    g = coded_decode(F, W, interpret=True, out_dtype=jnp.float32) * 0.25
+    mu = 0.9 * MU + g
+    np.testing.assert_allclose(np.asarray(mun), np.asarray(mu),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pn), np.asarray(P - 0.1 * mu),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(float(ss), float(jnp.sum(g * g)), rtol=1e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -110,35 +150,32 @@ def test_flash_attention_sweep(B, S, H, Hkv, hd, kind, w, dtype):
 
 
 def test_ops_wrapper_modes():
-    G = jnp.asarray(RNG.standard_normal((3, 64, 2)), jnp.float32)
+    """The ref backend and the interpreted kernels agree on both
+    contractions (the einsum oracle vs the Pallas sequence)."""
+    from repro.coding import resolve_backend
+    a_bk, b_bk = resolve_backend("ref"), resolve_backend("interpret")
+    G = jnp.asarray(RNG.standard_normal((3, 2, 64)), jnp.float32)
     C = jnp.asarray(RNG.standard_normal((3, 2)), jnp.float32)
-    a = ops.encode(G, C, mode="ref")
-    b = ops.encode(G, C, mode="interpret")
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(a_bk.encode(G, C)),
+                               np.asarray(b_bk.encode(G, C)),
+                               rtol=1e-5, atol=1e-5)
     F = jnp.asarray(RNG.standard_normal((4, 64)), jnp.float32)
     W = jnp.asarray(RNG.standard_normal((4, 2)), jnp.float32)
-    np.testing.assert_allclose(np.asarray(ops.decode(F, W, mode="ref")),
-                               np.asarray(ops.decode(F, W, mode="interpret")),
+    np.testing.assert_allclose(np.asarray(a_bk.decode(F, W)),
+                               np.asarray(b_bk.decode(F, W)),
                                rtol=1e-5, atol=1e-5)
 
 
-# ------------------------------------------------------- pick_tile memo
-def test_pick_tile_alignment_preference_and_cache():
-    """pick_tile prefers align-multiples over larger unaligned divisors,
-    falls back to the largest divisor, and memoizes (it is an O(size)
-    Python loop re-run at every trace for every leaf shape)."""
-    from repro.kernels.coded_encode import pick_tile as pick
-    pick.cache_clear()
-    # aligned divisor preferred even when a larger unaligned one exists
-    assert pick(1024, 768, 128) == 512         # not 1024>target nor 768
-    assert pick(640, 512, 128) == 128          # 320 divides but is unaligned
-    # no aligned divisor: largest divisor <= target
-    assert pick(192, 128, 128) == 96
-    assert pick(7, 512, 128) == 7
-    # exact-size hit when size <= target and aligned
-    assert pick(256, 512, 128) == 256
-    before = pick.cache_info()
-    assert pick(640, 512, 128) == 128          # repeat: served by the cache
-    after = pick.cache_info()
-    assert after.hits == before.hits + 1
-    assert after.misses == before.misses
+# ------------------------------------------------------- block tiling
+@pytest.mark.parametrize("A,B,bpe", [(1024, 2048, 8), (8, 18992, 12),
+                                     (600, 128, 48), (5, 7, 4),
+                                     (1, 151936, 16)])
+def test_block_tiles_aligned_and_within_budget(A, B, bpe):
+    """Every tile dim is whole or (16, 128)-aligned — never an unaligned
+    divisor — and a block stays inside the per-step VMEM budget."""
+    from repro.kernels.coded_encode import BLOCK_BYTES, block_tiles
+    ta, tb = block_tiles(A, B, bpe)
+    assert ta == A or ta % 16 == 0
+    assert tb == B or tb % 128 == 0
+    assert 0 < ta <= A and 0 < tb <= B
+    assert ta * tb * bpe <= BLOCK_BYTES

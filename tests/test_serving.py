@@ -2,7 +2,6 @@
 pinned by out_shardings), greedy generation runs for dense (window and
 dense-cache), SSM, and encdec families on a live mesh."""
 import jax
-from repro.compat import set_mesh
 import numpy as np
 import pytest
 
@@ -18,7 +17,7 @@ from repro.serving.engine import BatchedEngine
 def test_engine_generate(arch, window):
     cfg = get_config(arch).reduced()
     mesh = make_local_mesh(4, 2)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = api.init(jax.random.PRNGKey(0), cfg)
     engine = BatchedEngine(cfg, mesh, params, batch=4, seq_len=40,
                            window=window)
@@ -36,7 +35,7 @@ def test_engine_non_divisible_batch_uses_replicated_tokens():
     B = k*b batching sidesteps)."""
     cfg = get_config("qwen3-1.7b").reduced()
     mesh = make_local_mesh(4, 2)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = api.init(jax.random.PRNGKey(0), cfg)
     engine = BatchedEngine(cfg, mesh, params, batch=3, seq_len=32)
     spec = engine.arts.token_sharding.spec
@@ -75,7 +74,7 @@ def test_engine_deterministic_across_batch_slots():
     (catches cross-slot leakage through sharded caches)."""
     cfg = get_config("qwen3-1.7b").reduced()
     mesh = make_local_mesh(4, 2)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = api.init(jax.random.PRNGKey(1), cfg)
     engine = BatchedEngine(cfg, mesh, params, batch=4, seq_len=32)
     prompt = np.random.default_rng(1).integers(0, cfg.vocab, (1, 8),

@@ -80,8 +80,7 @@ def test_forward_decode_lm_family():
     (4 data x 2 model) mesh."""
     cfg = get_config("qwen3-1.7b").reduced()
     mesh = make_local_mesh(4, 2)
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = model_api.init(jax.random.PRNGKey(0), cfg)
     code = make_code(4, 2, 1, 1)
     b, seq = 1, 16
@@ -95,7 +94,7 @@ def test_forward_decode_lm_family():
     inp = arts.step_inputs([3])
     out = np.asarray(arts.compiled(placed)(
         params, placed, inp["W"], inp["mask"], inp["rho"]))
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         direct = np.asarray(model_api.make_forward(cfg)(
             params, {"tokens": jnp.asarray(toks)}))
     assert out.shape == direct.shape == (B, cfg.vocab)
