@@ -30,6 +30,7 @@ import jax.numpy as jnp
 if TYPE_CHECKING:  # annotation-only: keeps repro.coding import-independent
     from repro.core.schemes import GradCode
 
+from . import wire
 from .backends import CodecBackend, RefBackend, resolve_backend
 from .layout import flatten_rest, leaf_to_groups, unflatten_rest
 from .packing import (PackPlan, make_pack_plan, pack_bucket,
@@ -88,7 +89,7 @@ def decode_tree(enc: PyTree, smalls: PyTree, W: jax.Array, rho_i: jax.Array,
     def dec_one(e, sm, p):
         if p.coded:
             return sched.decode_leaf(e, W, p, axis_names, n, backend)
-        return jax.lax.psum(sm, axis_names)
+        return wire.psum(sm, axis_names)
 
     return jax.tree.map(dec_one, enc, smalls, plans,
                         is_leaf=lambda x: x is None)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import wire
 from .layout import groups_to_leaf, leaf_to_groups
 from .plan import LeafPlan
 
@@ -254,7 +255,7 @@ def psum_fallback(flat_leaves: Sequence[jax.Array], flat_plans,
         return {}
     sbuf = (jnp.concatenate([flat_leaves[i].reshape(-1) for i in small_ix])
             if len(small_ix) > 1 else flat_leaves[small_ix[0]].reshape(-1))
-    ssum = jax.lax.psum(sbuf, axis_names)
+    ssum = wire.psum(sbuf, axis_names)
     out: dict[int, jax.Array] = {}
     off = 0
     for i in small_ix:

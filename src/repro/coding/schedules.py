@@ -181,7 +181,7 @@ class PsumSchedule(Schedule):
 
     def decode_leaf(self, f_leaf, W, plan, axis_names, n, backend):
         """Plain all-reduce — the rho weighting happened at accumulation."""
-        return jax.lax.psum(f_leaf, axis_names)
+        return wire.psum(f_leaf, axis_names)
 
 
 SCHEDULES = {s.name: s for s in

@@ -56,6 +56,7 @@ def coded_decode(F: jax.Array, W: jax.Array, *, interpret: bool = False,
         out_specs=pl.BlockSpec((m, ta, tb), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((m, A, B), out_dtype),
         interpret=interpret,
+        name="coded_decode",
     )(W.reshape(-1).astype(jnp.float32), X)
     return from_plane(out, rest)
 
@@ -126,5 +127,6 @@ def coded_decode_apply(F: jax.Array, W: jax.Array, P: jax.Array,
                    jax.ShapeDtypeStruct((nb, 1, B), jnp.float32)],
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
+        name="coded_decode_apply",
     )(W.reshape(-1).astype(jnp.float32), X, Pl, Ml)
     return from_plane(pn, rest), from_plane(mun, rest), jnp.sum(ss)

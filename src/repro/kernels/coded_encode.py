@@ -119,6 +119,8 @@ def _encode_acc_kernel(K, rolled, c_ref, a_ref, g_ref, o_ref):
 
 
 def _encode_call(kernel, K, A, B, tiles, out_dtype, interpret, alias):
+    """The encode's ``pallas_call``, named for its kernel (``coded_encode``
+    or ``coded_encode_acc``) so a trace finds it by that name."""
     ta, tb = tiles
     grid = (pl.cdiv(A, ta), pl.cdiv(B, tb))
     plane = pl.BlockSpec((ta, tb), lambda i, j: (i, j))
@@ -134,6 +136,7 @@ def _encode_call(kernel, K, A, B, tiles, out_dtype, interpret, alias):
         out_shape=jax.ShapeDtypeStruct((A, B), out_dtype),
         input_output_aliases={1: 0} if alias else {},
         interpret=interpret,
+        name="coded_encode_acc" if alias else "coded_encode",
     )
 
 

@@ -20,6 +20,13 @@ subsets.  The step (manual over data axes, GSPMD-auto over 'model'):
      one collective choreography + one fused contraction per bucket,
   5. runs the optimizer update (replicated over data axes, model-sharded).
 
+Each phase runs under a ``jax.named_scope``: ``coded.grad`` (forward and
+backward), ``coded.encode`` (fold, mask, pack), ``coded.exchange`` (every
+collective, set in ``repro.coding.wire``), ``coded.decode`` and
+``coded.apply`` (the optimizer).  The scopes are op metadata only; a device
+trace carries them in each op's name path, so its time can be put down to a
+phase.
+
 All coding phases are delegated to a ``repro.coding.Codec``: ``schedule``
 picks the collective choreography (gather / a2a / psum — see
 ``repro.coding.schedules``), ``backend`` the encode/decode implementation
@@ -29,7 +36,6 @@ the compiled kernels and needs a TPU; "interpret" runs them interpreted).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable
 
 import jax
@@ -38,6 +44,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import coding
+from repro.coding import wire
 from repro.core import GradCode
 from repro.models import api as model_api
 from repro.optim import Optimizer
@@ -80,8 +87,7 @@ class StepArtifacts:
     pipeline: Callable | None = None   # (batch_shapes) -> PipelineFns
     # memoized jitted executables, keyed by (batch signature, donate): the
     # bench's donated steady-state step and the autotuner's telemetry step
-    # share ONE executable instead of tracing twice (and `instrumented`
-    # wraps exactly the `compiled` object, never a private re-jit)
+    # share ONE executable instead of tracing twice
     _exe_cache: dict = dataclasses.field(default_factory=dict, init=False,
                                          repr=False, compare=False)
 
@@ -106,8 +112,7 @@ class StepArtifacts:
         into the next call instead of replaying the originals.
 
         Memoized per (batch shapes, donate): repeat callers — the bench's
-        timing loop, `instrumented` telemetry wrappers, HLO dumps — all
-        receive the same jitted callable, so the step is traced and
+        timing loop, HLO dumps — all receive the same jitted callable, so the step is traced and
         compiled at most once per signature.
         """
         key = self._batch_sig(batch) + (bool(donate),)
@@ -168,33 +173,6 @@ class StepArtifacts:
         if self.partial:
             args.append(jax.ShapeDtypeStruct((), jnp.float32))
         return jax.jit(fn).lower(*args)
-
-    def instrumented(self, batch, on_time: Callable[[float], None],
-                     donate: bool = False):
-        """Telemetry hook: a ``compiled(...)`` executable that reports its
-        blocked wall-clock.
-
-        Returns a callable with the step signature that runs the jitted
-        step, blocks until every output is ready, and passes the elapsed
-        seconds to ``on_time`` before returning the outputs.  This is the
-        convenience wrapper for drivers that build their own loop; the
-        ``Trainer`` performs the equivalent inline timing itself (its jit
-        cache is keyed per scheme) and feeds the same blocked wall-clock
-        into the `repro.tune` step-cost calibration.
-        """
-        fn = self.compiled(batch, donate=donate)
-
-        def timed(*args):
-            t0 = time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
-            on_time(time.perf_counter() - t0)
-            return out
-
-        # the executable actually timed — tests assert it IS the memoized
-        # `compiled(...)` object (identical HLO by identity, not by diff)
-        timed.inner = fn
-        return timed
 
     def step_inputs(self, stragglers=()) -> dict[str, jax.Array]:
         """Drop-pattern hook: device-ready `W`/`mask`/`rho` for a straggler
@@ -394,7 +372,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             else:
                 enc, small, loss_acc = carry
             sub, cj, rj = xs
-            lval, g = jax.value_and_grad(loss_fn)(params, sub)
+            with jax.named_scope("coded.grad"):
+                lval, g = jax.value_and_grad(loss_fn)(params, sub)
 
             def fold(e, gleaf, pl):
                 if not pl.coded:
@@ -403,7 +382,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                 # contribution arrives as (Dg/m, *rest-moved); match e's layout
                 return e + contrib
 
-            enc = jax.tree.map(fold, enc, g, plans)
+            with jax.named_scope("coded.encode"):
+                enc = jax.tree.map(fold, enc, g, plans)
             if partial:
                 # rho-weighted subset gradient sumsq: psummed it becomes
                 # sum_j ||g_j||^2 over covered subsets — the certificate's
@@ -425,9 +405,10 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                                                  (lb, Ci, rho_i))
 
         # stragglers transmit nothing — zero the payload to prove independence
-        enc = jax.tree.map(
-            lambda e, pl: codec.to_wire(e, mask_i) if pl.coded else e,
-            enc, plans)
+        with jax.named_scope("coded.encode"):
+            enc = jax.tree.map(
+                lambda e, pl: codec.to_wire(e, mask_i) if pl.coded else e,
+                enc, plans)
         if ENC_CONSTRAINT:
             flat_e, td = jax.tree.flatten(enc)
             flat_s = td.flatten_up_to(enc_specs)
@@ -446,10 +427,12 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             # all-reduce instead of one per leaf.
             flat_enc, td = jax.tree.flatten(enc)
             flat_grads = list(flat_enc)
-            bufs = codec.pack(flat_enc, pplan)
-            decs = [codec.decode_packed(b, W, data_axes) for b in bufs]
-            for i, g_ in codec.unpack(decs, pplan).items():
-                flat_grads[i] = g_
+            with jax.named_scope("coded.encode"):
+                bufs = codec.pack(flat_enc, pplan)
+            with jax.named_scope("coded.decode"):
+                decs = [codec.decode_packed(b, W, data_axes) for b in bufs]
+                for i, g_ in codec.unpack(decs, pplan).items():
+                    flat_grads[i] = g_
             for i, g_ in coding.psum_fallback(flat_enc, flat_plans,
                                               data_axes).items():
                 flat_grads[i] = g_
@@ -457,19 +440,21 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         else:
             def dec_one(e, pl):
                 if not pl.coded:
-                    return jax.lax.psum(e, data_axes)
+                    return wire.psum(e, data_axes)
                 return codec.decode_leaf(e, W, pl, data_axes)
 
-            grads = jax.tree.map(dec_one, enc, plans)
+            with jax.named_scope("coded.decode"):
+                grads = jax.tree.map(dec_one, enc, plans)
         grads = jax.tree.map(lambda g_: g_ * grad_scale, grads)
         gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_) for g_ in jax.tree.leaves(grads)))
         # responders' view, normalised by the subset count (= n uniformly)
-        loss_global = jax.lax.psum(loss_sum * mask_i, data_axes) / k_subsets
+        loss_global = wire.psum(loss_sum * mask_i, data_axes) / k_subsets
 
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("coded.apply"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         metrics = {"loss": loss_global[None], "grad_norm": gnorm[None]}
         if partial:
-            bound = ef * jnp.sqrt(jax.lax.psum(gss_sum, data_axes))
+            bound = ef * jnp.sqrt(wire.psum(gss_sum, data_axes))
             metrics["decode_err_bound"] = bound[None]
         return new_params, new_opt, metrics
 
@@ -482,17 +467,19 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         def per_subset(carry, xs):
             acc, loss_acc = carry
             sub, rj = xs
-            lval, g = jax.value_and_grad(loss_fn)(params, sub)
+            with jax.named_scope("coded.grad"):
+                lval, g = jax.value_and_grad(loss_fn)(params, sub)
             acc = jax.tree.map(lambda a, g_: a + rj * g_.astype(jnp.float32), acc, g)
             return (acc, loss_acc + rj * lval), None
 
         init = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
                 jnp.zeros((), jnp.float32))
         (acc, loss_sum), _ = jax.lax.scan(per_subset, init, (lb, rho_i))
-        grads = jax.tree.map(lambda a: jax.lax.psum(a, data_axes) * grad_scale, acc)
+        grads = jax.tree.map(lambda a: wire.psum(a, data_axes) * grad_scale, acc)
         gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_) for g_ in jax.tree.leaves(grads)))
-        loss_global = jax.lax.psum(loss_sum * mask_i, data_axes) / k_subsets
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        loss_global = wire.psum(loss_sum * mask_i, data_axes) / k_subsets
+        with jax.named_scope("coded.apply"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         metrics = {"loss": loss_global[None], "grad_norm": gnorm[None]}
         if partial:
             # the psum baseline carries no code: rho already drops uncovered
@@ -526,15 +513,17 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         def per_subset(carry, xs):
             accs, smalls, loss_acc = carry
             sub, cj, rj = xs
-            lval, g = jax.value_and_grad(loss_fn)(params, sub)
+            with jax.named_scope("coded.grad"):
+                lval, g = jax.value_and_grad(loss_fn)(params, sub)
             flat_g = jax.tree.leaves(g)
             accs = list(accs)
-            for bi, slot in slot_items:
-                accs[bi] = codec.encode_into(
-                    accs[bi], flat_g[slot.leaf_index].astype(jnp.float32),
-                    cj, slot)
-            smalls = tuple(sm + rj * flat_g[i].astype(jnp.float32)
-                           for sm, i in zip(smalls, small_ix))
+            with jax.named_scope("coded.encode"):
+                for bi, slot in slot_items:
+                    accs[bi] = codec.encode_into(
+                        accs[bi], flat_g[slot.leaf_index].astype(jnp.float32),
+                        cj, slot)
+                smalls = tuple(sm + rj * flat_g[i].astype(jnp.float32)
+                               for sm, i in zip(smalls, small_ix))
             return (tuple(accs), smalls, loss_acc + rj * lval), None
 
         init = (tuple(jnp.zeros((b.size,), jnp.float32)
@@ -543,9 +532,10 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                 jnp.zeros((), jnp.float32))
         (accs, smalls, loss_sum), _ = jax.lax.scan(per_subset, init,
                                                    (lb, Ci, rho_i))
-        wires = tuple(codec.to_wire(a, mask_i) for a in accs)
-        side = jnp.concatenate([s_.reshape(-1) for s_ in smalls]
-                               + [(loss_sum * mask_i)[None]])
+        with jax.named_scope("coded.encode"):
+            wires = tuple(codec.to_wire(a, mask_i) for a in accs)
+            side = jnp.concatenate([s_.reshape(-1) for s_ in smalls]
+                                   + [(loss_sum * mask_i)[None]])
         return wires, side
 
     def _decode_update(params, opt_state, W, wires, side):
@@ -554,7 +544,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         locally produced encodings.  Op-for-op identical to the sync body
         (bitwise parity) on the default path; with ``fuse_apply`` the coded
         leaves ride the fused decode-plus-apply kernel instead."""
-        side_sum = jax.lax.psum(side, data_axes)
+        side_sum = wire.psum(side, data_axes)
         loss_global = side_sum[-1] / k_subsets
         flat_params, ptd = jax.tree.flatten(params)
         small_grads: dict[int, jax.Array] = {}
@@ -565,16 +555,19 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             off += sz
 
         if not fuse:
-            decs = [codec.decode_packed(w, W, data_axes) for w in wires]
             flat_grads: list = [None] * len(flat_params)
-            for i, g_ in codec.unpack(decs, pplan).items():
-                flat_grads[i] = g_ * grad_scale
+            with jax.named_scope("coded.decode"):
+                decs = [codec.decode_packed(w, W, data_axes) for w in wires]
+                for i, g_ in codec.unpack(decs, pplan).items():
+                    flat_grads[i] = g_ * grad_scale
             for i, g_ in small_grads.items():
                 flat_grads[i] = g_
             grads = ptd.unflatten(flat_grads)
             gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_)
                                  for g_ in jax.tree.leaves(grads)))
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("coded.apply"):
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
         else:
             hy = optimizer.hyper
             flat_mu = ptd.flatten_up_to(opt_state["mu"])
@@ -582,9 +575,10 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             mu_bufs = codec.pack_params(flat_mu, pplan)
             new_p_bufs, new_mu_bufs, ss_parts = [], [], []
             for w, pb, mb in zip(wires, p_bufs, mu_bufs):
-                pn, mn, ss = codec.decode_apply_packed(
-                    w, W, pb, mb, data_axes, lr=hy["lr"],
-                    momentum=hy["momentum"], scale=grad_scale)
+                with jax.named_scope("coded.decode"):
+                    pn, mn, ss = codec.decode_apply_packed(
+                        w, W, pb, mb, data_axes, lr=hy["lr"],
+                        momentum=hy["momentum"], scale=grad_scale)
                 new_p_bufs.append(pn)
                 new_mu_bufs.append(mn)
                 ss_parts.append(ss)
@@ -595,8 +589,9 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                                        jnp.zeros(flat_params[i].shape,
                                                  jnp.float32))
                        for i in range(len(flat_params))]
-            new_params, new_opt = optimizer.update(
-                ptd.unflatten(flat_gz), opt_state, params)
+            with jax.named_scope("coded.apply"):
+                new_params, new_opt = optimizer.update(
+                    ptd.unflatten(flat_gz), opt_state, params)
             flat_np = ptd.flatten_up_to(new_params)
             flat_nmu = ptd.flatten_up_to(new_opt["mu"])
             for i, v in codec.unpack_params(new_p_bufs, pplan,

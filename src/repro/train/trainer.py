@@ -365,12 +365,14 @@ class Trainer:
             # a fresh run restoring this snapshot can replay its data stream
             # to the same batch (skip_to_cursor) and verify it runs the same
             # seed and codec the snapshot was written under
-            self._ckpt.save(self._step_count,
-                            {"params": self.params, "opt_state": self.opt_state},
-                            {"arch": self.cfg.name,
-                             "data_cursor": self._data_cursor,
-                             "seed": self.seed,
-                             "scheme_sig": repr(self._scheme_sig)})
+            with jax.profiler.TraceAnnotation("trainer.checkpoint"):
+                self._ckpt.save(self._step_count,
+                                {"params": self.params,
+                                 "opt_state": self.opt_state},
+                                {"arch": self.cfg.name,
+                                 "data_cursor": self._data_cursor,
+                                 "seed": self.seed,
+                                 "scheme_sig": repr(self._scheme_sig)})
 
     def skip_to_cursor(self, stream: Iterator, consumed: int = 0) -> Iterator:
         """Advance a data stream to the restored batch cursor.
@@ -405,48 +407,62 @@ class Trainer:
 
     # ---------------------------------------------------------------- steps
     def step(self, batch: dict[str, np.ndarray]) -> dict[str, float]:
-        placed = self.batcher.place(batch)
-        draw = self._source.draw(self._step_count,
-                                 self.code).restrict(self.code.n)
-        stragglers = list(draw.stragglers)
-        times = draw.times
-        part = self._step_partial(stragglers)
-        # a forced-partial step cannot ride the pipelined wire (the partial
-        # executable is synchronous by construction), so it drops to the
-        # sync path for this step only; when the trainer is *configured*
-        # partial, pipelining is already off (SchemeSpec rejects the combo)
-        pipelined = self.pipelined and not part
-        if (self.pipelined and not pipelined and self._driver is not None
-                and self._driver.in_flight):
-            # retire the in-flight update before stepping synchronously —
-            # its buffers are valid under the unchanged codec
-            self.params, self.opt_state, _ = self._driver.drain(
-                self.params, self.opt_state)
-            self._driver = None
-        arts = (self.arts if part == self.partial
-                and pipelined == self.pipelined
-                else self._get_arts(self.code, self.schedule, self.packed,
-                                    pipelined=pipelined, partial=part))
-        fn = None
-        fresh = False
-        if not pipelined:
-            shapes = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), placed)
-            keyshape = (self._sig(partial=part, pipelined=pipelined),
-                        tuple(sorted((k, v.shape) for k, v in placed.items())))
-            fresh = keyshape not in self._jitted
-            if fresh:
-                smapped, in_specs, _ = arts.step(shapes)
-                self._jitted[keyshape] = jax.jit(smapped,
-                                                 donate_argnums=(0, 1))
-            fn = self._jitted[keyshape]
-        inp = make_step_inputs(self.code, stragglers, partial=part)
-        args = [jnp.asarray(inp["W"]), jnp.asarray(inp["mask"]),
-                jnp.asarray(inp["rho"])]
-        if part:
-            args.append(jnp.asarray(inp["err_factor"]))
-        t0 = time.perf_counter()
-        with jax.sharding.set_mesh(self.mesh):
+        """One training step, its host work in profiler spans, in order:
+        ``trainer.inputs`` (batch placement, straggler draw, float64
+        decode-weight solve, device puts), ``trainer.dispatch`` (the jitted
+        call; a fresh executable's trace and compile fall inside it),
+        ``trainer.sync`` (waiting on the metrics), ``trainer.readback``
+        (metrics to floats), and ``trainer.telemetry`` (timed straggler
+        sources only).  ``maybe_checkpoint`` adds ``trainer.checkpoint``
+        when it saves."""
+        with jax.profiler.TraceAnnotation("trainer.inputs"):
+            placed = self.batcher.place(batch)
+            draw = self._source.draw(self._step_count,
+                                     self.code).restrict(self.code.n)
+            stragglers = list(draw.stragglers)
+            times = draw.times
+            part = self._step_partial(stragglers)
+            # a forced-partial step cannot ride the pipelined wire (the
+            # partial executable is synchronous by construction), so it
+            # drops to the sync path for this step only; when the trainer
+            # is *configured* partial, pipelining is already off
+            # (SchemeSpec rejects the combo)
+            pipelined = self.pipelined and not part
+            if (self.pipelined and not pipelined and self._driver is not None
+                    and self._driver.in_flight):
+                # retire the in-flight update before stepping synchronously
+                # — its buffers are valid under the unchanged codec
+                self.params, self.opt_state, _ = self._driver.drain(
+                    self.params, self.opt_state)
+                self._driver = None
+            arts = (self.arts if part == self.partial
+                    and pipelined == self.pipelined
+                    else self._get_arts(self.code, self.schedule, self.packed,
+                                        pipelined=pipelined, partial=part))
+            fn = None
+            fresh = False
+            if not pipelined:
+                shapes = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), placed)
+                keyshape = (self._sig(partial=part, pipelined=pipelined),
+                            tuple(sorted((k, v.shape)
+                                         for k, v in placed.items())))
+                fresh = keyshape not in self._jitted
+                if fresh:
+                    smapped, in_specs, _ = arts.step(shapes)
+                    self._jitted[keyshape] = jax.jit(smapped,
+                                                     donate_argnums=(0, 1))
+                fn = self._jitted[keyshape]
+            inp = make_step_inputs(self.code, stragglers, partial=part)
+            args = [jnp.asarray(inp["W"]), jnp.asarray(inp["mask"]),
+                    jnp.asarray(inp["rho"])]
+            if part:
+                args.append(jnp.asarray(inp["err_factor"]))
+            t0 = time.perf_counter()
+            with jax.sharding.set_mesh(self.mesh):
+                dev_batch = jax.tree.map(jnp.asarray, placed)
+        with (jax.profiler.TraceAnnotation("trainer.dispatch"),
+              jax.sharding.set_mesh(self.mesh)):
             if pipelined:
                 # the driver fills on first use (metrics None — no update
                 # retired yet) and runs overlapped steady steps after; its
@@ -455,46 +471,50 @@ class Trainer:
                 if self._driver is None:
                     self._driver = PipelineDriver(arts)
                 self.params, self.opt_state, metrics = self._driver.step(
-                    self.params, self.opt_state,
-                    jax.tree.map(jnp.asarray, placed), *args)
+                    self.params, self.opt_state, dev_batch, *args)
                 fresh = self._driver.last_fresh
             else:
                 self.params, self.opt_state, metrics = fn(
-                    self.params, self.opt_state,
-                    jax.tree.map(jnp.asarray, placed), *args)
+                    self.params, self.opt_state, dev_batch, *args)
         if metrics is not None:
-            jax.block_until_ready(metrics)
+            with jax.profiler.TraceAnnotation("trainer.sync"):
+                jax.block_until_ready(metrics)
         wall = time.perf_counter() - t0
-        out = ({"loss": float("nan"), "grad_norm": float("nan")}
-               if metrics is None
-               else {k: float(v[0]) for k, v in metrics.items()})
+        with jax.profiler.TraceAnnotation("trainer.readback"):
+            out = ({"loss": float("nan"), "grad_norm": float("nan")}
+                   if metrics is None
+                   else {k: float(v[0]) for k, v in metrics.items()})
         if times is not None:
-            from repro.tune import record_from_times
-            # a fresh executable's first call pays one-time trace+compile:
-            # keep it out of the step-cost calibration (measured_step_s <= 0
-            # is ignored by StepCostBook) while still recording the worker
-            # timings the estimator fits on — and hand the compile wall to
-            # the record so the planner's recompile-amortization charge is
-            # calibrated from real traces.  The returned "step_time_s"
-            # stays the real wall either way.  A pipelined fill call
-            # (metrics None) retires no update, so its wall is not a steady
-            # step cost either.
-            uncal = fresh or metrics is None
-            rec = record_from_times(self._step_count, self.code,
-                                    self.schedule, self.packed, times,
-                                    measured_step_s=0.0 if uncal else wall,
-                                    pipelined=pipelined,
-                                    compile_s=wall if fresh else 0.0)
-            out["step_time_s"] = wall
-            out["modeled_wait_s"] = rec.wait_s
-            if self._tuner is not None:
-                self._tuner.record(rec)
-                new_plan = self._tuner.maybe_replan(
-                    self._step_count, departed=self._departed_workers())
-                if new_plan is not None:
-                    self._apply_plan(new_plan)
-            elif self.telemetry is not None:
-                self.telemetry.append(rec)
+            with jax.profiler.TraceAnnotation("trainer.telemetry"):
+                from repro.tune import record_from_times
+                # a fresh executable's first call pays one-time
+                # trace+compile: keep it out of the step-cost calibration
+                # (measured_step_s <= 0 is ignored by StepCostBook) while
+                # still recording the worker timings the estimator fits on
+                # — and hand the compile wall to the record so the
+                # planner's recompile-amortization charge is calibrated
+                # from real traces.  The returned "step_time_s" stays the
+                # real wall either way.  A pipelined fill call (metrics
+                # None) retires no update, so its wall is not a steady step
+                # cost either.
+                uncal = fresh or metrics is None
+                rec = record_from_times(self._step_count, self.code,
+                                        self.schedule, self.packed, times,
+                                        measured_step_s=0.0 if uncal
+                                        else wall,
+                                        pipelined=pipelined,
+                                        compile_s=wall if fresh else 0.0)
+                out["step_time_s"] = wall
+                out["modeled_wait_s"] = rec.wait_s
+                if self._tuner is not None:
+                    self._tuner.record(rec)
+                    new_plan = self._tuner.maybe_replan(
+                        self._step_count,
+                        departed=self._departed_workers())
+                    if new_plan is not None:
+                        self._apply_plan(new_plan)
+                elif self.telemetry is not None:
+                    self.telemetry.append(rec)
         self._step_count += 1
         self._data_cursor += 1
         self.maybe_checkpoint()

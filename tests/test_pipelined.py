@@ -291,10 +291,9 @@ def test_trainer_swap_drains_in_flight_pipeline():
 
 
 # ------------------------------------------------- executables & memoization
-def test_compiled_memoized_and_instrumented_shares_executable():
-    """StepArtifacts.compiled is memoized per (batch signature, donate) and
-    `instrumented` wraps exactly that executable (`timed.inner`) — the
-    bench's donated steady-state step and the telemetry wrapper must be the
+def test_compiled_memoized_per_signature():
+    """StepArtifacts.compiled is memoized per (batch signature, donate):
+    the bench's donated steady-state step and any later caller get the
     same compilation, not HLO twins."""
     opt = get_optimizer("sgd", 1e-2)
     cfg, arts = _build("gather", "ref", opt)
@@ -302,14 +301,6 @@ def test_compiled_memoized_and_instrumented_shares_executable():
     fn_d = arts.compiled(batch, donate=True)
     assert arts.compiled(batch, donate=True) is fn_d
     assert arts.compiled(batch, donate=False) is not fn_d   # separate key
-    seen = []
-    timed = arts.instrumented(batch, seen.append, donate=True)
-    assert timed.inner is fn_d
-    params = model_api.init(jax.random.PRNGKey(0), cfg)
-    inp = arts.step_inputs([])
-    timed(params, opt.init(params), batch, inp["W"], inp["mask"],
-          inp["rho"])
-    assert len(seen) == 1 and seen[0] > 0.0
 
 
 def test_compiled_pipeline_memoized():

@@ -22,6 +22,7 @@ is off around the compiles (an entry written for a described chip cannot be
 read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,12 +81,20 @@ def _sds(shape, dtype, sharding_):
                                 sharding=sharding_)
 
 
-def _compile_kernel(fn, *args):
-    """Compile ``fn`` for the described chip; the result must hold a Mosaic
-    kernel, and its scratch HBM stays within twice its operands and
-    results (the old (V, m) decode layout asked 64 times its result)."""
+def _holds_kernel(hlo: str, name: str) -> bool:
+    """Whether compiled HLO holds a Mosaic custom call from a
+    ``pallas_call`` named ``name`` (the last part of its op_name path)."""
+    return re.search(r'custom_call_target="tpu_custom_call".*op_name="[^"]*/'
+                     + re.escape(name) + r'/pallas_call"', hlo) is not None
+
+
+def _compile_kernel(name, fn, *args):
+    """Compile ``fn`` for the described chip; the result must hold the
+    Mosaic kernel ``name``, and its scratch HBM stays within twice its
+    operands and results (the old (V, m) decode layout asked 64 times its
+    result)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    assert _holds_kernel(compiled.as_text(), name), name
     ma = compiled.memory_analysis()
     io = ma.argument_size_in_bytes + ma.output_size_in_bytes
     assert ma.temp_size_in_bytes <= 2 * io, (ma.temp_size_in_bytes, io)
@@ -133,7 +142,7 @@ def test_encode_every_coded_leaf(one_chip, no_persistent_cache):
     assert any(18992 in G for _, G, _ in leaves)
     C = _sds((1, CODE.m), jnp.float32, one_chip)
     for name, G, _ in leaves:
-        _compile_kernel(lambda g, c: coded_encode(g, c),
+        _compile_kernel("coded_encode", lambda g, c: coded_encode(g, c),
                         _sds(G, jnp.float32, one_chip), C)
 
 
@@ -146,7 +155,8 @@ def test_encode_acc_into_wire_slot(one_chip, no_persistent_cache):
         _, G, _ = max((x for x in leaves if len(x[1]) == ndim),
                       key=lambda x: np.prod(x[1]))
         acc = _sds(G[2:], jnp.float32, one_chip)
-        _compile_kernel(lambda a, g, c: coded_encode_acc(a, g, c),
+        _compile_kernel("coded_encode_acc",
+                        lambda a, g, c: coded_encode_acc(a, g, c),
                         acc, _sds(G, jnp.float32, one_chip), C)
 
 
@@ -158,6 +168,7 @@ def test_decode_full_wire_bucket(one_chip, no_persistent_cache, schedule):
     assert L > 10**8                       # the step's whole coded gradient
     width = L if schedule == "gather" else L // CODE.n
     compiled = _compile_kernel(
+        "coded_decode",
         lambda f, w: coded_decode(f, w, out_dtype=jnp.float32),
         _sds((CODE.n, width), jnp.float32, one_chip),
         _sds((CODE.n, CODE.m), jnp.float32, one_chip))
@@ -170,7 +181,7 @@ def test_decode_widest_3d_leaf(one_chip, no_persistent_cache):
     """coded_decode on the per-leaf path's widest (n, V, R) stack."""
     _, G, _ = max((x for x in _coded_leaves() if len(x[1]) == 4),
                   key=lambda x: np.prod(x[1]))
-    _compile_kernel(lambda f, w: coded_decode(f, w),
+    _compile_kernel("coded_decode", lambda f, w: coded_decode(f, w),
                     _sds((CODE.n,) + G[2:], jnp.float32, one_chip),
                     _sds((CODE.n, CODE.m), jnp.float32, one_chip))
 
@@ -181,6 +192,7 @@ def test_decode_apply_full_bucket(one_chip, no_persistent_cache):
     L = _bucket().size
     v = _sds((CODE.m, L), jnp.float32, one_chip)
     _compile_kernel(
+        "coded_decode_apply",
         lambda f, w, p, mu: coded_decode_apply(f, w, p, mu, lr=0.1,
                                                momentum=0.9, scale=0.25),
         _sds((CODE.n, L), jnp.float32, one_chip),
@@ -211,7 +223,9 @@ def test_one_chip_coded_step_fits(topo, no_persistent_cache):
              for s in ((code.n, code.m), (code.n,), (code.n, code.d))]
     with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') >= 2
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 2
+    assert _holds_kernel(hlo, "coded_encode")
+    assert _holds_kernel(hlo, "coded_decode")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES

@@ -429,36 +429,6 @@ def test_trainer_autotune_swaps_codec_and_reuses_cache():
     assert len(tr._jitted) == n_jit
 
 
-def test_step_artifacts_instrumented_reports_time():
-    """The coded_step telemetry hook: blocked wall-clock per call."""
-    import jax
-    import jax.numpy as jnp
-    from repro.configs import get_config
-    from repro.core import make_code
-    from repro.data import CodedBatcher, make_synthetic_batch
-    from repro.launch.mesh import make_local_mesh
-    from repro.optim import get_optimizer
-    from repro.train import make_coded_train_step
-
-    cfg = dataclasses.replace(get_config("logistic-paper"), d_model=64)
-    code = make_code(4, 3, 1, 2)
-    opt = get_optimizer("sgd", 1e-2)
-    arts = make_coded_train_step(cfg, code, make_local_mesh(4, 1), opt)
-    rng = np.random.default_rng(0)
-    placed = jax.tree.map(
-        jnp.asarray, CodedBatcher(code).place(
-            make_synthetic_batch(rng, cfg, 16, 0)))
-    from repro.models import api as model_api
-    params = model_api.init(jax.random.PRNGKey(0), cfg)
-    walls = []
-    timed = arts.instrumented(placed, walls.append)
-    inp = arts.step_inputs(())
-    out = timed(params, opt.init(params), placed,
-                inp["W"], inp["mask"], inp["rho"])
-    assert len(out) == 3 and "loss" in out[2]
-    assert len(walls) == 1 and walls[0] > 0
-
-
 def test_trainer_injector_conflicts_with_straggler_mode():
     from repro.configs import get_config
     from repro.core import make_code
