@@ -13,6 +13,7 @@ Conventions
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -217,42 +218,118 @@ def online_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
+# ------------------------------------------------- fused (TPU) attention
+# Rows of q and of kv in one block of the splash kernels, in the forward and
+# in both backward kernels (dq, dkv).  On a TPU v5e at S = 2048 (the
+# qwen3-8b cell), 1024 took 1.1 ms of kernel time a step less than 512, and
+# 256 19 ms more (PERF.md).
+FUSED_BLOCK = 1024
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def attention_path(cfg, S: int, mask_kind: str) -> str:
+    """The path ``self_attention`` takes at sequence length ``S``:
+
+    - ``"fused"``: JAX's splash attention kernels, blockwise with their own
+      backward, so no (S, S) scores reach HBM.  On a TPU, for a causal or
+      sliding-window mask, in whole ``FUSED_BLOCK`` blocks of a head_dim
+      that fills the 128 lanes;
+    - ``"materialized"``: the (S, S) scores, up to ``CHUNK_THRESHOLD``;
+    - ``"online"``: the chunked online softmax beyond it.
+    """
+    if (_on_tpu() and mask_kind in ("causal", "window")
+            and S % FUSED_BLOCK == 0 and cfg.head_dim_ % 128 == 0):
+        return "fused"
+    return "materialized" if S <= CHUNK_THRESHOLD else "online"
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(S: int, g: int, mask_kind: str, window: int, block: int,
+                   interpret: bool):
+    """``make_splash_mqa``'s kernel with its block maps left in numpy: they
+    become constants of whatever program calls it, where ``jnp.array``
+    would place them on a device while that program is being traced."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm,
+        splash_attention_mask_info as mi)
+    if mask_kind == "causal":
+        head = sm.CausalMask((S, S))
+    else:                                    # j in (i - window, i]
+        head = sm.LocalMask((S, S), (window - 1, 0), offset=0)
+    mask = sm.MultiHeadMask([head] * g)
+    fwd, mask_fn = mi.process_mask(mask, (block, block))
+    dkv, _ = mi.process_mask_dkv(mask, (block, block))
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    return sk.SplashAttentionKernel(
+        fwd, fwd, dkv, block_sizes=sizes, is_mqa=True, save_residuals=False,
+        mask_value=sk.DEFAULT_MASK_VALUE, attn_logits_soft_cap=None,
+        residual_checkpoint_name=None, mask_function=mask_fn,
+        interpret=interpret)
+
+
+def fused_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
+                    window: int = 0) -> jax.Array:
+    """GQA self-attention through the splash kernels: one MQA kernel per KV
+    head (its ``q_per_kv`` query heads share it), vmapped over batch and KV
+    heads, in ``FUSED_BLOCK`` blocks.  Blocks the mask leaves empty are
+    skipped.  Logits and softmax statistics are f32 in the kernel; q is
+    scaled by 1/sqrt(hd) before it.  Off a TPU the kernels run in Pallas
+    interpret mode.
+
+    q: (B, S, H, hd); k/v: (B, S, Hkv, hd) -> (B, S, H, hd).
+    """
+    B, S, H, hd = q.shape
+    Hkv = k.shape[2]
+    kernel = _splash_kernel(S, q_per_kv, mask_kind, window, FUSED_BLOCK,
+                            not _on_tpu())
+    qs = (q.astype(jnp.float32) * (1.0 / np.sqrt(hd))).astype(q.dtype)
+    qs = qs.reshape(B, S, Hkv, q_per_kv, hd).transpose(0, 2, 3, 1, 4)
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    out = jax.vmap(jax.vmap(lambda a, b, c: kernel(a, b, c)))(qs, kt, vt)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
+
+
+def _attend(q, k, v, cfg, path: str, mask_kind: str, window: int):
+    if path == "fused":
+        return fused_attention(q, k, v, cfg.q_per_kv, mask_kind=mask_kind,
+                               window=window)
+    if path == "online":
+        return online_attention(q, k, v, cfg.q_per_kv, mask_kind=mask_kind,
+                                window=window)
+    S = q.shape[1]
+    if mask_kind == "causal":
+        mask = causal_mask(S)
+    elif mask_kind == "window":
+        mask = sliding_causal_mask(S, window)
+    else:
+        mask = jnp.ones((S, S), bool)
+    return gqa_scores_attend(q, k, v, mask, cfg.q_per_kv)
+
+
 def self_attention(p: dict, cfg, x: jax.Array, pos: jax.Array, *,
                    mask_kind: str = "causal", window: int = 0) -> jax.Array:
-    """Mask-kind self-attention that picks the materialized path for short
-    sequences and the chunked online-softmax path for long ones."""
-    B, S, _ = x.shape
+    """Mask-kind self-attention on the path ``attention_path`` picks."""
+    S = x.shape[1]
     q, k, v = qkv_project(p, cfg, x, pos)
-    if S <= CHUNK_THRESHOLD:
-        if mask_kind == "causal":
-            mask = causal_mask(S)
-        elif mask_kind == "window":
-            mask = sliding_causal_mask(S, window)
-        else:
-            mask = jnp.ones((S, S), bool)
-        out = gqa_scores_attend(q, k, v, mask, cfg.q_per_kv)
-    else:
-        out = online_attention(q, k, v, cfg.q_per_kv, mask_kind=mask_kind,
-                               window=window)
+    out = _attend(q, k, v, cfg, attention_path(cfg, S, mask_kind), mask_kind,
+                  window)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
 
 
 def self_attention_with_kv(p: dict, cfg, x: jax.Array, pos: jax.Array, *,
                            mask_kind: str = "causal", window: int = 0):
-    """Like self_attention but also returns (k, v) for prefill caching."""
-    B, S, _ = x.shape
+    """Like self_attention but also returns (k, v) for prefill caching; it
+    takes the materialized or the online path, never the fused one."""
+    S = x.shape[1]
     q, k, v = qkv_project(p, cfg, x, pos)
-    if S <= CHUNK_THRESHOLD:
-        if mask_kind == "causal":
-            mask = causal_mask(S)
-        elif mask_kind == "window":
-            mask = sliding_causal_mask(S, window)
-        else:
-            mask = jnp.ones((S, S), bool)
-        out = gqa_scores_attend(q, k, v, mask, cfg.q_per_kv)
-    else:
-        out = online_attention(q, k, v, cfg.q_per_kv, mask_kind=mask_kind,
-                               window=window)
+    path = "materialized" if S <= CHUNK_THRESHOLD else "online"
+    out = _attend(q, k, v, cfg, path, mask_kind, window)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, k, v
 

@@ -13,7 +13,10 @@ coded step the way a chip run would, at no chip time:
   accumulating encode into a wire slot, the decode of a whole wire bucket
   and of the widest 3-D leaf, and the fused decode-and-apply of a bucket;
 - the whole one-chip step of ``chip_smoke.py`` (code (1, 1, 0, 1), AdamW,
-  4 x 2048 tokens) on the compiled kernels, which must fit the chip.
+  4 x 2048 tokens) on the compiled kernels, which must fit the chip;
+- a model's forward and backward at the coded cell's attention shape
+  (2 x 2048 tokens, 16 query and 8 KV heads of 128, bf16) on the fused
+  splash attention kernels, with no (S, S) scores left in the program.
 
 A compile that passes is not a chip run: nothing here executes.  The
 topology is described inside a module fixture (never at import, so every
@@ -21,6 +24,7 @@ xdist worker collects the same tests), and the persistent compilation cache
 is off around the compiles (an entry written for a described chip cannot be
 read back without one).
 """
+import dataclasses
 import os
 import re
 
@@ -39,7 +43,7 @@ from repro.core import make_code
 from repro.data import CodedBatcher, make_synthetic_batch
 from repro.kernels import (coded_decode, coded_decode_apply, coded_encode,
                            coded_encode_acc)
-from repro.models import api as model_api
+from repro.models import api as model_api, common as cm
 from repro.optim import get_optimizer
 from repro.train import sharding
 from repro.train.coded_step import make_coded_train_step
@@ -86,6 +90,13 @@ def _holds_kernel(hlo: str, name: str) -> bool:
     ``pallas_call`` named ``name`` (the last part of its op_name path)."""
     return re.search(r'custom_call_target="tpu_custom_call".*op_name="[^"]*/'
                      + re.escape(name) + r'/pallas_call"', hlo) is not None
+
+
+def _holds_named_call(hlo: str, name: str) -> bool:
+    """Whether compiled HLO holds a Mosaic custom call whose instruction is
+    named for the kernel (``%name.<n> = ... custom-call``)."""
+    return re.search(r"%" + re.escape(name) + r"(\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', hlo) is not None
 
 
 def _compile_kernel(name, fn, *args):
@@ -229,3 +240,30 @@ def test_one_chip_coded_step_fits(topo, no_persistent_cache):
     assert _holds_kernel(hlo, "coded_decode")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+
+def test_model_attention_on_splash_kernels(one_chip, no_persistent_cache,
+                                           monkeypatch):
+    """With the platform check answering "tpu", one layer's forward and
+    backward at the coded cell's attention shape compiles for one chip with
+    the splash forward (run twice: forward and remat recompute), dq and dkv
+    kernels, and no (B, Hkv, g, S, S) scores or mask anywhere."""
+    monkeypatch.setattr(cm, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("qwen3-1.7b").cut(1, 8),
+                              compute_dtype="bfloat16")
+    B, S = 2, 2048
+    assert cm.attention_path(cfg, S, "causal") == "fused"
+    pshapes = jax.eval_shape(lambda: model_api.init(jax.random.PRNGKey(0),
+                                                    cfg))
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), pshapes)
+    tokens = _sds((B, S), jnp.int32, one_chip)
+    loss = model_api.make_loss(cfg)
+
+    def grad(p, t):
+        return jax.value_and_grad(loss)(p, {"tokens": t, "labels": t})
+
+    hlo = jax.jit(grad).lower(params, tokens).compile().as_text()
+    for name in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                 "splash_mqa_dkv_no_residuals"):
+        assert _holds_named_call(hlo, name), name
+    assert not re.search(rf"\[{B},{cfg.n_kv_heads},[\d,]*{S},{S}", hlo)
