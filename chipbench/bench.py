@@ -1,17 +1,21 @@
 """One run of one cell: set-up, the measured (or traced) window, the
 comparison with the reference, and the result.
 
+The cell's model type (its configuration's ``model_type``) is resolved
+once, to the modules of ``models/<model_type>/`` (``model_of``).
 Set-up builds the cell's ``Trainer`` (``program.py``), drives it through
 its first three steps with the run's own feed (these compile the step and
 give the comparison its readings), then hands the same trainer to the
 window.  ``--trace 0`` times back-to-back steps for the window's length;
 ``--trace 1`` traces a few steps and reduces the trace (``reduce_trace.py``) to
 the cell's per-layer metrics, each read by ``metrics/<name>.py``.  After
-the window the trainer is freed and the reference runs on the first chip.
+the window the trainer is freed and the reference (``reference.py``
+around the model type's own) runs on the first chip.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import gzip
 import importlib.util
@@ -20,9 +24,11 @@ import math
 import os
 import pathlib
 import platform
+import re
 import shutil
 import sys
 import time
+from typing import Callable
 
 import numpy as np
 
@@ -34,6 +40,8 @@ import reduce_trace as tracing
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
+MODELS = HERE / "models"
+MODEL_PARTS = ("program", "reference", "counts")
 # a fixed path inside the checkout: JAX's persistent cache keys on it
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = HERE / ".traces"
@@ -45,9 +53,58 @@ class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class Model:
+    """What the benchmark knows of one model type, from the modules of
+    ``models/<model_type>/`` (``run.py`` says what each defines).  Hashed
+    by identity: the reference keeps its compiled programs per model."""
+    model_type: str
+    model_config: Callable      # program.model_config(config)
+    dims: Callable              # reference.Dims.from_config(config)
+    init_params: Callable       # reference.init_params(seed, dims)
+    sequence_loss: Callable     # reference.sequence_loss(params, tokens,
+                                #     labels, dims, quant=None)
+    toy: dict                   # reference.TOY
+    total_params: Callable      # counts.total_params(config)
+    flops_per_token: Callable   # counts.flops_per_token(config, seq_len)
+    kernels: Callable           # counts.kernels(config, traffic), or none
+
+
+def _module(path: pathlib.Path):
+    name = "chipbench_models_" + re.sub(r"\W", "_", str(path))
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod     # dataclasses look their module up here
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _model_at(where: pathlib.Path) -> Model:
+    prog, ref, cnt = (_module(where / f"{p}.py") for p in MODEL_PARTS)
+    return Model(where.name, prog.model_config, ref.Dims.from_config,
+                 ref.init_params, ref.sequence_loss, ref.TOY,
+                 cnt.total_params, cnt.flops_per_token,
+                 getattr(cnt, "kernels", lambda config, traffic: {}))
+
+
+def model_of(config: dict, root: pathlib.Path = MODELS) -> Model:
+    """The configuration's model type: the directory ``root/<model_type>/``
+    and its modules, loaded once; raises where there is none."""
+    kind = config["model_type"]
+    where = root / kind
+    if not (re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", kind)
+            and all((where / f"{p}.py").is_file() for p in MODEL_PARTS)):
+        raise KeyError(f"{config['name']}: no model type {kind!r}: "
+                       f"{where} holds no "
+                       + ", ".join(f"{p}.py" for p in MODEL_PARTS))
+    return _model_at(where)
+
+
 @dataclasses.dataclass
 class Cell:
-    """A workload of ``BENCHMARK.json`` with its files read."""
+    """A workload of ``BENCHMARK.json`` with its files read and its model
+    type resolved."""
     name: str
     chips: int
     config: dict
@@ -55,6 +112,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    model: Model
 
 
 def read_benchmark() -> dict:
@@ -63,7 +121,8 @@ def read_benchmark() -> dict:
 
 def load_cell(name: str, b: dict | None = None) -> Cell:
     """The workload ``name`` of ``b`` (default: ``BENCHMARK.json``) and its
-    configuration, traffic, limits and metrics, found by name."""
+    configuration, model type, traffic, limits and metrics, found by
+    name."""
     b = b or read_benchmark()
     work = {w["name"]: w for w in b["workloads"]}
     if name not in work:
@@ -83,7 +142,8 @@ def load_cell(name: str, b: dict | None = None) -> Cell:
     if traffic["chips"] != w["chips"]:
         raise ValueError(f"{name}: traffic {w['traffic']} runs on "
                          f"{traffic['chips']} chips, the cell asks {w['chips']}")
-    return Cell(name, w["chips"], config, traffic, limits, e2e, per_layer)
+    return Cell(name, w["chips"], config, traffic, limits, e2e, per_layer,
+                model_of(config))
 
 
 def derive_seeds(seed: int) -> tuple[int, int, int]:
@@ -95,12 +155,12 @@ def derive_seeds(seed: int) -> tuple[int, int, int]:
 
 class Feed:
     """The cell's batches from the data seed: rows of uniform tokens over
-    the vocabulary, labels the next token.  Every step gets new rows."""
+    the ``vocab`` rows, labels the next token.  Every step gets new rows."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, vocab: int, traffic: dict, seed: int):
         self.rows = traffic["code"]["n"] * traffic["sequences_per_subset"]
         self.seq = traffic["seq_len"]
-        self.vocab = config["vocab_size"]
+        self.vocab = vocab
         self.rng = np.random.default_rng(seed)
 
     def next(self) -> dict[str, np.ndarray]:
@@ -169,8 +229,8 @@ def peak_bytes(devices) -> int:
     return int(max(peaks))
 
 
-def program_readings(trainer, feed: Feed, k: reference.Dims,
-                     weight_seed: int, b1: float) -> reference.Readings:
+def program_readings(trainer, feed: Feed, model: Model, k, weight_seed: int,
+                     b1: float) -> reference.Readings:
     """Set-up's first steps through the window's own call and feed: each
     step's loss, the first gradient from AdamW's state after one step, and
     each leaf's change after the last."""
@@ -183,7 +243,8 @@ def program_readings(trainer, feed: Feed, k: reference.Dims,
         if t == 0:
             grads = {n: float(v) / (1 - b1) for n, v in
                      norms(program.first_moment(trainer)).items()}
-    changes = reference.change_norms(weight_seed, k, program.params(trainer))
+    changes = reference.change_norms(model, weight_seed, k,
+                                     program.params(trainer))
     return reference.Readings(losses, grads, changes)
 
 
@@ -249,19 +310,51 @@ class ReadContext:
         self.n_chips = cell.chips
         self.peak = peak
         self.tokens_per_step = program.unique_tokens(cell.traffic)
-        self.flops_per_token = counts.flops_per_token(
+        self.flops_per_token = cell.model.flops_per_token(
             cell.config, cell.traffic["seq_len"])
-        self.kernels = counts.kernel_work(cell.config, cell.traffic["code"])
+        self.kernels = counts.kernel_work(cell.model.total_params(cell.config),
+                                          cell.traffic["code"])
+        self.model_kernels = cell.model.kernels(cell.config, cell.traffic)
+
+    def _share(self, nbytes: float, flops: float, times: list[float]):
+        """Mean over the chips that spent time on a kernel of the least
+        time its bytes or flops take a step, over that time."""
+        least = max(nbytes / self.peak["hbm_bytes_per_s"],
+                    flops / self.peak["bf16_flops_per_s"]) * self.trace.steps
+        shares = [least / t for t in times if t > 0]
+        return 100.0 * sum(shares) / len(shares) if shares else None
 
     def roofline(self, kind: str):
         """Share of the roofline of a codec kernel, mean over the chips
         whose trace holds it; None where none does."""
         nbytes, flops = self.kernels[kind]
-        least = max(nbytes / self.peak["hbm_bytes_per_s"],
-                    flops / self.peak["bf16_flops_per_s"]) * self.trace.steps
-        shares = [least / c.by_kind[kind] for c in self.trace.chips
-                  if c.by_kind[kind] > 0]
-        return 100.0 * sum(shares) / len(shares) if shares else None
+        return self._share(nbytes, flops,
+                           [c.by_kind[kind] for c in self.trace.chips])
+
+    def roofline_of(self, name: str):
+        """Share of the roofline of a kernel that the model type's
+        ``counts.kernels`` declares, its time the ops whose names match the
+        declared pattern; None where the model declares no such kernel or
+        no chip's trace holds it."""
+        if name not in self.model_kernels:
+            return None
+        pattern, nbytes, flops = self.model_kernels[name]
+        match = re.compile(pattern).search
+        return self._share(nbytes, flops, [
+            sum(t for op, t in c.by_op.items() if match(op))
+            for c in self.trace.chips])
+
+    def time_in_scope(self, scope: str):
+        """Milliseconds a step, mean over chips, of the ops whose innermost
+        named scope is ``scope`` (``<layer>.<phase>``) or, for a bare
+        ``<layer>``, any of its phases; None where no chip's trace holds
+        one."""
+        times = [sum(t for s, t in c.by_scope.items()
+                     if s == scope or s.split(".")[0] == scope)
+                 for c in self.trace.chips]
+        if not any(times) or self.trace.steps == 0:
+            return None
+        return 1e3 * sum(times) / len(times) / self.trace.steps
 
 
 def peaks_for(kind: str) -> dict:
@@ -345,13 +438,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     log = CompileLog()
     print(f"env: {json.dumps(capture_env())}", file=err)
     wseed, dseed, sseed = derive_seeds(seed)
-    k = reference.Dims.from_config(cell.config)
+    model = cell.model
+    k = model.dims(cell.config)
     opt = cell.traffic["optimizer"]
-    trainer = program.build_trainer(cell.config, cell.traffic,
-                                    weight_seed=wseed, straggler_seed=sseed,
-                                    backend=backend)
-    feed = Feed(cell.config, cell.traffic, dseed)
-    prog = program_readings(trainer, feed, k, wseed, opt["b1"])
+    trainer = program.build_trainer(model.model_config(cell.config),
+                                    cell.traffic, weight_seed=wseed,
+                                    straggler_seed=sseed, backend=backend)
+    feed = Feed(k.vocab, cell.traffic, dseed)
+    prog = program_readings(trainer, feed, model, k, wseed, opt["b1"])
     tokens = program.unique_tokens(cell.traffic)
     setup_s = time.perf_counter() - t_start
     print(f"set-up: {setup_s:.6f} s; backend compile {log.compile_s:.6f} s "
@@ -378,9 +472,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     gc.collect()
 
     t_ref = time.perf_counter()
-    replay = Feed(cell.config, cell.traffic, dseed)
+    replay = Feed(k.vocab, cell.traffic, dseed)
     batches = [replay.next() for _ in range(FIRST_STEPS)]
-    ref = reference.train_readings(wseed, k, batches, opt, device=used[0])
+    ref = reference.train_readings(model, wseed, k, batches, opt,
+                                   device=used[0])
     numbers = check.gaps(prog, ref)
     correct, shown = check.verdict(numbers, cell.limits)
     correct = correct and failed == 0

@@ -1,7 +1,7 @@
 """Readings that the limits of a cell's comparison are set from, on the chip
 at the cell's own size.  The benchmark's own runs never run this.
 
-  python3 chipbench/calibrate.py --workload qwen3-8b.worker \\
+  python3 chipbench/calibrate.py --workload <cell> \\
       --seeds 101,102,...,112 --control 101,102,103 --out readings.json
 
 For each of ``--seeds``: the program's first steps exactly as a run's
@@ -17,6 +17,7 @@ decodes its own encoding in the place of all n).  A state left unchanged
 reads 1 on ``update_norm_gap`` and needs no run.
 """
 import argparse
+import dataclasses
 import gc
 import json
 import pathlib
@@ -37,12 +38,14 @@ def _seeds(text: str) -> list[int]:
 
 def program_side(cell, seed: int):
     wseed, dseed, sseed = bench.derive_seeds(seed)
-    trainer = program.build_trainer(cell.config, cell.traffic,
-                                    weight_seed=wseed, straggler_seed=sseed)
-    feed = bench.Feed(cell.config, cell.traffic, dseed)
-    readings = bench.program_readings(
-        trainer, feed, reference.Dims.from_config(cell.config), wseed,
-        cell.traffic["optimizer"]["b1"])
+    model = cell.model
+    k = model.dims(cell.config)
+    trainer = program.build_trainer(model.model_config(cell.config),
+                                    cell.traffic, weight_seed=wseed,
+                                    straggler_seed=sseed)
+    feed = bench.Feed(k.vocab, cell.traffic, dseed)
+    readings = bench.program_readings(trainer, feed, model, k, wseed,
+                                      cell.traffic["optimizer"]["b1"])
     del trainer
     gc.collect()
     return readings
@@ -50,23 +53,23 @@ def program_side(cell, seed: int):
 
 def reference_side(cell, seed: int, **kw):
     wseed, dseed, _ = bench.derive_seeds(seed)
-    feed = bench.Feed(cell.config, cell.traffic, dseed)
+    k = cell.model.dims(cell.config)
+    feed = bench.Feed(k.vocab, cell.traffic, dseed)
     batches = [feed.next() for _ in range(bench.FIRST_STEPS)]
-    return reference.train_readings(
-        wseed, reference.Dims.from_config(cell.config), batches,
-        cell.traffic["optimizer"], **kw)
+    return reference.train_readings(cell.model, wseed, k, batches,
+                                    cell.traffic["optimizer"], **kw)
 
 
 def altered_side(cell, seed: int):
-    """The reference with its answer altered where it is produced."""
-    real = reference.sequence_loss
-    reference.sequence_loss = lambda *a, **kw: real(*a, **kw) * 1.01
-    reference._programs.cache_clear()
+    """The reference with its answer altered where it is produced: the
+    cell's model with every sequence's loss one part in a hundred high."""
+    real = cell.model.sequence_loss
+    altered = dataclasses.replace(
+        cell.model, sequence_loss=lambda *a, **kw: real(*a, **kw) * 1.01)
     try:
-        return reference_side(cell, seed)
+        return reference_side(dataclasses.replace(cell, model=altered), seed)
     finally:
-        reference.sequence_loss = real
-        reference._programs.cache_clear()
+        reference._programs.cache_clear()   # the altered programs
 
 
 def main() -> None:

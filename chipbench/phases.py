@@ -8,12 +8,11 @@ its device work with named scopes (``repro.train.coded_step``:
 ``coded.grad``, ``coded.encode``, ``coded.exchange``, ``coded.decode``,
 ``coded.apply``).  A scope reaches a TPU trace inside each op's name path
 (the ``tf_op`` stat of an ``XLA Ops`` event); an op belongs to the
-innermost ``coded.*`` part of that path, and to ``""`` where it has none.
+innermost ``<layer>.<phase>`` part of that path, and to ``""`` where it has
+none: the fifth field ``reduce_trace.extract`` gives each device op.
 
 Built on ``reduce_trace``'s lists and rules:
 
-``extract(path)``  ``reduce_trace.extract`` with each device op's scope
-                   as a fifth field.
 ``reduce(extracted)``  per chip, self time inside the window by
                    scope (``by_scope``), and for each pair of consecutive
                    step programs (the pairs ``step_gaps_s`` uses) the
@@ -36,7 +35,6 @@ import argparse
 import dataclasses
 import gzip
 import json
-import re
 import sys
 
 import reduce_trace as rt
@@ -45,8 +43,6 @@ SPANS = ("trainer.inputs", "trainer.dispatch", "trainer.sync",
          "trainer.readback", "trainer.telemetry", "trainer.checkpoint")
 SCOPES = ("coded.grad", "coded.encode", "coded.exchange", "coded.decode",
           "coded.apply")
-SCOPE = re.compile(r"coded\.[A-Za-z_]+")
-PATH_STAT = "tf_op"
 # per-layer numbers: a device phase's self time per step, or a host span's
 # cover of the gap between two step programs
 BY_SCOPE = {"fwd_bwd_ms": "coded.grad", "apply_ms": "coded.apply"}
@@ -54,107 +50,6 @@ BY_SPAN = {"gap_sync_ms": "trainer.sync",
            "gap_readback_ms": "trainer.readback",
            "gap_inputs_ms": "trainer.inputs",
            "gap_dispatch_ms": "trainer.dispatch"}
-
-
-# ------------------------------------------------------------------ extract
-def scope_of(path: str) -> str:
-    """The innermost ``coded.*`` part of an op's name path, or ``""``."""
-    found = SCOPE.findall(path)
-    return found[-1] if found else ""
-
-
-# ``jax.profiler.ProfileData`` gives an event its own stats only; an op's
-# name path is a stat of the op's metadata, which all its events share.  So
-# the device planes' op metadata is read from the ``.xplane.pb`` here, with
-# the few fields of the XPlane proto that hold it (tsl's xplane.proto:
-# XSpace.planes 1; XPlane.name 2, .event_metadata 4, .stat_metadata 5; a map
-# entry's key 1 and value 2; XEventMetadata.name 2, .stats 5;
-# XStatMetadata.name 2; XStat.metadata_id 1, .str_value 5, .ref_value 7).
-def _varint(buf, i: int) -> tuple[int, int]:
-    shift = value = 0
-    while True:
-        b = buf[i]
-        i += 1
-        value |= (b & 0x7F) << shift
-        if b < 0x80:
-            return value, i
-        shift += 7
-
-
-def _fields(buf):
-    """(field number, value) of a protobuf message: varints as ints,
-    length-delimited fields as memoryviews; fixed-width fields skipped."""
-    i = 0
-    while i < len(buf):
-        key, i = _varint(buf, i)
-        field, wire = key >> 3, key & 7
-        if wire == 0:
-            value, i = _varint(buf, i)
-        elif wire == 2:
-            size, i = _varint(buf, i)
-            value, i = buf[i:i + size], i + size
-        elif wire in (1, 5):
-            i += 8 if wire == 1 else 4
-            continue
-        else:
-            raise ValueError(f"protobuf wire type {wire} at byte {i}")
-        yield field, value
-
-
-def _entry(buf) -> tuple[int, memoryview]:
-    f = dict(_fields(buf))
-    return f.get(1, 0), f.get(2, memoryview(b""))
-
-
-def op_scopes(path: str) -> dict[str, dict[str, str]]:
-    """Per chip, each device op's name -> the innermost ``coded.*`` scope
-    of its name path (its metadata's ``tf_op`` stat)."""
-    import pathlib
-
-    out = {}
-    space = memoryview(pathlib.Path(path).read_bytes())
-    for field, plane in _fields(space):
-        if field != 1:
-            continue
-        name, events, stat_names = "", [], {}
-        for f, v in _fields(plane):
-            if f == 2:
-                name = bytes(v).decode()
-            elif f == 4:
-                events.append(v)
-            elif f == 5:
-                key, meta = _entry(v)
-                stat_names[key] = bytes(dict(_fields(meta)).get(2, b"")
-                                        ).decode()
-        m = rt.DEVICE_PLANE.match(name)
-        if not m:
-            continue
-        scopes: dict[str, str] = {}
-        for v in events:
-            meta = _fields(_entry(v)[1])
-            op, path_ = "", ""
-            for f, x in meta:
-                if f == 2:
-                    op = bytes(x).decode()
-                elif f == 5:
-                    stat = dict(_fields(x))
-                    if stat_names.get(stat.get(1, 0)) == PATH_STAT:
-                        path_ = (bytes(stat[5]).decode() if 5 in stat
-                                 else stat_names.get(stat.get(7, 0), ""))
-            scopes[op] = scopes.get(op) or scope_of(path_)
-        out[m.group(1)] = scopes
-    return out
-
-
-def extract(path: str) -> dict:
-    """``reduce_trace.extract(path)``, each op ``[name, start, end, kind,
-    scope]``."""
-    out = rt.extract(path)
-    scopes = op_scopes(path)
-    for key, chip in out["chips"].items():
-        for op in chip["ops"]:
-            op.append(scopes.get(key, {}).get(op[0], ""))
-    return out
 
 
 # ------------------------------------------------------------------- reduce
@@ -331,7 +226,7 @@ def main(argv=None) -> int:
                     "and the summary here (.json.gz)")
     args = ap.parse_args(argv)
     if args.trace.endswith(".xplane.pb"):
-        extracted = extract(args.trace)
+        extracted = rt.extract(args.trace)
     else:
         with gzip.open(args.trace, "rt") as f:
             extracted = json.load(f)

@@ -1,8 +1,9 @@
 """The system under test, built from a cell's files.
 
-This is the one module of the benchmark that imports the program
-(``repro``).  It builds the ``Trainer`` the way ``repro.launch.train.build``
-does, from the configuration and traffic files instead of flags, and reads
+This module and each model type's ``models/<model_type>/program.py`` are
+the benchmark's only imports of the program (``repro``).  It builds the
+``Trainer`` the way ``repro.launch.train.build`` does, from the model
+type's ``ModelConfig`` and the traffic file instead of flags, and reads
 back what the comparison needs from the trainer's own state.  Everything
 else the benchmark measures with is its own.
 """
@@ -11,37 +12,11 @@ from __future__ import annotations
 import jax
 
 
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a Qwen3 configuration file, at the
-    precision the file states."""
-    from repro.configs import ModelConfig
-
-    if config["model_type"] != "qwen3" or config["hidden_act"] != "silu" \
-            or config["attention_bias"] or config["tie_word_embeddings"]:
-        raise ValueError(f"{config['name']}: the program runs untied qwen3 "
-                         f"SwiGLU decoders without attention bias")
-    if float(config["rms_norm_eps"]) != 1e-6:
-        raise ValueError(f"{config['name']}: the program's RMSNorm has "
-                         f"eps 1e-6")
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        head_dim=config["head_dim"], qk_norm=True,
-        rope_theta=float(config["rope_theta"]),
-        param_dtype=config["precision"]["params"],
-        compute_dtype=config["precision"]["activations"].split()[0],
-        source=config["source"])
-
-
-def build_trainer(config: dict, traffic: dict, *, weight_seed: int,
+def build_trainer(model_config, traffic: dict, *, weight_seed: int,
                   straggler_seed: int, backend: str | None = None):
-    """The cell's ``Trainer``: its model, code, mesh over the first
-    ``code.n`` devices, AdamW and straggler source.  ``backend`` overrides
-    the traffic's codec backend (tests on the CPU)."""
+    """The cell's ``Trainer`` of the program's ``model_config``: its code,
+    mesh over the first ``code.n`` devices, AdamW and straggler source.
+    ``backend`` overrides the traffic's codec backend (tests on the CPU)."""
     from repro import coding
     from repro.core import make_code
     from repro.launch.mesh import make_local_mesh
@@ -57,7 +32,7 @@ def build_trainer(config: dict, traffic: dict, *, weight_seed: int,
     source = {"none": NoStragglers,
               "random": lambda: RandomStragglers(seed=straggler_seed)}[
         traffic["stragglers"]]()
-    return Trainer(model_config(config), code, make_local_mesh(c["n"], 1),
+    return Trainer(model_config, code, make_local_mesh(c["n"], 1),
                    adamw(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"]),
                    spec=coding.SchemeSpec(
                        schedule=traffic["schedule"],
@@ -86,4 +61,3 @@ def unique_tokens(traffic: dict) -> int:
     recompute it."""
     return (traffic["code"]["n"] * traffic["sequences_per_subset"]
             * traffic["seq_len"])
-

@@ -7,19 +7,23 @@ repository:
 ``jax.profiler.ProfileData`` and keeps, per TPU chip, the device
 operations (line "XLA Ops") and program executions (line "XLA Modules"),
 and the host's events, as plain lists ``[name, start_ns, end_ns]`` on the
-profiler's one clock, plus the benchmark's window annotation.
+profiler's one clock, plus the benchmark's window annotation.  A device
+op also keeps its HLO category and its named scope: the innermost part of
+its name path of the form ``<layer>.<phase>`` (``jax.named_scope``, such
+as ``coded.grad``), or ``""`` where it has none.
 
 ``reduce(extracted, steps)`` attributes each chip's time inside the window
 to the innermost operation running (self time, so an operation that
 encloses others, such as a loop, counts only its own part) and sums it by
 kind: the codec kernels (by the name XLA gives their custom calls,
 ``coded_encode*`` and ``coded_decode*``), collectives (by HLO opcode or
-name), and everything else ("compute").  Busy time is the union of the
-operations' intervals; the rest of the window is idle.
+name), and everything else ("compute"); and by named scope.  Busy time is
+the union of the operations' intervals; the rest of the window is idle.
 """
 from __future__ import annotations
 
 import dataclasses
+import pathlib
 import re
 
 WINDOW = "chipbench.window"
@@ -29,6 +33,10 @@ COLLECTIVE = re.compile(
     r"all-gather|all-reduce|all-to-all|reduce-scatter|collective-permute|"
     r"all_gather|all_reduce|all_to_all|psum|ppermute|send|recv")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+# a named scope in an op's name path: a whole word <layer>.<phase>, also
+# inside a transform's parentheses, as in "transpose(jvp(coded.grad))"
+SCOPE = re.compile(r"(?<![\w.])[A-Za-z][A-Za-z0-9_]*\.[A-Za-z_][A-Za-z0-9_]*")
+PATH_STAT = "tf_op"
 
 
 # ------------------------------------------------------------------ extract
@@ -39,8 +47,97 @@ def _stat(ev, key):
     return None
 
 
+def scope_of(path: str) -> str:
+    """The innermost ``<layer>.<phase>`` part of an op's name path, or
+    ``""``."""
+    found = SCOPE.findall(path)
+    return found[-1] if found else ""
+
+
+# ``jax.profiler.ProfileData`` gives an event its own stats only; an op's
+# name path is a stat of the op's metadata, which all its events share.  So
+# the device planes' op metadata is read from the ``.xplane.pb`` here, with
+# the few fields of the XPlane proto that hold it (tsl's xplane.proto:
+# XSpace.planes 1; XPlane.name 2, .event_metadata 4, .stat_metadata 5; a map
+# entry's key 1 and value 2; XEventMetadata.name 2, .stats 5;
+# XStatMetadata.name 2; XStat.metadata_id 1, .str_value 5, .ref_value 7).
+def _varint(buf, i: int) -> tuple[int, int]:
+    shift = value = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of a protobuf message: varints as ints,
+    length-delimited fields as memoryviews; fixed-width fields skipped."""
+    i = 0
+    while i < len(buf):
+        key, i = _varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        elif wire in (1, 5):
+            i += 8 if wire == 1 else 4
+            continue
+        else:
+            raise ValueError(f"protobuf wire type {wire} at byte {i}")
+        yield field, value
+
+
+def _entry(buf) -> tuple[int, memoryview]:
+    f = dict(_fields(buf))
+    return f.get(1, 0), f.get(2, memoryview(b""))
+
+
+def op_scopes(path: str) -> dict[str, dict[str, str]]:
+    """Per chip, each device op's name -> the innermost named scope of its
+    name path (its metadata's ``tf_op`` stat)."""
+    out = {}
+    space = memoryview(pathlib.Path(path).read_bytes())
+    for field, plane in _fields(space):
+        if field != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for f, v in _fields(plane):
+            if f == 2:
+                name = bytes(v).decode()
+            elif f == 4:
+                events.append(v)
+            elif f == 5:
+                key, meta = _entry(v)
+                stat_names[key] = bytes(dict(_fields(meta)).get(2, b"")
+                                        ).decode()
+        m = DEVICE_PLANE.match(name)
+        if not m:
+            continue
+        scopes: dict[str, str] = {}
+        for v in events:
+            meta = _fields(_entry(v)[1])
+            op, path_ = "", ""
+            for f, x in meta:
+                if f == 2:
+                    op = bytes(x).decode()
+                elif f == 5:
+                    stat = dict(_fields(x))
+                    if stat_names.get(stat.get(1, 0)) == PATH_STAT:
+                        path_ = (bytes(stat[5]).decode() if 5 in stat
+                                 else stat_names.get(stat.get(7, 0), ""))
+            scopes[op] = scopes.get(op) or scope_of(path_)
+        out[m.group(1)] = scopes
+    return out
+
+
 def extract(path: str) -> dict:
-    """The events the reduction needs, as JSON-able lists."""
+    """The events the reduction needs, as JSON-able lists; a device op is
+    ``[name, start, end, category, scope]``."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -70,6 +167,10 @@ def extract(path: str) -> dict:
                         window = item[1:]
                     elif ev.duration_ns > 0:
                         host.append(item)
+    scopes = op_scopes(path) if chips else {}
+    for key, chip in chips.items():
+        for op in chip["ops"]:
+            op.append(scopes.get(key, {}).get(op[0], ""))
     return {"chips": chips, "host": host, "window": window}
 
 
@@ -141,6 +242,7 @@ class Chip:
     busy_s: float
     by_kind: dict[str, float]       # encode / decode / collective / compute
     by_op: dict[str, float]
+    by_scope: dict[str, float]      # named scopes only (an op's fifth field)
     step_gaps_s: list[float]        # idle between consecutive step programs
     gaps: list[tuple[int, int]]     # idle intervals inside the window (ns)
 
@@ -155,8 +257,8 @@ class Reduced:
 
 
 def reduce(extracted: dict, steps: int) -> Reduced:
-    """Per-chip busy time, self time by kind and by operation, and the
-    idle gaps, inside the benchmark's window annotation."""
+    """Per-chip busy time, self time by kind, by operation and by named
+    scope, and the idle gaps, inside the benchmark's window annotation."""
     lo, hi = extracted["window"]
     chips = []
     for key in sorted(extracted["chips"], key=int):
@@ -168,8 +270,12 @@ def reduce(extracted: dict, steps: int) -> Reduced:
         by_op = {n: t / 1e9 for n, t in self_times(ops, lo, hi).items()}
         by_kind = {"encode": 0.0, "decode": 0.0, "collective": 0.0,
                    "compute": 0.0}
+        scopes = {op[0]: op[4] for op in ops if len(op) > 4 and op[4]}
+        by_scope: dict[str, float] = {}
         for n, t in by_op.items():
             by_kind[kind_of(n, cats.get(n, ""))] += t
+            if n in scopes:
+                by_scope[scopes[n]] = by_scope.get(scopes[n], 0.0) + t
         spans = sorted((s, e) for _, s, e, *_ in ops if e > lo and s < hi)
         gaps, end = [], lo
         for s, e in spans:
@@ -189,7 +295,7 @@ def reduce(extracted: dict, steps: int) -> Reduced:
             step_gaps = [(b[0] - a[1]) / 1e9 for a, b in zip(runs, runs[1:])]
         chips.append(Chip(busy_s=union_ns([(s, e) for _, s, e, *_ in ops],
                                           lo, hi) / 1e9,
-                          by_kind=by_kind, by_op=by_op,
+                          by_kind=by_kind, by_op=by_op, by_scope=by_scope,
                           step_gaps_s=step_gaps, gaps=gaps))
     return Reduced(window_s=(hi - lo) / 1e9, steps=steps, chips=chips,
                    host=extracted["host"])

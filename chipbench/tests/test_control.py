@@ -12,11 +12,11 @@ import reference
 
 def readings(cell, seed, **kw):
     wseed, dseed, _ = bench.derive_seeds(seed)
-    feed = bench.Feed(cell.config, cell.traffic, dseed)
+    k = cell.model.dims(cell.config)
+    feed = bench.Feed(k.vocab, cell.traffic, dseed)
     batches = [feed.next() for _ in range(bench.FIRST_STEPS)]
-    return reference.train_readings(
-        wseed, reference.Dims.from_config(cell.config), batches,
-        cell.traffic["optimizer"], **kw)
+    return reference.train_readings(cell.model, wseed, k, batches,
+                                    cell.traffic["optimizer"], **kw)
 
 
 @pytest.mark.parametrize("seed", [11, 2**31 + 12, 13])

@@ -17,12 +17,17 @@ RECORDED = sorted((pathlib.Path(__file__).resolve().parents[1] / "data"
 
 
 def test_innermost_scope_of_a_path():
-    assert phases.scope_of("jit(stepfn)/shard_map/while/body/coded.grad/"
-                           "transpose(jvp(dot_general))") == "coded.grad"
+    assert rt.scope_of("jit(stepfn)/shard_map/while/body/coded.grad/"
+                       "transpose(jvp(dot_general))") == "coded.grad"
     # an all-gather issued by the decode sits under both scopes
-    assert phases.scope_of("jit(stepfn)/coded.decode/coded.exchange/"
-                           "all_gather") == "coded.exchange"
-    assert phases.scope_of("jit(stepfn)/add") == ""
+    assert rt.scope_of("jit(stepfn)/coded.decode/coded.exchange/"
+                       "all_gather") == "coded.exchange"
+    assert rt.scope_of("jit(stepfn)/add") == ""
+    # any layer's scope, also inside a transform of the backward pass
+    assert rt.scope_of("jit(stepfn)/coded.grad/transpose(jvp(moe.route))/"
+                       "dot_general") == "moe.route"
+    assert rt.scope_of("jit(stepfn)/coded.grad/jvp(moe_2.up_proj)") \
+        == "moe_2.up_proj"
 
 
 def _varint(n):
@@ -69,7 +74,7 @@ def test_op_scopes_read_from_the_xplane_proto(tmp_path):
     host = _field(2, "/host:CPU") + event_meta(1, "trainer.sync", [])
     xplane = tmp_path / "t.xplane.pb"
     xplane.write_bytes(_field(1, tpu) + _field(1, host))
-    assert phases.op_scopes(str(xplane)) == {"0": {
+    assert rt.op_scopes(str(xplane)) == {"0": {
         "%fusion.1 = f32[8]": "coded.grad",
         "%all-gather.2 = f32[8]": "coded.exchange",
         "%copy.3 = f32[8]": ""}}

@@ -10,44 +10,43 @@ import pytest
 
 import tiny  # noqa: F401  (puts the benchmark and the program on the path)
 import bench
-import program
 import reference
 
 
-@pytest.mark.parametrize("workload", ["qwen3-8b.worker",
-                                      "qwen3-1.7b.coded-gather"])
+@pytest.mark.parametrize("workload", tiny.CELLS)
 def test_weights_match_the_program(workload):
     from repro.models import api
 
     c = tiny.cell(workload)
     seed = bench.derive_seeds(2**31 + 77)[0]
-    cfg = program.model_config(c.config)
+    cfg = c.model.model_config(c.config)
     want = api.init(jax.random.PRNGKey(seed), cfg)
-    k = reference.Dims.from_config(c.config)
-    eager = reference.init_params(seed, k)
+    k = c.model.dims(c.config)
+    eager = c.model.init_params(seed, k)
     assert jax.tree.structure(eager) == jax.tree.structure(want)
     for g, w in zip(jax.tree.leaves(eager), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     # compiled whole, XLA fuses the scaling into the sampler: an ulp apart
-    jitted = jax.jit(reference.init_params, static_argnums=1)(seed, k)
+    jitted = jax.jit(c.model.init_params, static_argnums=1)(seed, k)
     for g, w in zip(jax.tree.leaves(jitted), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=3e-7,
                                    atol=0)
 
 
-def test_loss_and_gradient_match_the_program_at_f32():
+@pytest.mark.parametrize("workload", tiny.CELLS)
+def test_loss_and_gradient_match_the_program_at_f32(workload):
     from repro.models import api
 
-    c = tiny.cell("qwen3-8b.worker", seq_len=32)
-    cfg = dataclasses.replace(program.model_config(c.config),
+    c = tiny.cell(workload, seq_len=32)
+    cfg = dataclasses.replace(c.model.model_config(c.config),
                               compute_dtype="float32")
-    k = reference.Dims.from_config(c.config)
-    params = reference.init_params(5, k)
-    batch = bench.Feed(c.config, c.traffic, 9).next()
+    k = c.model.dims(c.config)
+    params = c.model.init_params(5, k)
+    batch = bench.Feed(k.vocab, c.traffic, 9).next()
     with jax.default_matmul_precision("highest"):
         want_l, want_g = jax.value_and_grad(api.make_loss(cfg))(
             params, jax.tree.map(jnp.asarray, batch))
-        got_l, got_g = reference.batch_grad(params, batch["tokens"],
+        got_l, got_g = reference.batch_grad(c.model, params, batch["tokens"],
                                             batch["labels"], k)
     assert abs(float(got_l) - float(want_l)) < 1e-5 * abs(float(want_l))
     for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):

@@ -58,6 +58,55 @@ def test_reduce_hand_made_steps():
                               ["place", pytest.approx(50e-9)]]
 
 
+def scoped():
+    """Two chips, two steps; ops with named scopes of two layers, one op
+    with none, and a kernel of a model's own inside a scope."""
+    ops = [["while.1", 0, 300, "", "coded.grad"],
+           ["fusion.2", 10, 60, "", "moe.route"],
+           ["expert_mm.3", 60, 160, "", "moe.experts"],
+           ["fusion.4", 300, 340, "", ""],
+           ["coded_encode.5", 340, 400, "", "coded.encode"]]
+    chip = {"ops": ops, "modules": [["jit_stepfn", 0, 400]]}
+    other = {"ops": [op[:3] + op[3:4] + ["moe.experts" if i == 2 else op[4]]
+                     for i, op in enumerate(ops)],
+             "modules": chip["modules"]}
+    other["ops"][2][2] = 260                # a slower kernel on chip 1
+    return {"window": [0, 1000], "host": [], "chips": {"0": chip,
+                                                       "1": other}}
+
+
+def test_time_by_scope_and_a_models_kernel():
+    r = rt.reduce(scoped(), steps=2)
+    c0, c1 = r.chips
+    # self time: the loop's own part, its children under their scopes
+    assert c0.by_scope == {"coded.grad": pytest.approx(150e-9),
+                           "moe.route": pytest.approx(50e-9),
+                           "moe.experts": pytest.approx(100e-9),
+                           "coded.encode": pytest.approx(60e-9)}
+    assert c1.by_scope["moe.experts"] == pytest.approx(200e-9)
+    # time by scope leaves out ops with none: the rest of the busy time
+    for c in r.chips:
+        assert sum(c.by_scope.values()) + 40e-9 == pytest.approx(c.busy_s)
+    assert c0.by_kind["encode"] == pytest.approx(60e-9)
+    cell = bench.load_cell(tiny.CELLS[0])
+    peak = {"hbm_bytes_per_s": 1e12, "bf16_flops_per_s": 1e15}
+    ctx = bench.ReadContext(r, cell, peak)
+    # ms a step, mean over chips: a scope, and a layer's phases together
+    assert ctx.time_in_scope("moe.experts") == pytest.approx(
+        1e3 * (100e-9 + 200e-9) / 2 / 2)
+    assert ctx.time_in_scope("moe") == pytest.approx(
+        1e3 * (150e-9 + 250e-9) / 2 / 2)
+    assert ctx.time_in_scope("moe.gate") is None
+    assert ctx.time_in_scope("attn") is None
+    # a kernel the model declares: its bytes at 1e12 B/s take 50 ns a step
+    assert ctx.roofline_of("expert_mm") is None     # not declared
+    ctx.model_kernels = {"expert_mm": (r"^expert_mm\.", 50e3, 1.0)}
+    assert ctx.roofline_of("expert_mm") == pytest.approx(
+        100 * (100e-9 / 100e-9 + 100e-9 / 200e-9) / 2)
+    ctx.model_kernels = {"expert_mm": (r"^no_such_op", 50e3, 1.0)}
+    assert ctx.roofline_of("expert_mm") is None
+
+
 @pytest.mark.parametrize("path", RECORDED, ids=lambda p: p.name)
 def test_recorded_chip_trace(path):
     ext = json.loads(gzip.decompress(path.read_bytes()))
@@ -70,7 +119,8 @@ def test_recorded_chip_trace(path):
         # self time partitions the busy time
         assert sum(c.by_op.values()) == pytest.approx(c.busy_s, rel=1e-9)
         assert c.by_kind["encode"] > 0 and c.by_kind["decode"] > 0
-    cell = tiny.full_cell(ext["workload"])
+        assert c.by_scope == {}         # recorded before ops kept scopes
+    cell = bench.load_cell(ext["workload"])
     peaks = json.loads((bench.HERE / "peaks.json").read_text())
     ctx = bench.ReadContext(r, cell, peaks[ext["device_kind"]])
     got = {m["name"]: bench.read_metric(m["name"], ctx)

@@ -1,5 +1,5 @@
-"""A cell small enough for the CPU: the qwen3 configuration's keys at
-toy widths, with the traffic files' shape of a step."""
+"""A cell small enough for the CPU: each model type's toy sizes (its
+reference's ``TOY``), with the traffic files' shape of a step."""
 import copy
 import json
 import pathlib
@@ -11,11 +11,8 @@ sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
 
 import bench  # noqa: E402
 
-SIZES = {"hidden_size": 128, "intermediate_size": 256,
-         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
-         "num_hidden_layers": 2, "vocab_size": 512}
-# every cell whose files are in place, in BENCHMARK.json or not (yet)
-CELLS = ["qwen3-8b.worker", "qwen3-1.7b.coded-gather"]
+# every cell of BENCHMARK.json
+CELLS = [w["name"] for w in bench.read_benchmark()["workloads"]]
 # At these sizes a step averages over 128 or 512 tokens, not 8192 or 16384,
 # so every gap reads several times what it does at a cell's size.  The tests
 # hold limits set by the cells' rule from readings at these sizes (13 seeds
@@ -23,33 +20,17 @@ CELLS = ["qwen3-8b.worker", "qwen3-1.7b.coded-gather"]
 LIMITS = {"loss_gap": 6e-4, "grad_norm_gap": 4e-3, "update_norm_gap": 2e-2}
 
 
-def full_cell(workload: str) -> bench.Cell:
-    """The workload as ``BENCHMARK.json`` defines it; a cell that it does
-    not list is built from its files, found by its name
-    ``<config>.<traffic>``, with the metrics that every cell reports."""
-    b = bench.read_benchmark()
-    if workload not in {w["name"] for w in b["workloads"]}:
-        config, traffic = workload.rsplit(".", 1)
-        chips = json.loads((BENCH / "traffic" / f"{traffic}.json")
-                           .read_text())["chips"]
-        b["configs"].append({"name": config,
-                             "file": f"chipbench/configs/{config}.json"})
-        b["workloads"].append({"name": workload, "config": config,
-                               "traffic": traffic, "chips": chips})
-    return bench.load_cell(workload, b)
-
-
 def cell(workload: str, seq_len: int = 64,
          sequences_per_subset: int = 2) -> bench.Cell:
-    """The workload's cell with its configuration cut to toy sizes, its
-    sequences shortened, and the limits for these sizes."""
-    real = full_cell(workload)
+    """The workload's cell with its configuration cut to its model type's
+    toy sizes, its sequences shortened, and the limits for these sizes."""
+    real = bench.load_cell(workload)
     config = copy.deepcopy(real.config)
-    config.update(SIZES)
+    config.update(real.model.toy)
     traffic = dict(real.traffic, seq_len=seq_len,
                    sequences_per_subset=sequences_per_subset)
     return bench.Cell(workload, real.chips, config, traffic,
-                      LIMITS, real.end_to_end, real.per_layer)
+                      LIMITS, real.end_to_end, real.per_layer, real.model)
 
 
 def run(c: bench.Cell, seed: int = 3, backend: str = "ref",
