@@ -1,5 +1,6 @@
-"""Architecture registry: the 10 assigned architectures + the paper's own
-logistic-regression workload, selectable via ``--arch <id>``."""
+"""Architecture registry: the 10 assigned architectures, the paper's own
+logistic-regression workload and the benchmark's DeepSeek-V2-Lite,
+selectable via ``--arch <id>``."""
 from __future__ import annotations
 
 import importlib
@@ -18,9 +19,12 @@ _MODULES = {
     "qwen3-1.7b": "qwen3_1p7b",
     "granite-34b": "granite_34b",
     "logistic-paper": "logistic_paper",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
-ARCHS = [a for a in _MODULES if a != "logistic-paper"]
+# the assigned architectures; deepseek-v2-lite trains only (no serving path)
+ARCHS = [a for a in _MODULES if a not in ("logistic-paper",
+                                          "deepseek-v2-lite")]
 
 
 def get_config(name: str) -> ModelConfig:

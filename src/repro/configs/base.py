@@ -12,6 +12,21 @@ import dataclasses
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's stretch of RoPE (a ``rope_scaling`` of type ``yarn``): the
+    rotary frequencies slowed by ``factor`` below a ramp between
+    ``beta_fast`` and ``beta_slow`` rotations over ``original_len``
+    positions, and the attention logits scaled by the square of
+    ``0.1 mscale_all_dim ln(factor) + 1``."""
+    factor: float
+    original_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                 # dense | moe | ssm | hybrid | encdec | vlm
@@ -31,6 +46,20 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # DeepSeekMoE (family "deepseek"): n_experts is the router's width and
+    # d_ff the leading dense layers' width
+    moe_d_ff: int = 0           # width of each routed expert
+    n_shared_experts: int = 0   # shared experts, one SwiGLU n x moe_d_ff wide
+    n_dense_layers: int = 0     # leading dense layers (of n_layers)
+    expert_shares: int = 1      # chips one layer's routed experts are split over
+    expert_share_index: int = 0  # the share held here
+    aux_loss_alpha: float = 0.0  # weight of the sequence-wise balance loss
+    # multi-head latent attention (family "deepseek"; kv_lora_rank 0 = none)
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Yarn | None = None
     # SSM / hybrid
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -57,18 +86,53 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a query and key head: latent attention's nope and rope
+        parts, else ``head_dim_``."""
+        if self.kv_lora_rank:
+            return self.qk_nope_dim + self.qk_rope_dim
+        return self.head_dim_
+
+    @property
+    def v_head_dim_(self) -> int:
+        return self.v_head_dim or self.head_dim_
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts held here: one of ``expert_shares`` equal shares."""
+        return self.n_experts // self.expert_shares
+
+    @property
+    def first_expert_held(self) -> int:
+        return self.expert_share_index * self.experts_held
+
     def __post_init__(self):
         if self.n_heads % max(self.n_kv_heads, 1) != 0:
             raise ValueError(f"{self.name}: n_heads must be divisible by n_kv_heads")
-        if self.family == "moe" and (self.n_experts < 1 or self.top_k < 1):
+        if self.family in ("moe", "deepseek") and (self.n_experts < 1
+                                                   or self.top_k < 1):
             raise ValueError(f"{self.name}: moe needs n_experts/top_k")
+        if self.n_experts % self.expert_shares or not (
+                0 <= self.expert_share_index < self.expert_shares):
+            raise ValueError(
+                f"{self.name}: {self.n_experts} experts do not split into "
+                f"{self.expert_shares} shares holding share "
+                f"{self.expert_share_index}")
+        if self.n_dense_layers >= self.n_layers > 0 and self.n_experts:
+            raise ValueError(f"{self.name}: no expert layer follows the "
+                             f"{self.n_dense_layers} dense ones")
 
-    def cut(self, n_layers: int, vocab_share: int = 1) -> "ModelConfig":
+    def cut(self, n_layers: int, vocab_share: int = 1,
+            expert_share: int = 1) -> "ModelConfig":
         """This chip's share of the published model: every width as
-        published, depth cut to ``n_layers`` and the vocabulary to one
-        ``1/vocab_share`` slice of its rows (the slices of a vocabulary
-        split over ``vocab_share`` chips; the data draws its ids from the
-        slice).  The cut is named, e.g. ``qwen3-1.7b-4l-vocab18992``."""
+        published, depth cut to ``n_layers`` (leading dense layers
+        included), the vocabulary to one ``1/vocab_share`` slice of its rows
+        (the slices of a vocabulary split over ``vocab_share`` chips; the
+        data draws its ids from the slice), and the routed experts to the
+        first of ``expert_share`` equal shares (the router keeps its
+        published width).  The cut is named, e.g.
+        ``qwen3-1.7b-4l-vocab18992`` or ``...-vocab12800-8of64e``."""
         if not 1 <= n_layers <= self.n_layers:
             raise ValueError(f"{self.name}: n_layers={n_layers} is outside "
                              f"1..{self.n_layers}")
@@ -76,9 +140,12 @@ class ModelConfig:
             raise ValueError(f"{self.name}: vocab {self.vocab} does not "
                              f"split into {vocab_share} equal slices")
         vocab = self.vocab // vocab_share
+        name = f"{self.name}-{n_layers}l-vocab{vocab}"
+        if expert_share != 1:
+            name += f"-{self.n_experts // expert_share}of{self.n_experts}e"
         return dataclasses.replace(
-            self, n_layers=n_layers, vocab=vocab,
-            name=f"{self.name}-{n_layers}l-vocab{vocab}")
+            self, n_layers=n_layers, vocab=vocab, expert_shares=expert_share,
+            expert_share_index=0, name=name)
 
     def reduced(self) -> "ModelConfig":
         """Family-preserving smoke-test variant (2 layers, d_model <= 512,
@@ -105,6 +172,12 @@ class ModelConfig:
         if self.n_experts:
             kw["n_experts"] = min(self.n_experts, 4)
             kw["top_k"] = min(self.top_k, 2)
+            kw["expert_shares"], kw["expert_share_index"] = 1, 0
+            kw["moe_d_ff"] = min(self.moe_d_ff, 128)
+            kw["n_dense_layers"] = min(self.n_dense_layers, 1)
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=64, qk_nope_dim=32, qk_rope_dim=16,
+                      v_head_dim=32)
         if self.attn_every:
             kw["attn_every"] = 1
         if self.enc_layers:

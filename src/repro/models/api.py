@@ -3,6 +3,8 @@
 - ``get_module(cfg)``: the family module (init / loss / forward / serving).
 - ``make_loss(cfg)``: ``fn(params, batch) -> scalar``; ``batch`` is always a
   dict (tokens/labels, + embeds for vlm/audio, or x/y for linear).
+- ``make_loss_and_counts(cfg)``: ``fn(params, batch) -> (scalar, counters)``,
+  the family's named f32 counters (``counters(cfg)``; none for most).
 - ``make_prefill(cfg, cache_len, window)`` / ``make_decode(cfg, window)``:
   uniform serving entry points.
 - ``cache_spec(cfg, B, S, window)``: ShapeDtypeStructs of the decode state.
@@ -11,11 +13,12 @@ from __future__ import annotations
 
 import jax
 
-from . import dense, encdec, linear, mamba_hybrid, moe, vlm, xlstm
+from . import deepseek, dense, encdec, linear, mamba_hybrid, moe, vlm, xlstm
 
 _FAMILY = {
     "dense": dense,
     "moe": moe,
+    "deepseek": deepseek,
     "ssm": xlstm,
     "hybrid": mamba_hybrid,
     "encdec": encdec,
@@ -38,6 +41,22 @@ def make_loss(cfg):
     def fn(params, batch):
         return mod.loss(params, cfg, batch)
 
+    return fn
+
+
+def counters(cfg) -> tuple[str, ...]:
+    """Names of the counters the family's loss reports beside it."""
+    return getattr(get_module(cfg), "COUNTERS", ())
+
+
+def make_loss_and_counts(cfg):
+    mod = get_module(cfg)
+    if counters(cfg):
+        def fn(params, batch):
+            return mod.loss_and_counts(params, cfg, batch)
+    else:
+        def fn(params, batch):
+            return mod.loss(params, cfg, batch), {}
     return fn
 
 

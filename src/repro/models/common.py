@@ -54,10 +54,18 @@ def rope_freqs(hd: int, theta: float) -> np.ndarray:
 
 def apply_rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     """x: (..., S, H, hd), pos: (..., S) int -> rotated x (same dtype)."""
-    hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta), jnp.float32)          # (hd/2,)
+    return rotate_halves(x, pos, rope_freqs(x.shape[-1], theta))
+
+
+def rotate_halves(x: jax.Array, pos: jax.Array, inv_freq: np.ndarray,
+                  scale: float = 1.0) -> jax.Array:
+    """RoPE with the given inverse frequencies (hd/2,), the first half of
+    the head dims paired with the second; cos and sin times ``scale``."""
+    freqs = jnp.asarray(inv_freq, jnp.float32)                       # (hd/2,)
     ang = pos.astype(jnp.float32)[..., None] * freqs                 # (..., S, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -116,19 +124,20 @@ def qkv_project(p: dict, cfg, x: jax.Array, pos: jax.Array):
     return q, k, v
 
 
-def gqa_scores_attend(q, k, v, mask, q_per_kv: int):
-    """q: (B,Sq,H,hd), k/v: (B,Sk,Hkv,hd), mask: (B,Sq,Sk) or (Sq,Sk) bool."""
+def gqa_scores_attend(q, k, v, mask, q_per_kv: int, scale=None):
+    """q/k: (B,Sq,H,hd), (B,Sk,Hkv,hd), v: (B,Sk,Hkv,hv), mask: (B,Sq,Sk) or
+    (Sq,Sk) bool; logits times ``scale`` (default 1/sqrt(hd))."""
     B, Sq, H, hd = q.shape
     Hkv = k.shape[2]
     qg = q.reshape(B, Sq, Hkv, q_per_kv, hd)
     logits = jnp.einsum("bqhgk,bshk->bhgqs", qg, k).astype(jnp.float32)
-    logits = logits / np.sqrt(hd)
+    logits = logits / np.sqrt(hd) if scale is None else logits * scale
     if mask.ndim == 2:
         mask = mask[None]
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgqs,bshk->bqhgk", probs, v)
-    return out.reshape(B, Sq, H, hd)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def attention(p: dict, cfg, x: jax.Array, pos: jax.Array,
@@ -154,10 +163,12 @@ REMAT_KV_STEP = False
 
 def online_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
                      window: int = 0, chunk_q: int = CHUNK_Q,
-                     chunk_kv: int = CHUNK_KV, kv_pos0: int = 0) -> jax.Array:
+                     chunk_kv: int = CHUNK_KV, kv_pos0: int = 0,
+                     scale=None) -> jax.Array:
     """Flash-style attention in pure JAX: never materializes (Sq, Sk).
 
-    q: (B, Sq, H, hd); k/v: (B, Sk, Hkv, hd).
+    q: (B, Sq, H, hd); k: (B, Sk, Hkv, hd); v: (B, Sk, Hkv, hv); logits
+    times ``scale`` (default 1/sqrt(hd)).
     mask_kind: "causal" | "full" | "window" (causal with a back-window).
     Query positions are ``kv_pos0 + arange(Sq)`` relative to kv positions
     ``arange(Sk)`` (self-attention uses kv_pos0=Sk-Sq=0).
@@ -173,10 +184,11 @@ def online_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
     while Sk % ckv:
         ckv -= 1
     nq, nk = Sq // cq, Sk // ckv
-    scale = 1.0 / np.sqrt(hd)
+    hv = v.shape[-1]
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     qr = q.reshape(B, nq, cq, Hkv, q_per_kv, hd)
     kr = k.reshape(B, nk, ckv, Hkv, hd)
-    vr = v.reshape(B, nk, ckv, Hkv, hd)
+    vr = v.reshape(B, nk, ckv, Hkv, hv)
 
     def q_block(qi_qc):
         qi, qc = qi_qc                     # qc: (B, cq, Hkv, g, hd)
@@ -205,7 +217,7 @@ def online_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
 
         m0 = jnp.full((B, Hkv, q_per_kv, cq), -1e30, jnp.float32)
         l0 = jnp.zeros((B, Hkv, q_per_kv, cq), jnp.float32)
-        o0 = jnp.zeros((B, Hkv, q_per_kv, cq, hd), jnp.float32)
+        o0 = jnp.zeros((B, Hkv, q_per_kv, cq, hv), jnp.float32)
         body = jax.remat(kv_step) if REMAT_KV_STEP else kv_step
         (m, l, o), _ = jax.lax.scan(
             body, (m0, l0, o0),
@@ -214,8 +226,8 @@ def online_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
         return jnp.einsum("bhgqk->bqhgk", o)
 
     blocks = jax.lax.map(q_block, (jnp.arange(nq), jnp.moveaxis(qr, 1, 0)))
-    out = jnp.moveaxis(blocks, 0, 1).reshape(B, Sq, Hkv, q_per_kv, hd)
-    return out.reshape(B, Sq, H, hd).astype(q.dtype)
+    out = jnp.moveaxis(blocks, 0, 1).reshape(B, Sq, Hkv, q_per_kv, hv)
+    return out.reshape(B, Sq, H, hv).astype(q.dtype)
 
 
 # ------------------------------------------------- fused (TPU) attention
@@ -235,13 +247,15 @@ def attention_path(cfg, S: int, mask_kind: str) -> str:
 
     - ``"fused"``: JAX's splash attention kernels, blockwise with their own
       backward, so no (S, S) scores reach HBM.  On a TPU, for a causal or
-      sliding-window mask, in whole ``FUSED_BLOCK`` blocks of a head_dim
-      that fills the 128 lanes;
+      sliding-window mask, in whole ``FUSED_BLOCK`` blocks, with value heads
+      that fill the 128 lanes and query/key heads in whole halves of them
+      (latent attention's 128 + 64);
     - ``"materialized"``: the (S, S) scores, up to ``CHUNK_THRESHOLD``;
     - ``"online"``: the chunked online softmax beyond it.
     """
     if (_on_tpu() and mask_kind in ("causal", "window")
-            and S % FUSED_BLOCK == 0 and cfg.head_dim_ % 128 == 0):
+            and S % FUSED_BLOCK == 0 and cfg.qk_head_dim % 64 == 0
+            and cfg.v_head_dim_ % 128 == 0):
         return "fused"
     return "materialized" if S <= CHUNK_THRESHOLD else "online"
 
@@ -274,34 +288,38 @@ def _splash_kernel(S: int, g: int, mask_kind: str, window: int, block: int,
 
 
 def fused_attention(q, k, v, q_per_kv: int, *, mask_kind: str = "causal",
-                    window: int = 0) -> jax.Array:
+                    window: int = 0, scale=None) -> jax.Array:
     """GQA self-attention through the splash kernels: one MQA kernel per KV
     head (its ``q_per_kv`` query heads share it), vmapped over batch and KV
     heads, in ``FUSED_BLOCK`` blocks.  Blocks the mask leaves empty are
     skipped.  Logits and softmax statistics are f32 in the kernel; q is
-    scaled by 1/sqrt(hd) before it.  Off a TPU the kernels run in Pallas
-    interpret mode.
+    scaled by ``scale`` (default 1/sqrt(hd)) before it.  Off a TPU the
+    kernels run in Pallas interpret mode.
 
-    q: (B, S, H, hd); k/v: (B, S, Hkv, hd) -> (B, S, H, hd).
+    q: (B, S, H, hd); k: (B, S, Hkv, hd); v: (B, S, Hkv, hv) -> (B, S, H, hv).
     """
     B, S, H, hd = q.shape
     Hkv = k.shape[2]
     kernel = _splash_kernel(S, q_per_kv, mask_kind, window, FUSED_BLOCK,
                             not _on_tpu())
-    qs = (q.astype(jnp.float32) * (1.0 / np.sqrt(hd))).astype(q.dtype)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qs = qs.reshape(B, S, Hkv, q_per_kv, hd).transpose(0, 2, 3, 1, 4)
     kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     out = jax.vmap(jax.vmap(lambda a, b, c: kernel(a, b, c)))(qs, kt, vt)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, v.shape[-1])
 
 
-def _attend(q, k, v, cfg, path: str, mask_kind: str, window: int):
+def attend(q, k, v, cfg, path: str, mask_kind: str, window: int,
+           scale=None):
+    """Attention of projected heads on ``path`` (``attention_path``);
+    logits times ``scale``, default 1/sqrt(head dim)."""
     if path == "fused":
         return fused_attention(q, k, v, cfg.q_per_kv, mask_kind=mask_kind,
-                               window=window)
+                               window=window, scale=scale)
     if path == "online":
         return online_attention(q, k, v, cfg.q_per_kv, mask_kind=mask_kind,
-                                window=window)
+                                window=window, scale=scale)
     S = q.shape[1]
     if mask_kind == "causal":
         mask = causal_mask(S)
@@ -309,7 +327,7 @@ def _attend(q, k, v, cfg, path: str, mask_kind: str, window: int):
         mask = sliding_causal_mask(S, window)
     else:
         mask = jnp.ones((S, S), bool)
-    return gqa_scores_attend(q, k, v, mask, cfg.q_per_kv)
+    return gqa_scores_attend(q, k, v, mask, cfg.q_per_kv, scale)
 
 
 def self_attention(p: dict, cfg, x: jax.Array, pos: jax.Array, *,
@@ -317,8 +335,8 @@ def self_attention(p: dict, cfg, x: jax.Array, pos: jax.Array, *,
     """Mask-kind self-attention on the path ``attention_path`` picks."""
     S = x.shape[1]
     q, k, v = qkv_project(p, cfg, x, pos)
-    out = _attend(q, k, v, cfg, attention_path(cfg, S, mask_kind), mask_kind,
-                  window)
+    out = attend(q, k, v, cfg, attention_path(cfg, S, mask_kind), mask_kind,
+                 window)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
 
 
@@ -329,7 +347,7 @@ def self_attention_with_kv(p: dict, cfg, x: jax.Array, pos: jax.Array, *,
     S = x.shape[1]
     q, k, v = qkv_project(p, cfg, x, pos)
     path = "materialized" if S <= CHUNK_THRESHOLD else "online"
-    out = _attend(q, k, v, cfg, path, mask_kind, window)
+    out = attend(q, k, v, cfg, path, mask_kind, window)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, k, v
 

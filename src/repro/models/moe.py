@@ -1,4 +1,5 @@
-"""Mixture-of-Experts decoder LM (olmoe / grok family).
+"""Mixture-of-Experts decoder LM (olmoe / grok family), and the expert layer
+that holds a share of the experts (DeepSeekMoE, ``repro.models.deepseek``).
 
 Routing: top-k softmax router with capacity-based scatter dispatch (Switch
 style, but gather/scatter instead of the (T, E, C) one-hot einsum so the
@@ -6,8 +7,14 @@ dispatch tensors stay O(T*k), not O(T*E*C)).  Tokens overflowing an expert's
 capacity are dropped (standard); a load-balance auxiliary loss (Shazeer) keeps
 the router spread.  Expert weights carry a leading E axis so the `model` mesh
 axis can shard either E (olmoe: 64 experts) or d_ff (grok: 8 experts).
+
+``held_experts_ffn`` is told which experts it holds: it routes over all of
+them and computes, with no capacity and no dropped slot, the part of the
+result its own experts give (``held_experts_ffn``'s docstring).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +34,14 @@ AUX_LOSS_WEIGHT = 0.01
 #                to (Tc, E, Cc)).  Flipped by the dry-run's --opt moe_einsum.
 DISPATCH = "scatter"
 TOKEN_CHUNK = 2048
+
+# megablox row tile, and the grain of the held experts' buffer: 512
+# overflows the v5e's 16 MiB of scoped VMEM in tgmm at d_model 2048 x
+# expert width 1408
+GMM_ROW_TILE = 256
+# rows of the held experts' buffer, in multiples of the slots a uniform
+# router sends to them
+BUFFER_SHARE = 2
 
 
 def init(key, cfg):
@@ -236,3 +251,125 @@ def decode_step(params, cfg, cache, token, *, window: int = 0):
     x = cm.rms_norm(x, params["ln_f"])
     logits = cm.unembed(x, params["unembed"])[:, 0]
     return logits, {"k": ks, "v": vs, "pos": pos + 1}
+
+
+# ------------------------------------------------- experts held here
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """megablox tiles: GMM_ROW_TILE rows; a width whole up to 1536, else
+    1024 of it."""
+    return (GMM_ROW_TILE,) + tuple(x if x <= 1536 else 1024 for x in (k, n))
+
+
+def grouped_matmul(x, w, sizes):
+    """Rows of x: (M, K) in consecutive groups of ``sizes`` (G,), each times
+    its group's w[g]: (K, N) -> (M, N), in JAX's Pallas megablox ``gmm``
+    (``tgmm`` for the weights' gradient; interpret mode off a TPU), which
+    visits only the row tiles the groups cover.  The rows past the groups
+    read 0 and pass no gradient back, whatever the op leaves there."""
+    past = jnp.arange(x.shape[0]) >= jnp.sum(sizes)
+    return jnp.where(past[:, None], 0, _grouped(x, w, sizes))
+
+
+def _grouped(x, w, sizes):
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    # one more group, of the rows past w's groups, so that the sizes add up
+    # to M as megablox assumes; it has no weights and is not computed
+    rest = x.shape[0] - jnp.sum(sizes)
+    return megablox.gmm(
+        x, w, jnp.concatenate([sizes, rest[None]]).astype(jnp.int32),
+        x.dtype, _gmm_tiling, jnp.zeros((), jnp.int32), None, False,
+        not cm._on_tpu())
+
+
+def seq_aux_loss(probs, eidx, n_experts: int, top_k: int, batch: int):
+    """DeepSeek-V2's sequence-wise balance loss before its weight alpha
+    (arXiv:2405.04434 eq. 23-25): per sequence, the sum over experts of
+    f_i (E / (K S) times the slots routed to i) and P_i (i's mean score),
+    averaged over the sequences.  probs: (B*S, E), eidx: (B*S, K)."""
+    T = probs.shape[0]
+    S = T // batch
+    slots = jax.nn.one_hot(eidx.reshape(batch, S * top_k), n_experts,
+                           dtype=jnp.float32).sum(1)                # (B, E)
+    f = jax.lax.stop_gradient(slots) * (n_experts / (top_k * S))
+    P = probs.reshape(batch, S, n_experts).mean(1)
+    return jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def held_experts_ffn(lp, cfg, x):
+    """The routed experts' part of DeepSeekMoE's output from the experts
+    held here, and the shared experts' (which every chip computes alike).
+
+    The router scores all ``cfg.n_experts`` experts (f32 logits at
+    ``HIGHEST`` precision, so a near tie does not flip on the bf16
+    rounding of a product), softmax, greedy top-k, gates as scored.  Of the
+    T x k (token, expert) slots, those whose expert lies in the held range
+    ``[first_expert_held, + experts_held)`` are sorted by expert and run
+    through grouped matmuls of the held experts' SwiGLUs, a static
+    ``buffer`` of rows at a time: ``BUFFER_SHARE`` times the slots a uniform
+    router sends here, in whole row tiles.  The buffers cover T x min(k, held) rows,
+    every slot a token can send here, so no slot is ever dropped however
+    uneven the routing; a buffer past the slots routed here is skipped
+    (``lax.cond``, rematerialised in the backward), and the grouped
+    matmuls visit only the rows their groups cover, so the matmul work
+    follows the slots routed here (the gather and scatter the buffer).
+    Each output row, times its gate, is added to its token.
+
+    lp: the layer's ``router`` (D, E), ``moe`` {w_gate, w_up (held, D, F),
+    w_down (held, F, D)} and ``shared`` (a SwiGLU); x: (B, S, D).
+    Returns (y (B, S, D), the balance loss before alpha, and the slots
+    routed here and the largest held expert's slots, both int32).
+    """
+    B, S, D = x.shape
+    E, K = cfg.n_experts, cfg.top_k
+    Eh, e0 = cfg.experts_held, cfg.first_expert_held
+    T = B * S
+    most = T * min(K, Eh)               # every slot of a token held here
+    tile = GMM_ROW_TILE
+    buffer = min(-(-BUFFER_SHARE * T * K * Eh // E // tile),
+                 -(-most // tile)) * tile
+    n_chunks = -(-most // buffer)
+    xt = x.reshape(T, D)
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            lp["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
+        gate, eidx = jax.lax.top_k(probs, K)                        # (T, K)
+        aux = seq_aux_loss(probs, eidx, E, K, B)
+    with jax.named_scope("moe.dispatch"):
+        local = eidx.reshape(-1) - e0                               # (T*K,)
+        key = jnp.where((local >= 0) & (local < Eh), local, Eh)
+        order = jnp.argsort(key, stable=True)   # held slots first, by expert
+        sizes = jnp.bincount(key, length=Eh + 1).astype(jnp.int32)[:Eh]
+        here = jnp.sum(sizes)
+        starts = jnp.cumsum(sizes) - sizes
+        pad = (0, max(n_chunks * buffer - T * K, 0))
+        tok = jnp.pad(order // K, pad)
+        gate_sorted = jnp.pad(gate.reshape(-1)[order].astype(x.dtype), pad)
+    w = {n: a.astype(x.dtype) for n, a in lp["moe"].items()}
+
+    def chunk(c, y):
+        lo = c * buffer
+        with jax.named_scope("moe.dispatch"):
+            t = jnp.where(lo + jnp.arange(buffer) < here,
+                          tok[lo:lo + buffer], T)         # T: no slot, dropped
+            # each held expert's rows within this chunk
+            sz = (jnp.clip(starts + sizes, lo, lo + buffer)
+                  - jnp.clip(starts, lo, lo + buffer))
+            xs = xt.at[t].get(mode="fill", fill_value=0)
+        with jax.named_scope("moe.experts"):
+            h = (jax.nn.silu(grouped_matmul(xs, w["w_gate"], sz))
+                 * grouped_matmul(xs, w["w_up"], sz))
+            out = grouped_matmul(h, w["w_down"], sz)
+        with jax.named_scope("moe.combine"):
+            return y.at[t].add(out * gate_sorted[lo:lo + buffer, None],
+                               mode="drop")
+
+    y = chunk(0, jnp.zeros((T, D), x.dtype))
+    for c in range(1, n_chunks):
+        y = jax.lax.cond(c * buffer < here,
+                         jax.checkpoint(functools.partial(chunk, c)),
+                         lambda y_: y_, y)
+    with jax.named_scope("moe.shared"):
+        y = y + cm.swiglu(lp["shared"], x).reshape(T, D)
+    return y.reshape(B, S, D), aux, (here, jnp.max(sizes))

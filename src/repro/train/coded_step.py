@@ -27,6 +27,11 @@ collective, set in ``repro.coding.wire``), ``coded.decode`` and
 trace carries them in each op's name path, so its time can be put down to a
 phase.
 
+A family whose loss reports counters (``repro.models.api.counters``, e.g.
+the expert layer's routed slots) has them summed over the worker's subsets
+and returned, one per worker (dim 0 over the data axes), beside the loss in
+the synchronous and psum steps' metrics.
+
 All coding phases are delegated to a ``repro.coding.Codec``: ``schedule``
 picks the collective choreography (gather / a2a / psum — see
 ``repro.coding.schedules``), ``backend`` the encode/decode implementation
@@ -289,6 +294,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         raise ValueError(f"code.n={code.n} != data-parallel degree {n}")
     ms = mesh.shape["model"]
     loss_fn = model_api.make_loss(cfg)
+    loss_counts_fn = model_api.make_loss_and_counts(cfg)
+    counter_names = model_api.counters(cfg)
     k_subsets = getattr(code, "num_subsets", n)
     if grad_scale is None:
         grad_scale = 1.0 if cfg.family == "linear" else 1.0 / k_subsets
@@ -355,6 +362,9 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
     C = jnp.asarray(code.C, jnp.float32)           # (n, d, m) host constant
 
+    def counts_zero():
+        return {n: jnp.zeros((), jnp.float32) for n in counter_names}
+
     # The per-worker rows of C/mask/rho enter the shard_map body sharded over
     # the data axes (dim 0), so each worker reads its own row locally — no
     # axis_index/dynamic gather in the step (axis_index lowers to PartitionId,
@@ -368,12 +378,14 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
         def per_subset(carry, xs):
             if partial:
-                enc, small, loss_acc, gss_acc = carry
+                enc, cnt, loss_acc, gss_acc = carry
             else:
-                enc, small, loss_acc = carry
+                enc, cnt, loss_acc = carry
             sub, cj, rj = xs
             with jax.named_scope("coded.grad"):
-                lval, g = jax.value_and_grad(loss_fn)(params, sub)
+                (lval, c), g = jax.value_and_grad(loss_counts_fn,
+                                                  has_aux=True)(params, sub)
+            cnt = jax.tree.map(jnp.add, cnt, c)
 
             def fold(e, gleaf, pl):
                 if not pl.coded:
@@ -390,19 +402,19 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                 # gradient-norm term
                 gss = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                           for l in jax.tree.leaves(g))
-                return (enc, small, loss_acc + rj * lval,
+                return (enc, cnt, loss_acc + rj * lval,
                         gss_acc + rj * gss), None
-            return (enc, small, loss_acc + rj * lval), None
+            return (enc, cnt, loss_acc + rj * lval), None
 
         init = (jax.tree.map(codec.encoding_zero, params, plans),
-                None, jnp.zeros((), jnp.float32))
+                counts_zero(), jnp.zeros((), jnp.float32))
         if partial:
             init = init + (jnp.zeros((), jnp.float32),)
-            (enc, _, loss_sum, gss_sum), _ = jax.lax.scan(
+            (enc, cnt, loss_sum, gss_sum), _ = jax.lax.scan(
                 per_subset, init, (lb, Ci, rho_i))
         else:
-            (enc, _, loss_sum), _ = jax.lax.scan(per_subset, init,
-                                                 (lb, Ci, rho_i))
+            (enc, cnt, loss_sum), _ = jax.lax.scan(per_subset, init,
+                                                   (lb, Ci, rho_i))
 
         # stragglers transmit nothing — zero the payload to prove independence
         with jax.named_scope("coded.encode"):
@@ -452,7 +464,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
         with jax.named_scope("coded.apply"):
             new_params, new_opt = optimizer.update(grads, opt_state, params)
-        metrics = {"loss": loss_global[None], "grad_norm": gnorm[None]}
+        metrics = {"loss": loss_global[None], "grad_norm": gnorm[None],
+                   **{n: v[None] for n, v in cnt.items()}}
         if partial:
             bound = ef * jnp.sqrt(wire.psum(gss_sum, data_axes))
             metrics["decode_err_bound"] = bound[None]
@@ -465,22 +478,25 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         mask_i = mask[0]
 
         def per_subset(carry, xs):
-            acc, loss_acc = carry
+            acc, cnt, loss_acc = carry
             sub, rj = xs
             with jax.named_scope("coded.grad"):
-                lval, g = jax.value_and_grad(loss_fn)(params, sub)
+                (lval, c), g = jax.value_and_grad(loss_counts_fn,
+                                                  has_aux=True)(params, sub)
             acc = jax.tree.map(lambda a, g_: a + rj * g_.astype(jnp.float32), acc, g)
-            return (acc, loss_acc + rj * lval), None
+            cnt = jax.tree.map(jnp.add, cnt, c)
+            return (acc, cnt, loss_acc + rj * lval), None
 
         init = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
-                jnp.zeros((), jnp.float32))
-        (acc, loss_sum), _ = jax.lax.scan(per_subset, init, (lb, rho_i))
+                counts_zero(), jnp.zeros((), jnp.float32))
+        (acc, cnt, loss_sum), _ = jax.lax.scan(per_subset, init, (lb, rho_i))
         grads = jax.tree.map(lambda a: wire.psum(a, data_axes) * grad_scale, acc)
         gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_) for g_ in jax.tree.leaves(grads)))
         loss_global = wire.psum(loss_sum * mask_i, data_axes) / k_subsets
         with jax.named_scope("coded.apply"):
             new_params, new_opt = optimizer.update(grads, opt_state, params)
-        metrics = {"loss": loss_global[None], "grad_norm": gnorm[None]}
+        metrics = {"loss": loss_global[None], "grad_norm": gnorm[None],
+                   **{n: v[None] for n, v in cnt.items()}}
         if partial:
             # the psum baseline carries no code: rho already drops uncovered
             # subsets exactly, so the certificate term is identically zero
@@ -660,7 +676,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         # worker-row operands: dim 0 split over the (flattened) data axes
         dspec = P(data_axes if len(data_axes) > 1 else data_axes[0])
         in_specs = (pspecs, ospecs, bspecs, P(), P(), P())
-        mspecs = {"loss": P(), "grad_norm": P()}
+        mspecs = {"loss": P(), "grad_norm": P(),
+                  **{n: dspec for n in counter_names}}
         if partial:
             in_specs = in_specs + (P(),)          # the err_factor scalar
             mspecs["decode_err_bound"] = P()

@@ -37,7 +37,8 @@ def manual_axes(mesh, data_axes, backend=None) -> set[str]:
 
 
 # leaves that live under a stacked-layer container get one leading stack dim
-_STACKS = ("layers", "pairs", "mamba", "enc_layers", "dec_layers")
+_STACKS = ("layers", "dense_layers", "pairs", "mamba", "enc_layers",
+           "dec_layers")
 
 
 def _key_names(path) -> list[str]:
@@ -82,6 +83,10 @@ def _rule(names: list[str], shape: tuple[int, ...], ms: int, ax: str):
             return spec(ax if ok(0) else None, None, None)
         if name in ("bq", "bk", "bv"):
             return spec(ax if ok(0) else None, None)
+        if name == "wkv_a":              # (D, latent + rope): every head's
+            return spec(None, None)
+        if name == "wkv_b":              # (latent, H, nope + v)
+            return spec(None, ax if ok(1) else None, None)
     if parent == "moe":
         if name in ("w_gate", "w_up"):   # (E, D, F)
             if ok(0):

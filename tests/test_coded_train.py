@@ -67,7 +67,7 @@ def _tree_max_diff(a, b):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-1.7b", "olmoe-1b-7b", "xlstm-350m",
-                                  "zamba2-1.2b"])
+                                  "zamba2-1.2b", "deepseek-v2-lite"])
 def test_coded_equals_uncoded(arch):
     ref, mref, _ = _run(arch, "psum", [])
     got, mgot, arts = _run(arch, "gather", [2])

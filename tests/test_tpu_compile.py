@@ -267,3 +267,95 @@ def test_model_attention_on_splash_kernels(one_chip, no_persistent_cache,
                  "splash_mqa_dkv_no_residuals"):
         assert _holds_named_call(hlo, name), name
     assert not re.search(rf"\[{B},{cfg.n_kv_heads},[\d,]*{S},{S}", hlo)
+
+
+# ------------------------------------------------------- DeepSeek-V2-Lite
+# the chip's share of the benchmark's cell: 1 dense + 4 expert layers,
+# 12800 vocabulary rows, 8 of 64 experts held, bf16 activations
+DSV2 = dataclasses.replace(get_config("deepseek-v2-lite").cut(5, 8, 8),
+                           compute_dtype="bfloat16")
+
+
+def test_latent_attention_on_splash_kernels(one_chip, no_persistent_cache,
+                                            monkeypatch):
+    """With the platform check answering "tpu", one latent-attention
+    layer's forward and backward at the cell's shape (4096 positions, 16
+    heads, q/k 192 and v 128 wide) compiles with the splash forward, dq and
+    dkv kernels, and no (S, S) scores anywhere."""
+    from repro.models import mla
+    monkeypatch.setattr(cm, "_on_tpu", lambda: True)
+    B, S = 1, 4096
+    p = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                     jax.eval_shape(lambda: mla.init(jax.random.PRNGKey(0),
+                                                     DSV2, jnp.float32)))
+    x = _sds((B, S, DSV2.d_model), jnp.bfloat16, one_chip)
+
+    def grad(p_, x_):
+        pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        return jax.grad(lambda a, b: jnp.sum(mla.self_attention(
+            a, DSV2, b, pos).astype(jnp.float32)), argnums=(0, 1))(p_, x_)
+
+    hlo = jax.jit(grad).lower(p, x).compile().as_text()
+    for name in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                 "splash_mqa_dkv_no_residuals"):
+        assert _holds_named_call(hlo, name), name
+    assert not re.search(rf"\[[\d,]*{S},{S}\]", hlo)
+
+
+def test_held_experts_compile_at_published_widths(one_chip,
+                                                  no_persistent_cache,
+                                                  monkeypatch):
+    """One expert layer's forward and backward at the cell's 16384 tokens
+    (d_model 2048, 8 held experts of width 1408, shared width 2816), its
+    grouped matmuls in megablox's gmm and tgmm kernels, within the scoped
+    VMEM."""
+    from repro.models import moe
+    monkeypatch.setattr(cm, "_on_tpu", lambda: True)
+    pshapes = jax.eval_shape(lambda: model_api.init(jax.random.PRNGKey(0),
+                                                    DSV2))
+    lp = jax.tree.map(lambda x: _sds(x.shape[1:], x.dtype, one_chip),
+                      {k: pshapes["layers"][k]
+                       for k in ("router", "moe", "shared")})
+    x = _sds((4, 4096, DSV2.d_model), jnp.bfloat16, one_chip)
+
+    def grad(lp_, x_):
+        def f(a, b):
+            y, aux, _ = moe.held_experts_ffn(a, DSV2, b)
+            return jnp.sum(y.astype(jnp.float32)) + aux
+        return jax.grad(f, argnums=(0, 1))(lp_, x_)
+
+    hlo = jax.jit(grad).lower(lp, x).compile().as_text()
+    assert _holds_named_call(hlo, "gmm")
+    assert re.search(r"%[\w.]*tgmm[\w.]* = [^\n]*tpu_custom_call", hlo)
+
+
+def test_deepseek_cell_step_fits(topo, no_persistent_cache, monkeypatch):
+    """The deepseek-v2-lite.worker step (code (1, 1, 0, 1), AdamW, 4 x 4096
+    tokens, compiled codec kernels, splash attention, grouped matmuls)
+    compiles for one chip and its arguments plus scratch fit the chip."""
+    monkeypatch.setattr(cm, "_on_tpu", lambda: True)
+    code = make_code(1, 1, 0, 1)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    opt = get_optimizer("adamw", 3e-4)
+    arts = make_coded_train_step(
+        DSV2, code, mesh, opt, spec=coding.SchemeSpec(backend=PallasBackend()))
+    toks = np.zeros((4, 4096), np.int32)
+    batch = CodedBatcher(code).place({"tokens": toks, "labels": toks})
+    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                          batch)
+    fn, _, _ = arts.step(shapes)
+    rep = NamedSharding(mesh, P())
+    pshapes = jax.eval_shape(
+        lambda: model_api.init(jax.random.PRNGKey(0), DSV2))
+    args = [jax.tree.map(lambda x: _sds(x.shape, x.dtype, rep), t)
+            for t in (pshapes, jax.eval_shape(opt.init, pshapes), shapes)]
+    args += [_sds(s, jnp.float32, rep)
+             for s in ((code.n, code.m), (code.n,), (code.n, code.d))]
+    with jax.sharding.set_mesh(mesh):
+        compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert _holds_kernel(hlo, "coded_encode")
+    assert _holds_named_call(hlo, "splash_mqa_fwd_residuals")
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
