@@ -153,6 +153,8 @@ def one_chip(log: CompileLog, steps: int = 4) -> None:
     batches = [next(stream) for _ in range(steps)]
     calls = custom_calls(trainer, batches[0])
     print(f"pallas step: {calls} tpu_custom_call ops in the lowered step")
+    print(f"wire: {len(trainer.arts.pack_plan.buckets)} buckets, copy-free "
+          f"share {trainer.arts.copy_free_share:.6f}")
     if calls < 2:
         fail("the pallas step holds no compiled encode and decode kernels")
     losses, walls = [], []

@@ -3,8 +3,10 @@ interchangeable implementations.
 
 Canonical shapes (the leaf <-> canonical reshaping lives in ``codec.py``):
 
-  encode: G (d, m, V[, R]) x C (d, m)  ->  (V[, R])      (paper eq. 17/18)
-  decode: F (n, V[, R])   x W (n, m)   ->  (m, V[, R])   (paper eq. 19-21)
+  encode: G (d, m, A[, B]) x C (d, m)  ->  (A[, B])      (paper eq. 17/18)
+  decode: F (n, A[, B])   x W (n, m)   ->  (m, A[, B])   (paper eq. 19-21)
+
+with ``(A, B)`` a kernel plane (``layout.to_plane_view``) or a flat A.
 
 The group axis m always leads: it is never the TPU's lane axis.
 
@@ -50,14 +52,16 @@ class CodecBackend:
         """Decode contraction: F (n, V[, R]) x W (n, m) -> (m, V[, R])."""
         raise NotImplementedError
 
-    def encode_acc(self, acc: jax.Array, G: jax.Array,
-                   C: jax.Array) -> jax.Array:
-        """Accumulating encode: ``acc + encode(G, C)`` with acc (V[, R]) f32.
+    def encode_acc(self, acc: jax.Array, G: jax.Array, C: jax.Array, *,
+                   row: int = 0) -> jax.Array:
+        """Accumulating encode into a wire bucket: rows ``[row, row + A)``
+        of the f32 ``(rows, B)`` stack ``acc`` += ``encode(G, C)``, G the
+        slot's ``(d, m, A, B)`` plane.
 
-        The pipelined step's fused-encode fold — one call per (subset, leaf)
-        writes straight into the 128-aligned wire-bucket accumulator slot
-        instead of materialising the per-leaf encoding for a later pack
-        copy.  Must be bit-identical to the two-step spelling.
+        The packed step's encode fold — one call per (subset, leaf) writes
+        straight into the leaf's slot of the wire bucket instead of
+        materialising the per-leaf encoding for a later pack copy.  Must be
+        bit-identical to the two-step spelling.
         """
         raise NotImplementedError
 
@@ -66,9 +70,10 @@ class CodecBackend:
                      scale: float):
         """Fused decode + SGD-momentum apply over one packed bucket.
 
-        F (n, L) x W (n, m) -> g = scale * decode; then
-        ``mu' = momentum * MU + g``, ``p' = P - lr * mu'`` on the (m, L)
-        f32 bucket-layout views.  Returns ``(p', mu', sum(g*g))`` — the
+        F (n, rows, B) x W (n, m) -> g = scale * decode; then
+        ``mu' = momentum * MU + g``, ``p' = P - lr * mu'`` on the
+        (m, rows, B) f32 bucket-layout views.  Returns
+        ``(p', mu', sum(g*g))`` — the
         gradient-norm partial rides along so the step never rebuilds g.
         """
         raise NotImplementedError
@@ -90,9 +95,12 @@ class RefBackend(CodecBackend):
         ``out_dtype``."""
         return _ref_mod.coded_decode_ref(F, W, out_dtype)
 
-    def encode_acc(self, acc, G, C):
-        """``acc + encode(G, C)`` — XLA fuses the add into the contraction."""
-        return acc + self.encode(G, C, out_dtype=jnp.float32)
+    def encode_acc(self, acc, G, C, *, row=0):
+        """``acc + encode(G, C)`` over the slot's rows (an in-place
+        dynamic-update-slice of the bucket)."""
+        enc = self.encode(G, C, out_dtype=jnp.float32)
+        cur = jax.lax.slice_in_dim(acc, row, row + enc.shape[0])
+        return jax.lax.dynamic_update_slice_in_dim(acc, cur + enc, row, 0)
 
     def decode_apply(self, F, W, P, MU, *, lr, momentum, scale):
         """Reference decode + elementwise SGD-momentum apply (see
@@ -120,10 +128,10 @@ class PallasBackend(CodecBackend):
         return _decode_mod.coded_decode(F, W, interpret=self.interpret,
                                         out_dtype=out_dtype)
 
-    def encode_acc(self, acc, G, C):
+    def encode_acc(self, acc, G, C, *, row=0):
         """Accumulate via the ``coded_encode_acc`` Pallas kernel (in-place
-        through ``input_output_aliases``)."""
-        return _encode_mod.coded_encode_acc(acc, G, C,
+        through ``input_output_aliases``, its grid on the slot's rows)."""
+        return _encode_mod.coded_encode_acc(acc, G, C, row=row,
                                             interpret=self.interpret)
 
     def decode_apply(self, F, W, P, MU, *, lr, momentum, scale):

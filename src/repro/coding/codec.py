@@ -6,10 +6,11 @@ A codec binds a gradient code to an aggregation ``Schedule`` and a compute
   plan    — choose each leaf's grouping dimension (``plan_tree``),
   encode  — fold one subset's gradient into the l/m encoding (eq. 17/18),
   wire    — mask stragglers + cast to the wire dtype (u16-bitcast collectives),
-  pack    — lay every coded encoding into bucketed flat wire buffers
-            (``packing.py``; static ``PackPlan``, O(1) collectives/bucket),
+  pack    — lay every coded encoding into bucketed ``(rows, lanes)`` wire
+            buffers (``packing.py``; static ``PackPlan``, O(1)
+            collectives/bucket); the train step encodes straight into them,
   decode  — run the schedule's collective choreography + contraction (eq. 19-21),
-  unpack  — static slices + ``groups_to_leaf`` back to leaf layouts.
+  unpack  — static row slices + ``groups_to_leaf`` back to leaf layouts.
 
 New code families plug in by constructing a codec around their code object —
 the heterogeneous-load ``repro.core.hetero.HeteroCode`` and the
@@ -21,7 +22,6 @@ error certificate).
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import TYPE_CHECKING, Any
 
 import jax
@@ -32,8 +32,8 @@ if TYPE_CHECKING:  # annotation-only: keeps repro.coding import-independent
 
 from . import wire
 from .backends import CodecBackend, RefBackend, resolve_backend
-from .layout import flatten_rest, leaf_to_groups, unflatten_rest
-from .packing import (PackPlan, make_pack_plan, pack_bucket,
+from .layout import leaf_to_groups, to_plane_view, to_slot_plane
+from .packing import (PackPlan, enc_shape, make_pack_plan, pack_bucket,
                       pack_param_groups, unpack_bucket, unpack_param_groups)
 from .plan import LeafPlan, coded_fraction, plan_tree
 from .schedules import Schedule, get_schedule
@@ -54,10 +54,9 @@ def encode_leaf(g: jax.Array, coef: jax.Array, plan: LeafPlan,
     assert plan.coded
     m = coef.shape[0]
     x = leaf_to_groups(g, plan, m)                  # (m, V, *rest)
-    rest = x.shape[2:]
-    G = flatten_rest(x, 2)[None]                    # (1, m, V[, R])
+    G = to_plane_view(x, 1)[None]                   # (1, m, A[, B])
     out = backend.encode(G, coef.reshape(1, m), out_dtype=g.dtype)
-    return unflatten_rest(out, 1, rest)             # (V, *rest)
+    return out.reshape(x.shape[1:])                 # (V, *rest)
 
 
 def encode_tree(grads: PyTree, coef: jax.Array, plans: PyTree,
@@ -105,11 +104,15 @@ class Codec:
     wire_dtype: Any = jnp.float32
 
     # ---- planning
-    def plan(self, tree: PyTree, specs: PyTree | None = None) -> PyTree:
+    def plan(self, tree: PyTree, specs: PyTree | None = None,
+             model_size: int | None = None, device=None) -> PyTree:
         """Choose every leaf's grouping dimension (``plan_tree``), honouring
-        the schedule's extra divisibility (a2a slices encodings n ways)."""
+        the schedule's extra divisibility (a2a slices encodings n ways);
+        ``model_size`` is the mesh's model-axis size and ``device`` gives
+        the leaves' device order (see ``plan_leaf``)."""
         return plan_tree(tree, specs, self.code.m,
-                         self.schedule.n_split(self.code.n))
+                         self.schedule.n_split(self.code.n), model_size,
+                         device)
 
     def coded_fraction(self, tree: PyTree, plans: PyTree) -> float:
         """Fraction of gradient bytes covered by the code (rest -> psum)."""
@@ -126,35 +129,29 @@ class Codec:
         """f32 zero accumulator in the encoding layout of leaf ``p``."""
         if not plan.coded:
             return jnp.zeros(p.shape, jnp.float32)
-        x = jnp.moveaxis(jnp.zeros(p.shape, jnp.float32), plan.group_dim, 0)
-        return jnp.zeros((x.shape[0] // self.code.m, *x.shape[1:]), jnp.float32)
+        return jnp.zeros(enc_shape(p.shape, plan, self.code.m), jnp.float32)
 
     # ---- fused encode (encode straight into the wire layout)
-    def bucket_acc_zeros(self, pplan: PackPlan) -> list[jax.Array]:
-        """Flat f32 zero accumulators, one per wire bucket — the fused
-        encode fold's carry.  Alignment gaps and the n-divisible tail are
-        never written, so they stay exactly zero on the wire (matching
-        ``pack_bucket``'s explicit zero padding bit-for-bit)."""
-        return [jnp.zeros((b.size,), jnp.float32) for b in pplan.buckets]
+    def bucket_zeros(self, pplan: PackPlan) -> list[jax.Array]:
+        """f32 zero ``(rows, lanes)`` accumulators, one per wire bucket —
+        the encode fold's carry.  Padding rows are never written, so they
+        stay exactly zero on the wire (matching ``pack_bucket``'s explicit
+        zero rows bit-for-bit)."""
+        return [jnp.zeros(b.shape, jnp.float32) for b in pplan.buckets]
 
     def encode_into(self, buf: jax.Array, g: jax.Array, coef: jax.Array,
                     slot) -> jax.Array:
         """Fold one subset's gradient leaf straight into its bucket slot:
-        ``buf[slot] += encode(g, coef)`` via the backend's accumulating
-        encode, skipping the materialise-then-pack copy of the sync path.
-        ``g`` must already be f32 (the fold accumulates in f32, exactly like
-        the per-leaf path's ``encoding_zero`` carry); returns the updated
-        flat buffer."""
+        ``buf[slot rows] += encode(g, coef)`` via the backend's accumulating
+        encode (in place, over the slot's rows alone), skipping the
+        materialise-then-pack copy.  ``g`` must already be f32 (the fold
+        accumulates in f32, exactly like the per-leaf path's
+        ``encoding_zero`` carry); returns the updated bucket."""
         m = coef.shape[0]
         x = leaf_to_groups(g, slot.plan, m)             # (m, V, *rest)
-        rest = x.shape[2:]
-        G = flatten_rest(x, 2)[None]                    # (1, m, V[, R])
-        acc = jax.lax.slice_in_dim(buf, slot.offset, slot.offset + slot.size)
-        if rest:
-            acc = acc.reshape(slot.enc_shape[0], math.prod(rest))
-        acc = self.backend.encode_acc(acc, G, coef.reshape(1, m))
-        return buf.at[slot.offset:slot.offset + slot.size].set(
-            acc.reshape(-1))
+        G = to_slot_plane(x, slot.enc_shape, 1)[None]   # (1, m, rows, lanes)
+        return self.backend.encode_acc(buf, G, coef.reshape(1, m),
+                                       row=slot.row)
 
     # ---- wire
     def to_wire(self, e: jax.Array, mask_i: jax.Array) -> jax.Array:
@@ -177,14 +174,15 @@ class Codec:
                 for b in pplan.buckets]
 
     def unpack(self, decoded_bufs, pplan: PackPlan) -> dict[int, jax.Array]:
-        """Per-bucket (m, L) decoded buffers -> {leaf_index: gradient leaf}."""
+        """Per-bucket (m, rows, lanes) decoded buffers -> {leaf_index:
+        gradient leaf}."""
         out: dict[int, jax.Array] = {}
         for dec, b in zip(decoded_bufs, pplan.buckets):
             out.update(unpack_bucket(dec, b))
         return out
 
     def pack_params(self, flat_leaves, pplan: PackPlan) -> list[jax.Array]:
-        """Param/momentum leaves -> one (m, L) f32 bucket-layout view per
+        """Param/momentum leaves -> one (m, rows, lanes) f32 view per
         bucket, row-aligned with the decoded gradient buffers (the fused
         decode-plus-apply operands; see ``packing.pack_param_groups``)."""
         return [pack_param_groups(flat_leaves, b, self.code.m)
@@ -192,8 +190,8 @@ class Codec:
 
     def unpack_params(self, bufs, pplan: PackPlan,
                       flat_like) -> dict[int, jax.Array]:
-        """Updated (m, L) buffers -> {leaf_index: leaf}, cast back to each
-        leaf's dtype (``flat_like`` supplies the originals)."""
+        """Updated (m, rows, lanes) buffers -> {leaf_index: leaf}, cast back
+        to each leaf's dtype (``flat_like`` supplies the originals)."""
         out: dict[int, jax.Array] = {}
         for buf, b in zip(bufs, pplan.buckets):
             out.update(unpack_param_groups(buf, b, flat_like))
@@ -225,7 +223,8 @@ class Codec:
 
     def decode_packed(self, buf: jax.Array, W: jax.Array,
                       axis_names) -> jax.Array:
-        """One bucket's collective + fused contraction: (L,) -> (m, L) f32."""
+        """One bucket's collective + fused contraction: (rows, lanes) ->
+        (m, rows, lanes) f32 (a flat (L,) wire -> (m, L) alike)."""
         return self.schedule.decode_packed(buf, W, axis_names, self.code.n,
                                            self.backend)
 
@@ -233,8 +232,8 @@ class Codec:
                             MU: jax.Array, axis_names, *, lr: float,
                             momentum: float, scale: float):
         """One bucket's collective + fused decode-and-SGD-momentum apply on
-        its (m, L) param/momentum views: returns (p', mu', sum(g*g)).  See
-        ``Schedule.decode_apply_packed``."""
+        its (m, rows, lanes) param/momentum views: returns
+        (p', mu', sum(g*g)).  See ``Schedule.decode_apply_packed``."""
         return self.schedule.decode_apply_packed(
             buf, W, P, MU, axis_names, self.code.n, self.backend, lr=lr,
             momentum=momentum, scale=scale)

@@ -1,31 +1,35 @@
-"""Static packing of coded-leaf encodings into bucketed flat wire buffers.
+"""Static packing of coded-leaf encodings into bucketed wire buffers.
 
 The per-leaf decode path issues one ``all_gather``/``all_to_all`` (plus one
 skinny contraction) *per coded parameter leaf*; for zoo configs with
 dozens-to-hundreds of leaves the per-collective latency term alpha dominates
 exactly the way the paper's shifted-exponential T_comm model (Sec. VI)
 predicts.  This module computes, once at step-build time, a ``PackPlan``
-that lays every coded leaf's flattened ``(V, *rest)`` encoding into one (or
-a few) flat wire buffers, so each train step issues O(1) collectives per
-*bucket* instead of per leaf and runs one large, aligned decode contraction
-over the packed buffer.
+that lays every coded leaf's ``(V, *rest)`` encoding into a few wire
+buffers, so each train step issues O(1) collectives per *bucket* instead of
+per leaf and runs one large, aligned decode contraction over each bucket.
+
+A bucket is a 2-D ``(rows, lanes)`` f32 stack of rows, and each leaf's slot
+is a range of whole rows: the leaf's own kernel plane
+(``layout.slot_plane``).  So a leaf's gradient enters its slot, and leaves
+the decoded ``(m, rows, lanes)`` buffer, by a row slice and a reshape — no
+relayout of the leaf on either side.
 
 Bucketing: leaves are grouped by (wire dtype, effective model-sharding
-pattern of the encoding).  Axes of size 1 carry no data movement, so their
-spec entries are dropped from the pattern ("effective"): on a 1-sized model
-axis everything lands in a single replicated bucket.  Leaves whose encodings
-really are model-sharded (>1 axis) form separate buckets per pattern — the
-flat layout costs them a GSPMD reshard over the model axis, a trade made
-visible (and separable) by the bucket key rather than hidden per leaf.
+pattern of the encoding, lane width).  Axes of size 1 carry no data
+movement, so their spec entries are dropped from the pattern ("effective"):
+on a 1-sized model axis every leaf of one lane width lands in one
+replicated bucket.  Leaves whose encodings really are model-sharded (>1
+axis) form separate buckets per pattern.
 
 Layout invariants (see DESIGN.md §7 for the wire-format diagram):
-  - slot offsets are ``align`` (default 128) element-aligned, so the fused
-    decode kernel always sees lane-aligned tiles;
-  - each bucket's padded length is divisible by lcm(align, n), so the a2a
-    schedule can split it into n equal chunks without per-leaf divisibility
-    constraints;
-  - padding elements are zeros on the wire and are never read back — the
-    unpack phase uses static slices from the slot table.
+  - slot rows start on a multiple of ``ROW_ALIGN`` (16), so the encode
+    kernel writes a slot in whole tiles;
+  - each bucket's row count is divisible by lcm(16, n), so the gathered
+    ``(n, rows, lanes)`` stack needs no pad and the a2a schedule can split
+    it into n equal row chunks;
+  - padding rows are zeros on the wire and are never read back — the
+    unpack phase uses static row slices from the slot table.
 
 All padding is explicit: ``PackPlan.padded_elems`` vs ``unpadded_elems`` is
 the exact wire overhead, reported by the ``coding_packed`` bench next to the
@@ -43,12 +47,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import wire
-from .layout import groups_to_leaf, leaf_to_groups
+from .layout import (ROW_ALIGN, from_slot_plane, groups_to_leaf,
+                     leaf_to_groups, slot_plane, to_slot_plane)
 from .plan import LeafPlan
 
 PyTree = Any
 
-# element alignment of slot offsets and bucket lengths: one VPU lane row
+# element alignment of a flat (L,) wire, as the serving path lays out its
+# own: one VPU lane row
 WIRE_ALIGN = 128
 
 
@@ -58,10 +64,10 @@ def _round_up(x: int, k: int) -> int:
 
 def enc_shape(shape: Sequence[int], plan: LeafPlan, m: int) -> tuple[int, ...]:
     """The ``(V, *rest)`` encoding shape of a coded leaf (the shape
-    ``encode_leaf`` produces: grouping dim moved first and split by m)."""
+    ``encode_leaf`` produces: grouping dim first and split by m, the rest
+    in device order)."""
     assert plan.coded
-    k = plan.group_dim
-    moved = (shape[k],) + tuple(shape[:k]) + tuple(shape[k + 1:])
+    moved = tuple(shape[d] for d in plan.group_perm(len(shape)))
     return (moved[0] // m,) + moved[1:]
 
 
@@ -81,8 +87,7 @@ def sharding_pattern(spec, plan: LeafPlan, rank: int, model_size: int,
     if spec is None or model_size <= 1:
         return ()
     entries = list(spec) + [None] * (rank - len(list(spec)))
-    k = plan.group_dim
-    moved = [entries[k]] + entries[:k] + entries[k + 1:]
+    moved = [entries[d] for d in plan.group_perm(rank)]
     # moved[0] is the grouping dim — the planner only groups model-replicated
     # dims, so its entry never names the model axis
     return tuple(i for i, e in enumerate(moved)
@@ -91,55 +96,84 @@ def sharding_pattern(spec, plan: LeafPlan, rank: int, model_size: int,
 
 @dataclasses.dataclass(frozen=True)
 class LeafSlot:
-    """Where one coded leaf's flattened encoding lives in its bucket."""
+    """Where one coded leaf's encoding lives in its bucket: rows
+    ``[row, row + rows)`` of the bucket's ``(rows, lanes)`` stack."""
     leaf_index: int            # position in the flattened (tree-order) leaves
-    offset: int                # start element in the bucket's flat buffer
+    row: int                   # first row, a multiple of ROW_ALIGN
+    rows: int                  # rows of the slot's plane
+    lanes: int                 # the bucket's lane width
     size: int                  # unpadded elements = prod(enc_shape)
     enc_shape: tuple[int, ...]  # (V, *rest)
     plan: LeafPlan
 
+    @property
+    def offset(self) -> int:
+        """First element of the slot in the row-major flattened bucket; the
+        slot's ``size`` elements follow contiguously in encoding order."""
+        return self.row * self.lanes
+
+    @property
+    def free(self) -> bool:
+        """Whether leaf -> slot -> leaf is a bitcast: grouped on the leading
+        dim of its device order and planed as the group's own rows and
+        lanes (no flat lift)."""
+        return self.plan.leads and slot_plane(self.enc_shape)[2]
+
 
 @dataclasses.dataclass(frozen=True)
 class WireBucket:
-    """One flat wire buffer: a slot table plus its padded length."""
-    key: tuple                 # (wire dtype name, model-sharding pattern)
+    """One wire buffer: a slot table over a ``(rows, lanes)`` stack."""
+    key: tuple                 # (wire dtype, model-sharding pattern, lanes)
     slots: tuple[LeafSlot, ...]
-    size: int                  # padded length: align-multiple and n-divisible
+    rows: int                  # padded: ROW_ALIGN- and n-divisible
+    lanes: int
     unpadded: int              # sum of slot sizes
 
     @property
+    def shape(self) -> tuple[int, int]:
+        """The bucket buffer's ``(rows, lanes)``."""
+        return self.rows, self.lanes
+
+    @property
+    def size(self) -> int:
+        """Padded elements on the wire."""
+        return self.rows * self.lanes
+
+    @property
     def padding(self) -> int:
-        """Zero elements added for alignment and the n-divisible tail."""
+        """Zero elements added for row alignment and the n-divisible tail."""
         return self.size - self.unpadded
 
     @functools.lru_cache(maxsize=None)
     def worker_chunk_slots(self, n: int) -> tuple[tuple, ...]:
         """Ragged per-worker view of the a2a chunking of this bucket.
 
-        The a2a schedule splits the ``size``-element buffer into ``n`` equal
-        chunks and worker ``p`` decodes chunk ``p`` — but the *slot*
-        boundaries do not align with the chunk boundaries, so each worker
-        covers a ragged set of (possibly partial) leaf segments.  Returns,
-        per worker, a tuple of ``(leaf_index, elem_lo, elem_hi)`` triples in
-        that leaf's flattened-encoding coordinates.  The union over workers
-        tiles every slot exactly once (asserted in tests) — the accounting
-        used to attribute per-worker decode work under heterogeneous loads.
+        The a2a schedule splits the bucket's rows into ``n`` equal chunks
+        and worker ``p`` decodes chunk ``p`` — but the *slot* boundaries do
+        not align with the chunk boundaries, so each worker covers a ragged
+        set of (possibly partial) leaf segments.  Returns, per worker, a
+        tuple of ``(leaf_index, elem_lo, elem_hi)`` triples in that leaf's
+        flattened-encoding coordinates.  The union over workers tiles every
+        slot exactly once (asserted in tests) — the accounting used to
+        attribute per-worker decode work under heterogeneous loads.
 
         Memoized (the dataclass is frozen and hashable): the O(n * slots)
         scan runs at Python trace time inside every step (re)trace and the
         tuning loop asks for the same (bucket, n) pair constantly.
         """
-        assert self.size % n == 0, f"bucket size {self.size} not n={n}-divisible"
-        chunk = self.size // n
+        assert self.rows % n == 0, \
+            f"bucket rows {self.rows} not n={n}-divisible"
+        chunk = self.rows // n
         out = []
         for p in range(n):
             lo_p, hi_p = p * chunk, (p + 1) * chunk
             segs = []
             for s in self.slots:
-                lo = max(s.offset, lo_p)
-                hi = min(s.offset + s.size, hi_p)
+                lo = (max(s.row, lo_p) - s.row) * self.lanes
+                hi = min((min(s.row + s.rows, hi_p) - s.row) * self.lanes,
+                         s.size)
                 if lo < hi:
-                    segs.append((s.leaf_index, lo - s.offset, hi - s.offset))
+                    segs.append((s.leaf_index, lo, hi))
             out.append(tuple(segs))
         return tuple(out)
 
@@ -148,7 +182,6 @@ class WireBucket:
 class PackPlan:
     """Static wire layout for every coded leaf of a parameter tree."""
     buckets: tuple[WireBucket, ...]
-    align: int
     n: int                     # data-parallel degree (a2a chunk divisor)
     m: int                     # the code's group size (encoding = l/m elems)
     wire_dtype: str
@@ -168,19 +201,26 @@ class PackPlan:
         """Total coded leaves across every bucket's slot table."""
         return sum(len(b.slots) for b in self.buckets)
 
+    @property
+    def free_share(self) -> float:
+        """Share of the coded gradient's elements whose path from backward
+        to the optimizer holds no relayout: grouped on the leading dim and
+        planed by a bitcast (``LeafSlot.free``)."""
+        free = sum(s.size for b in self.buckets for s in b.slots if s.free)
+        return free / max(self.unpadded_elems, 1)
+
     def recv_elems_per_worker(self, schedule) -> float:
         """Padding-exact wire cost under ``schedule``'s own model: the
         schedule takes the pre-encoding gradient length l and divides by m
         internally, so feeding it l = padded_elems * m yields exactly what
         the padded buffers transmit (the per-leaf prediction summed over
-        leaves, plus the explicit alignment padding)."""
+        leaves, plus the explicit row padding)."""
         return schedule.recv_elems_per_worker(
             float(self.padded_elems * self.m), self.n, self.m)
 
 
 def make_pack_plan(tree: PyTree, plans: PyTree, *, m: int, n: int,
                    specs: PyTree | None = None, model_size: int = 1,
-                   align: int = WIRE_ALIGN,
                    wire_dtype="float32") -> PackPlan:
     """Compute the static wire layout from the leaf plans.
 
@@ -204,44 +244,55 @@ def make_pack_plan(tree: PyTree, plans: PyTree, *, m: int, n: int,
         es = enc_shape(tuple(x.shape), pl, m)
         pattern = sharding_pattern(
             tuple(sp) if sp is not None else None, pl, len(x.shape), model_size)
-        groups.setdefault((dtype_name, pattern), []).append((i, es, pl))
+        lanes = slot_plane(es)[1]
+        groups.setdefault((dtype_name, pattern, lanes), []).append((i, es, pl))
 
-    chunk = math.lcm(align, n)   # bucket length: aligned AND n-divisible
+    chunk = math.lcm(ROW_ALIGN, n)   # bucket rows: aligned AND n-divisible
     buckets = []
     for key in sorted(groups):
-        off = 0
+        lanes = key[2]
+        row = 0
         slots = []
         for i, es, pl in groups[key]:
-            off = _round_up(off, align)
-            size = int(np.prod(es))
-            slots.append(LeafSlot(leaf_index=i, offset=off, size=size,
+            rows = slot_plane(es)[0]
+            slots.append(LeafSlot(leaf_index=i, row=row, rows=rows,
+                                  lanes=lanes, size=math.prod(es),
                                   enc_shape=es, plan=pl))
-            off += size
+            row += _round_up(rows, ROW_ALIGN)
         buckets.append(WireBucket(
-            key=key, slots=tuple(slots),
-            size=_round_up(off, chunk),
-            unpadded=sum(s.size for s in slots)))
-    return PackPlan(buckets=tuple(buckets), align=align, n=n, m=m,
-                    wire_dtype=dtype_name)
+            key=key, slots=tuple(slots), rows=_round_up(row, chunk),
+            lanes=lanes, unpadded=sum(s.size for s in slots)))
+    return PackPlan(buckets=tuple(buckets), n=n, m=m, wire_dtype=dtype_name)
 
 
 # ------------------------------------------------------------ traced phases
-def pack_bucket(flat_leaves: Sequence[jax.Array], bucket: WireBucket,
+def _stack_rows(parts_of, bucket: WireBucket, lead: tuple[int, ...],
                 dtype) -> jax.Array:
-    """Concatenate the bucket's slot encodings (flattened, already in the
-    wire dtype after ``Codec.to_wire``) with zero padding at the alignment
-    gaps and the tail.  Pure reshape/concat — fused by XLA."""
-    dtype = jnp.dtype(dtype)
+    """Concatenate each slot's ``(*lead, rows, lanes)`` plane along the
+    rows, with zero rows at the alignment gaps and the tail."""
     parts: list[jax.Array] = []
     pos = 0
     for s in bucket.slots:
-        if s.offset > pos:
-            parts.append(jnp.zeros((s.offset - pos,), dtype))
-        parts.append(flat_leaves[s.leaf_index].reshape(-1).astype(dtype))
-        pos = s.offset + s.size
-    if bucket.size > pos:
-        parts.append(jnp.zeros((bucket.size - pos,), dtype))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        if s.row > pos:
+            parts.append(jnp.zeros((*lead, s.row - pos, bucket.lanes), dtype))
+        parts.append(parts_of(s).astype(dtype))
+        pos = s.row + s.rows
+    if bucket.rows > pos:
+        parts.append(jnp.zeros((*lead, bucket.rows - pos, bucket.lanes),
+                               dtype))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-2)
+
+
+def pack_bucket(flat_leaves: Sequence[jax.Array], bucket: WireBucket,
+                dtype) -> jax.Array:
+    """Lay the bucket's slot encodings (``(V, *rest)``, already in the wire
+    dtype after ``Codec.to_wire``) into its ``(rows, lanes)`` buffer with
+    zero padding rows.  The train step encodes straight into the slots
+    instead (``Codec.encode_into``); this is the spelling for encodings
+    made elsewhere."""
+    return _stack_rows(
+        lambda s: to_slot_plane(flat_leaves[s.leaf_index], s.enc_shape, 0),
+        bucket, (), jnp.dtype(dtype))
 
 
 def psum_fallback(flat_leaves: Sequence[jax.Array], flat_plans,
@@ -269,32 +320,25 @@ def psum_fallback(flat_leaves: Sequence[jax.Array], flat_plans,
 def pack_param_groups(flat_leaves: Sequence[jax.Array],
                       bucket: WireBucket, m: int) -> jax.Array:
     """Lay the bucket's *parameter* (or optimizer-state) leaves out in the
-    decoded-buffer layout: an ``(m, bucket.size)`` f32 view whose columns
-    ``[slot.offset, slot.offset + slot.size)`` hold leaf ``slot.leaf_index``
+    decoded-buffer layout: an ``(m, rows, lanes)`` f32 view whose rows
+    ``[slot.row, slot.row + slot.rows)`` hold leaf ``slot.leaf_index``
     exactly where ``unpack_bucket`` reads that leaf's decoded gradient.
 
     This is the fused decode-plus-apply path's input: with params and
     momentum in this layout, the per-bucket kernel can run the optimizer
-    update right after the decode contraction without unpacking.  Columns
-    in the alignment gaps and the tail are zeros (their decoded gradient is
-    zero too, so the update fixes them at zero)."""
-    parts: list[jax.Array] = []
-    pos = 0
-    for s in bucket.slots:
-        if s.offset > pos:
-            parts.append(jnp.zeros((m, s.offset - pos), jnp.float32))
-        x = leaf_to_groups(
-            flat_leaves[s.leaf_index].astype(jnp.float32), s.plan, m)
-        parts.append(x.reshape(m, s.size))
-        pos = s.offset + s.size
-    if bucket.size > pos:
-        parts.append(jnp.zeros((m, bucket.size - pos), jnp.float32))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    update right after the decode contraction without unpacking.  Padding
+    rows are zeros (their decoded gradient is zero too, so the update
+    fixes them at zero)."""
+    return _stack_rows(
+        lambda s: to_slot_plane(leaf_to_groups(flat_leaves[s.leaf_index],
+                                               s.plan, m),
+                                s.enc_shape, 1),
+        bucket, (m,), jnp.float32)
 
 
 def unpack_param_groups(buf: jax.Array, bucket: WireBucket,
                         flat_like: Sequence[Any]) -> dict[int, jax.Array]:
-    """Invert ``pack_param_groups``: slice the updated ``(m, bucket.size)``
+    """Invert ``pack_param_groups``: slice the updated ``(m, rows, lanes)``
     buffer back into leaf layouts, cast to each leaf's original dtype
     (``flat_like`` supplies the dtypes).  Returns {leaf_index: leaf}."""
     out = unpack_bucket(buf, bucket)
@@ -302,14 +346,12 @@ def unpack_param_groups(buf: jax.Array, bucket: WireBucket,
 
 
 def unpack_bucket(decoded: jax.Array, bucket: WireBucket) -> dict[int, jax.Array]:
-    """Invert the packing on the decoded ``(m, bucket.size)`` buffer: static
-    slices from the slot table, reshaped back through ``groups_to_leaf`` into
+    """Invert the packing on the decoded ``(m, rows, lanes)`` buffer: a
+    static row slice per slot, viewed back through ``groups_to_leaf`` in
     each leaf's original layout.  Returns {leaf_index: gradient leaf}."""
-    m = decoded.shape[0]
     out: dict[int, jax.Array] = {}
     for s in bucket.slots:
-        seg = jax.lax.slice_in_dim(decoded, s.offset, s.offset + s.size,
-                                   axis=1)                    # (m, size)
-        x = seg.reshape(m, *s.enc_shape)                      # (m, V, *rest)
-        out[s.leaf_index] = groups_to_leaf(x, s.plan)
+        seg = jax.lax.slice_in_dim(decoded, s.row, s.row + s.rows, axis=1)
+        out[s.leaf_index] = groups_to_leaf(
+            from_slot_plane(seg, s.enc_shape, 1), s.plan)
     return out

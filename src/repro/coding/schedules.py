@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from . import wire
 from .backends import CodecBackend, RefBackend
-from .layout import flatten_rest, groups_to_leaf, unflatten_rest
+from .layout import groups_to_leaf, to_plane_view
 from .plan import LeafPlan
 
 _REF = RefBackend()
@@ -29,11 +29,11 @@ _REF = RefBackend()
 
 def _decode_stack(stacked: jax.Array, W: jax.Array,
                   backend: CodecBackend) -> jax.Array:
-    """(n, V, *rest) x (n, m) -> (m, V, *rest), accumulated/returned in f32."""
-    rest = stacked.shape[2:]
-    F = flatten_rest(stacked, 2)
-    dec = backend.decode(F, W, out_dtype=jnp.float32)   # (m, V[, R])
-    return unflatten_rest(dec, 2, rest)
+    """(n, V, *rest) x (n, m) -> (m, V, *rest), accumulated/returned in f32,
+    contracted over the group's plane view."""
+    F = to_plane_view(stacked, 1)                       # (n, A[, B])
+    dec = backend.decode(F, W, out_dtype=jnp.float32)   # (m, A[, B])
+    return dec.reshape(dec.shape[:1] + stacked.shape[1:])
 
 
 def _gather_slices(dec: jax.Array, axis_names, dtype) -> jax.Array:
@@ -70,12 +70,13 @@ class Schedule:
 
     def decode_packed(self, buf: jax.Array, W: jax.Array, axis_names, n: int,
                       backend: CodecBackend) -> jax.Array:
-        """Decode one packed wire bucket: ``buf`` is the (L,) flat buffer of
-        concatenated leaf encodings (``repro.coding.packing``), L a multiple
-        of lcm(128, n).  Returns the (m, L) decoded blocks in f32 — the same
-        per-element contraction as ``decode_leaf``, issued as ONE collective
-        choreography and one large aligned contraction for the whole bucket
-        instead of one per leaf."""
+        """Decode one packed wire bucket: ``buf`` is the ``(rows, lanes)``
+        stack of leaf encodings (``repro.coding.packing``), rows a multiple
+        of lcm(16, n) — or a flat ``(L,)`` wire, L a multiple of
+        lcm(128, n).  Returns the ``(m, *buf.shape)`` decoded blocks in f32
+        — the same per-element contraction as ``decode_leaf``, issued as ONE
+        collective choreography and one large aligned contraction for the
+        whole bucket instead of one per leaf."""
         raise NotImplementedError
 
     def decode_apply_packed(self, buf: jax.Array, W: jax.Array,
@@ -83,7 +84,8 @@ class Schedule:
                             backend: CodecBackend, *, lr: float,
                             momentum: float, scale: float):
         """Decode one packed bucket AND apply the SGD-momentum update to its
-        ``(m, L)`` param/momentum views (``packing.pack_param_groups``) in
+        ``(m, *buf.shape)`` param/momentum views
+        (``packing.pack_param_groups``) in
         the same pass:
 
             g = scale * decode(buf);  mu' = momentum * MU + g;  p' = P - lr * mu'
@@ -116,17 +118,17 @@ class GatherSchedule(Schedule):
         return groups_to_leaf(_decode_stack(gathered, W, backend), plan)
 
     def decode_packed(self, buf, W, axis_names, n, backend):
-        """One all_gather + one fused (n, L) x (n, m) contraction for the
+        """One all_gather + one fused (n, ...) x (n, m) contraction for the
         whole bucket."""
-        gathered = wire.all_gather_wire(buf, axis_names)     # (n, L)
-        return backend.decode(gathered, W, out_dtype=jnp.float32)  # (m, L)
+        gathered = wire.all_gather_wire(buf, axis_names)     # (n, *buf.shape)
+        return backend.decode(gathered, W, out_dtype=jnp.float32)
 
     def decode_apply_packed(self, buf, W, P, MU, axis_names, n, backend, *,
                             lr, momentum, scale):
         """Fully fused: one all_gather, then the backend's decode-plus-apply
         over the whole bucket (contraction + momentum + param update in one
         kernel on the pallas backend)."""
-        gathered = wire.all_gather_wire(buf, axis_names)     # (n, L)
+        gathered = wire.all_gather_wire(buf, axis_names)     # (n, *buf.shape)
         return backend.decode_apply(gathered, W, P, MU, lr=lr,
                                     momentum=momentum, scale=scale)
 
@@ -159,14 +161,15 @@ class AllToAllSchedule(Schedule):
                               plan)
 
     def decode_packed(self, buf, W, axis_names, n, backend):
-        """One all_to_all of the bucket's n chunks, one fused (n, L/n)
-        contraction, one all_gather of the decoded slices."""
+        """One all_to_all of the bucket's n row chunks, one fused
+        (n, rows/n, ...) contraction, one all_gather of the decoded
+        slices."""
         L = buf.shape[0]
-        assert L % n == 0, f"a2a needs n | bucket length, got {L} % {n}"
-        ex = wire.all_to_all_wire(buf, axis_names)           # (L,)
-        ex = ex.reshape(n, L // n)                           # row p: peer p
-        dec = backend.decode(ex, W, out_dtype=jnp.float32)   # (m, L/n)
-        return _gather_slices(dec, axis_names, buf.dtype)    # (m, L)
+        assert L % n == 0, f"a2a needs n | bucket rows, got {L} % {n}"
+        ex = wire.all_to_all_wire(buf, axis_names)           # (L, ...)
+        ex = ex.reshape(n, L // n, *buf.shape[1:])           # row p: peer p
+        dec = backend.decode(ex, W, out_dtype=jnp.float32)   # (m, L/n, ...)
+        return _gather_slices(dec, axis_names, buf.dtype)    # (m, L, ...)
 
 
 @dataclasses.dataclass(frozen=True)

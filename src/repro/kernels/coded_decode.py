@@ -65,9 +65,10 @@ def coded_decode(F: jax.Array, W: jax.Array, *, interpret: bool = False,
 def _decode_apply_kernel(n, m, rows, lr, momentum, scale, rolled,
                          w_ref, f_ref, p_ref, mu_ref,
                          pn_ref, mun_ref, ss_ref):
-    """w: (n*m,) SMEM, f: (n, TA, 128), p/mu: (m, TA, 128) -> p', mu', and
-    this block's (1, 128) partial of sum(g^2) (rows past ``rows`` — the
-    ragged last block's padding — are masked out of it)."""
+    """w: (n*m,) SMEM, f: (n, TA, TB), p/mu: (m, TA, TB) -> p', mu', and
+    this block's (1, TB) partial of sum(g^2) (rows past ``rows`` — the
+    ragged last block's padding — are masked out of it; lanes past the
+    plane's are dropped when the partials are written)."""
     ta = f_ref.shape[1]
     row = (pl.program_id(0) * ta
            + jax.lax.broadcasted_iota(jnp.int32, f_ref.shape[1:], 0))
@@ -90,9 +91,10 @@ def coded_decode_apply(F: jax.Array, W: jax.Array, P: jax.Array,
                        scale: float, interpret: bool = False):
     """Fused decode + SGD-momentum apply for one packed wire bucket.
 
-    F: (n, L) gathered wire stack; W: (n, m) decode weights; P / MU:
-    (m, L) f32 bucket-layout views of the params and momentum state
-    (``repro.coding.packing.pack_param_groups``).  One pass computes
+    F: (n, L) or (n, rows, B) gathered wire stack; W: (n, m) decode
+    weights; P / MU: (m, L) or (m, rows, B) f32 bucket-layout views of the
+    params and momentum state (``repro.coding.packing.pack_param_groups``).
+    One pass computes
 
         g   = scale * (W^T F)        (paper eq. 19-21 + grad scaling)
         mu' = momentum * mu + g
@@ -109,22 +111,22 @@ def coded_decode_apply(F: jax.Array, W: jax.Array, P: jax.Array,
     (X, rest), (Pl, _), (Ml, _) = (to_plane(F, 1), to_plane(P, 1),
                                    to_plane(MU, 1))
     _, A, B = X.shape
-    ta, _ = block_tiles(A, B, n * X.dtype.itemsize + 4 * m * 4)
-    nb = pl.cdiv(A, ta)
+    ta, tb = block_tiles(A, B, n * X.dtype.itemsize + 4 * m * 4)
+    grid = (pl.cdiv(A, ta), pl.cdiv(B, tb))
     kern = functools.partial(_decode_apply_kernel, n, m, A, float(lr),
                              float(momentum), float(scale), interpret)
-    plane = pl.BlockSpec((m, ta, B), lambda i: (0, i, 0))
+    plane = pl.BlockSpec((m, ta, tb), lambda i, j: (0, i, j))
     pn, mun, ss = pl.pallas_call(
         kern,
-        grid=(nb,),
+        grid=grid,
         in_specs=[smem_spec(),
-                  pl.BlockSpec((n, ta, B), lambda i: (0, i, 0)),
+                  pl.BlockSpec((n, ta, tb), lambda i, j: (0, i, j)),
                   plane, plane],
         out_specs=[plane, plane,
-                   pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0))],
+                   pl.BlockSpec((1, 1, tb), lambda i, j: (i, 0, j))],
         out_shape=[jax.ShapeDtypeStruct((m, A, B), jnp.float32),
                    jax.ShapeDtypeStruct((m, A, B), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, 1, B), jnp.float32)],
+                   jax.ShapeDtypeStruct((grid[0], 1, B), jnp.float32)],
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
         name="coded_decode_apply",

@@ -19,7 +19,9 @@ tiling that keeps HBM traffic at exactly one read of G:
   sizes them so double-buffered operands fit the 16 MiB scoped VMEM.
 
 ``coded_encode_acc`` is the same contraction folded into an f32
-accumulator in place (the pipelined step's encode-into-wire path).
+accumulator in place: the packed step's encode into its leaf's rows of a
+wire bucket, with element-offset blocks that tile the slot exactly
+(``slot_tiles``) and a grid over the slot's rows alone.
 """
 from __future__ import annotations
 
@@ -118,28 +120,6 @@ def _encode_acc_kernel(K, rolled, c_ref, a_ref, g_ref, o_ref):
     o_ref[...] = a_ref[...] + weighted_sum(c_ref, g_ref, K, rolled=rolled)
 
 
-def _encode_call(kernel, K, A, B, tiles, out_dtype, interpret, alias):
-    """The encode's ``pallas_call``, named for its kernel (``coded_encode``
-    or ``coded_encode_acc``) so a trace finds it by that name."""
-    ta, tb = tiles
-    grid = (pl.cdiv(A, ta), pl.cdiv(B, tb))
-    plane = pl.BlockSpec((ta, tb), lambda i, j: (i, j))
-    in_specs = [smem_spec()]
-    if alias:
-        in_specs.append(plane)
-    in_specs.append(pl.BlockSpec((K, ta, tb), lambda i, j: (0, i, j)))
-    return pl.pallas_call(
-        functools.partial(kernel, K, interpret),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=plane,
-        out_shape=jax.ShapeDtypeStruct((A, B), out_dtype),
-        input_output_aliases={1: 0} if alias else {},
-        interpret=interpret,
-        name="coded_encode_acc" if alias else "coded_encode",
-    )
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
 def coded_encode(G: jax.Array, C: jax.Array, *, interpret: bool = False,
                  out_dtype=None) -> jax.Array:
@@ -151,34 +131,80 @@ def coded_encode(G: jax.Array, C: jax.Array, *, interpret: bool = False,
     out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else G.dtype
     X, rest = to_plane(G.reshape(-1, *G.shape[2:]), 1)
     K, A, B = X.shape
-    bpe = K * X.dtype.itemsize + out_dtype.itemsize
-    out = _encode_call(_encode_kernel, K, A, B, block_tiles(A, B, bpe),
-                       out_dtype, interpret, alias=False)(
-        C.reshape(-1).astype(jnp.float32), X)
+    ta, tb = block_tiles(A, B, K * X.dtype.itemsize + out_dtype.itemsize)
+    plane = pl.BlockSpec((ta, tb), lambda i, j: (i, j))
+    out = pl.pallas_call(
+        functools.partial(_encode_kernel, K, interpret),
+        grid=(pl.cdiv(A, ta), pl.cdiv(B, tb)),
+        in_specs=[smem_spec(),
+                  pl.BlockSpec((K, ta, tb), lambda i, j: (0, i, j))],
+        out_specs=plane,
+        out_shape=jax.ShapeDtypeStruct((A, B), out_dtype),
+        interpret=interpret,
+        name="coded_encode",
+    )(C.reshape(-1).astype(jnp.float32), X)
     return from_plane(out, rest)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def slot_tiles(A: int, B: int, bytes_per_elem: int,
+               budget: int = BLOCK_BYTES) -> tuple[int, int]:
+    """Tile ``(TA, TB)`` that divides an ``(A, B)`` slot plane exactly:
+    ``TA`` a multiple of 8 dividing ``A``, ``TB`` the whole ``B`` or a
+    multiple of 128 dividing it, the largest such within ``budget`` bytes
+    of blocks (a whole-``B`` lane axis of no 128-multiple may exceed it).
+    A slot's blocks then never leave its rows, nor the bucket's lanes."""
+    assert A % 8 == 0, f"slot rows {A} are no multiple of 8"
+    lanes = [B] + [t for t in range(B // LANES * LANES, 0, -LANES)
+                   if t < B and B % t == 0]
+    tb = next((t for t in lanes if 8 * t * bytes_per_elem <= budget),
+              lanes[-1])
+    rows = max(8, budget // (tb * bytes_per_elem))
+    ta = max(t for t in range(8, min(A, rows) + 1, 8) if A % t == 0)
+    return ta, tb
+
+
+@functools.partial(jax.jit, static_argnames=("row", "interpret"))
 def coded_encode_acc(acc: jax.Array, G: jax.Array, C: jax.Array, *,
-                     interpret: bool = False) -> jax.Array:
-    """Accumulating encode: ``acc + coded_encode(G, C)`` in one pass.
+                     row: int = 0, interpret: bool = False) -> jax.Array:
+    """Accumulating encode into a wire bucket: rows ``[row, row + A)`` of
+    ``acc`` += ``coded_encode(G, C)``, in one pass, every other row left
+    as it was.
 
-    acc: (V,) or (V, R) f32 — one leaf's 128-aligned slot of a wire-bucket
-    accumulator (``repro.coding.packing``); G: (d, m, V[, R]); C: (d, m).
-    The pipelined step's fused encode path calls this once per (subset,
-    leaf) so the wire buffer fills as gradient leaves materialise, instead
-    of materialising every per-leaf encoding and concatenating in a later
-    pack copy.  ``input_output_aliases`` updates the accumulator in place
-    (the slot is consumed each fold); accumulation stays f32 in-kernel in
+    acc: a bucket's ``(R, B)`` f32 stack (``repro.coding.packing``); G: the
+    slot's ``(d, m, A, B)`` plane, A and ``row`` multiples of 8; C: (d, m).
+    The step calls this once per (subset, leaf), so the wire buffer fills
+    as gradient leaves materialise, instead of materialising every per-leaf
+    encoding and concatenating in a later pack copy.
+    ``input_output_aliases`` updates the bucket in place and the grid
+    covers the slot's rows alone, in element-offset blocks that tile the
+    slot exactly (``slot_tiles``).  Accumulation stays f32 in-kernel in
     :func:`weighted_sum`'s order, so the fold is bit-identical to
-    ``acc + coded_encode(G, C)``.
+    ``acc[slot] + coded_encode(G, C)``.
     """
-    assert acc.dtype == jnp.float32, "wire accumulators are f32"
-    X, rest = to_plane(G.reshape(-1, *G.shape[2:]), 1)
-    K, A, B = X.shape
-    a, _ = to_plane(acc, 0)
-    bpe = K * X.dtype.itemsize + 2 * 4
-    out = _encode_call(_encode_acc_kernel, K, A, B, block_tiles(A, B, bpe),
-                       jnp.float32, interpret, alias=True)(
-        C.reshape(-1).astype(jnp.float32), a, X)
-    return from_plane(out, rest)
+    d, m, A, B = G.shape
+    R = acc.shape[0]
+    assert acc.dtype == jnp.float32 and acc.shape == (R, B), \
+        f"a wire bucket is an f32 (rows, {B}) stack, got {acc.shape}"
+    assert row % 8 == 0 and row + A <= R, (row, A, R)
+    K = d * m
+    bpe = K * G.dtype.itemsize + 2 * 4
+    ta, tb = slot_tiles(A, B, bpe)
+    slot = pl.BlockSpec(
+        (pl.Element(ta), pl.Element(tb)),
+        lambda i, j: (pl.multiple_of(row + i * ta, 8),
+                      pl.multiple_of(j * tb, LANES)))
+    vmem = 2 * ta * tb * bpe                  # double-buffered blocks
+    params = (pltpu.CompilerParams(vmem_limit_bytes=vmem + (4 << 20))
+              if vmem > (12 << 20) else None)
+    return pl.pallas_call(
+        functools.partial(_encode_acc_kernel, K, interpret),
+        grid=(A // ta, B // tb),
+        in_specs=[smem_spec(), slot,
+                  pl.BlockSpec((K, ta, tb), lambda i, j: (0, i, j))],
+        out_specs=slot,
+        out_shape=jax.ShapeDtypeStruct((R, B), jnp.float32),
+        input_output_aliases={1: 0},
+        compiler_params=params,
+        interpret=interpret,
+        name="coded_encode_acc",
+    )(C.reshape(-1).astype(jnp.float32), acc, G.reshape(K, A, B))

@@ -107,6 +107,7 @@ def build_train_lowering(arch: str, shape_name: str, mesh, *,
                       "codec_backend": arts.codec.backend.name,
                       "wire_buckets": (len(arts.pack_plan.buckets)
                                        if arts.pack_plan else 0),
+                      "copy_free_share": round(arts.copy_free_share, 6),
                       "loads": list(arts.loads),
                       "partial": partial}
 

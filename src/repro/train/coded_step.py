@@ -10,18 +10,25 @@ subsets.  The step (manual over data axes, GSPMD-auto over 'model'):
      redundancy d is the paper's intended cost),
   2. folds each subset gradient into the l/m encoding on the fly with the
      worker's coefficient rows C[i, j, :] (paper eq. 17/18 — never
-     materializes the (d, l) partial-gradient matrix),
-  3. multiplies by the responder mask (stragglers transmit nothing; proves
-     the decode is independent of straggler payloads),
-  4. packs the coded encodings into the static ``PackPlan``'s bucketed flat
-     wire buffers (default; ``packed=False`` keeps the per-leaf escape
-     hatch) and decodes the summed gradient with the host-computed float64
-     weights W (zero rows at stragglers) via the gather or a2a schedule —
-     one collective choreography + one fused contraction per bucket,
-  5. runs the optimizer update (replicated over data axes, model-sharded).
+     materializes the (d, l) partial-gradient matrix), scaled by the
+     responder mask (stragglers transmit nothing; proves the decode is
+     independent of straggler payloads).  By default the fold writes
+     straight into the leaf's rows of the static ``PackPlan``'s
+     ``(rows, lanes)`` wire buckets (``Codec.encode_into``; ``packed=False``
+     keeps the per-leaf escape hatch),
+  3. decodes the summed gradient with the host-computed float64 weights W
+     (zero rows at stragglers) via the gather or a2a schedule — one
+     collective choreography + one fused contraction per bucket — and reads
+     each leaf's gradient back as a row slice of the decoded buffer,
+  4. runs the optimizer update (replicated over data axes, model-sharded).
+
+With each leaf grouped on its leading dim (``repro.coding.plan``), every view
+of the coded gradient between backward and the optimizer is a reshape: the
+only passes over it are the encode kernel, the exchange and the decode
+kernel.
 
 Each phase runs under a ``jax.named_scope``: ``coded.grad`` (forward and
-backward), ``coded.encode`` (fold, mask, pack), ``coded.exchange`` (every
+backward), ``coded.encode`` (the fold into the wire), ``coded.exchange`` (every
 collective, set in ``repro.coding.wire``), ``coded.decode`` and
 ``coded.apply`` (the optimizer).  The scopes are op metadata only; a device
 trace carries them in each op's name path, so its time can be put down to a
@@ -95,6 +102,13 @@ class StepArtifacts:
     # share ONE executable instead of tracing twice
     _exe_cache: dict = dataclasses.field(default_factory=dict, init=False,
                                          repr=False, compare=False)
+
+    @property
+    def copy_free_share(self) -> float:
+        """Share of the coded gradient's elements whose way from backward to
+        the optimizer holds no relayout (``PackPlan.free_share``); 0 on the
+        per-leaf path."""
+        return self.pack_plan.free_share if self.pack_plan else 0.0
 
     # ---- benchmark / driver hooks --------------------------------------
     @staticmethod
@@ -264,8 +278,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     current batch at pre-update params — the decode collective and the
     encode compute are dataflow-independent, so XLA overlaps them — and
     drain retires the last buffers.  The encode folds each subset gradient
-    straight into the 128-aligned wire layout (``Codec.encode_into``, the
-    accumulating encode kernel) instead of materialise-then-pack.
+    straight into the wire buckets (``Codec.encode_into``, the
+    accumulating encode kernel), as the synchronous step does.
     Requires ``packed=True``, an encoding schedule (not psum) and
     ``partial=False``; the synchronous executable is still built and is
     byte-identical to the non-pipelined build.  Parity contract: fill
@@ -312,7 +326,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         if not packed:
             raise ValueError(
                 "pipelined=True requires packed=True: the wire state IS the "
-                "PackPlan's bucketed flat buffers")
+                "PackPlan's wire buckets")
         if partial:
             raise ValueError(
                 "pipelined partial-recovery is unsupported: the err_factor "
@@ -333,28 +347,60 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     pspecs = sharding.param_specs(pshapes, ms)
     oshapes = jax.eval_shape(optimizer.init, pshapes)
     ospecs = sharding.opt_state_specs(oshapes, pspecs)
-    plans = codec.plan(pshapes, pspecs)
+    plans = codec.plan(pshapes, pspecs, model_size=ms,
+                       device=mesh.devices.flat[0])
     coded_frac = codec.coded_fraction(pshapes, plans)
-    # §Tentpole (packed wire): static layout of every coded leaf's encoding
-    # into bucketed 128-aligned flat buffers (bucket key: wire dtype x
-    # effective model sharding).  Computed once here; the step then issues
-    # one collective choreography + one fused contraction per bucket.
+    # packed wire: static layout of every coded leaf's encoding as a row
+    # range of a (rows, lanes) bucket (bucket key: wire dtype x effective
+    # model sharding x lane width).  Computed once here; the step then
+    # issues one collective choreography + one fused contraction per bucket.
     pplan = (codec.pack_plan(pshapes, plans, specs=pspecs, model_size=ms)
              if packed and codec.schedule.uses_encoding else None)
     flat_plans = jax.tree.leaves(
         plans, is_leaf=lambda x: isinstance(x, coding.LeafPlan))
+    wire_dtype = jnp.dtype(codec.wire_dtype)
+    if pplan is not None:
+        # where every coded leaf lands in the wire buckets (the fold
+        # targets) and the psum-fallback leaves beside them
+        flat_pshapes = jax.tree.leaves(pshapes)
+        slot_items = [(bi, s) for bi, b in enumerate(pplan.buckets)
+                      for s in b.slots]
+        small_ix = [i for i, pl_ in enumerate(flat_plans) if not pl_.coded]
+        small_shapes = [tuple(flat_pshapes[i].shape) for i in small_ix]
+        small_sizes = [int(np.prod(sh)) for sh in small_shapes]
+
+    def packed_zero():
+        """The packed fold's carry: zero wire buckets and zero
+        psum-fallback accumulators."""
+        return (tuple(codec.bucket_zeros(pplan)),
+                tuple(jnp.zeros(sh, jnp.float32) for sh in small_shapes))
+
+    def packed_fold(state, g, cm, rj):
+        """Fold one subset gradient ``g`` into the packed carry: each coded
+        leaf into its bucket rows with the masked coefficient row ``cm``
+        (``Codec.encode_into``, in place), each fallback leaf rho-weighted."""
+        bufs, smalls = state
+        flat_g = jax.tree.leaves(g)
+        bufs = list(bufs)
+        for bi, slot in slot_items:
+            bufs[bi] = codec.encode_into(
+                bufs[bi], flat_g[slot.leaf_index].astype(jnp.float32), cm,
+                slot)
+        smalls = tuple(sm + rj * flat_g[i].astype(jnp.float32)
+                       for sm, i in zip(smalls, small_ix))
+        return tuple(bufs), smalls
 
     # §Perf lever (enc_constraint): the encoding of a model-sharded leaf can
     # silently lose its 'model' sharding at the manual-collective boundary
     # (GSPMD resharding — grok's 10 TB all-gather).  This computes the spec
-    # each encoding *should* keep: dims = [group_dim] + rest, model entries
-    # preserved.
+    # each encoding *should* keep: dims in ``LeafPlan.group_perm`` order,
+    # the grouping dim replicated, model entries preserved.
     def _enc_spec(pl, spec):
         if not pl.coded:
             return None
         entries = [e if e == "model" else None for e in tuple(spec)]
-        del entries[pl.group_dim]
-        return P(*([None] + entries))
+        perm = pl.group_perm(len(entries))
+        return P(*([None] + [entries[d] for d in perm[1:]]))
 
     enc_specs = jax.tree.map(
         _enc_spec, plans, pspecs,
@@ -378,78 +424,85 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
         def per_subset(carry, xs):
             if partial:
-                enc, cnt, loss_acc, gss_acc = carry
+                state, cnt, loss_acc, gss_acc = carry
             else:
-                enc, cnt, loss_acc = carry
+                state, cnt, loss_acc = carry
             sub, cj, rj = xs
             with jax.named_scope("coded.grad"):
                 (lval, c), g = jax.value_and_grad(loss_counts_fn,
                                                   has_aux=True)(params, sub)
             cnt = jax.tree.map(jnp.add, cnt, c)
+            # stragglers transmit nothing: the responder mask scales the
+            # coefficient row, so a straggler's whole wire is zero
+            cm = cj * mask_i
 
             def fold(e, gleaf, pl):
                 if not pl.coded:
                     return e + rj * gleaf.astype(jnp.float32)
-                contrib = codec.encode_leaf(gleaf.astype(jnp.float32), cj, pl)
-                # contribution arrives as (Dg/m, *rest-moved); match e's layout
-                return e + contrib
+                return e + codec.encode_leaf(gleaf.astype(jnp.float32), cm,
+                                             pl)
 
             with jax.named_scope("coded.encode"):
-                enc = jax.tree.map(fold, enc, g, plans)
+                if pplan is not None:
+                    state = packed_fold(state, g, cm, rj)
+                else:
+                    state = jax.tree.map(fold, state, g, plans)
             if partial:
                 # rho-weighted subset gradient sumsq: psummed it becomes
                 # sum_j ||g_j||^2 over covered subsets — the certificate's
                 # gradient-norm term
                 gss = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                           for l in jax.tree.leaves(g))
-                return (enc, cnt, loss_acc + rj * lval,
+                return (state, cnt, loss_acc + rj * lval,
                         gss_acc + rj * gss), None
-            return (enc, cnt, loss_acc + rj * lval), None
+            return (state, cnt, loss_acc + rj * lval), None
 
-        init = (jax.tree.map(codec.encoding_zero, params, plans),
+        init = (packed_zero() if pplan is not None
+                else jax.tree.map(codec.encoding_zero, params, plans),
                 counts_zero(), jnp.zeros((), jnp.float32))
         if partial:
             init = init + (jnp.zeros((), jnp.float32),)
-            (enc, cnt, loss_sum, gss_sum), _ = jax.lax.scan(
-                per_subset, init, (lb, Ci, rho_i))
+        carry, _ = jax.lax.scan(per_subset, init, (lb, Ci, rho_i))
+        if partial:
+            state, cnt, loss_sum, gss_sum = carry
         else:
-            (enc, cnt, loss_sum), _ = jax.lax.scan(per_subset, init,
-                                                   (lb, Ci, rho_i))
-
-        # stragglers transmit nothing — zero the payload to prove independence
-        with jax.named_scope("coded.encode"):
-            enc = jax.tree.map(
-                lambda e, pl: codec.to_wire(e, mask_i) if pl.coded else e,
-                enc, plans)
-        if ENC_CONSTRAINT:
-            flat_e, td = jax.tree.flatten(enc)
-            flat_s = td.flatten_up_to(enc_specs)
-            flat_p = [p for p in jax.tree.leaves(
-                plans, is_leaf=lambda x: isinstance(x, coding.LeafPlan))]
-            flat_e = [jax.lax.with_sharding_constraint(e, s)
-                      if (pl.coded and s is not None and "model" in tuple(s))
-                      else e
-                      for e, s, pl in zip(flat_e, flat_s, flat_p)]
-            enc = td.unflatten(flat_e)
+            state, cnt, loss_sum = carry
 
         if pplan is not None:
-            # packed path: coded leaves ride the plan's flat buckets (one
-            # collective + one fused (n, L) contraction each); the psum
-            # fallback leaves are summed through a single concatenated
-            # all-reduce instead of one per leaf.
-            flat_enc, td = jax.tree.flatten(enc)
-            flat_grads = list(flat_enc)
+            # packed path: coded leaves ride the plan's buckets (one
+            # collective + one fused contraction each) and come back as row
+            # slices of the decoded buffers; the psum fallback leaves are
+            # summed through a single concatenated all-reduce instead of
+            # one per leaf.
+            bufs, smalls = state
+            flat_grads: list = [None] * len(flat_plans)
+            for i, sm in zip(small_ix, smalls):
+                flat_grads[i] = sm
             with jax.named_scope("coded.encode"):
-                bufs = codec.pack(flat_enc, pplan)
+                wires = [b.astype(wire_dtype) for b in bufs]
             with jax.named_scope("coded.decode"):
-                decs = [codec.decode_packed(b, W, data_axes) for b in bufs]
-                for i, g_ in codec.unpack(decs, pplan).items():
-                    flat_grads[i] = g_
-            for i, g_ in coding.psum_fallback(flat_enc, flat_plans,
-                                              data_axes).items():
-                flat_grads[i] = g_
-            grads = td.unflatten(flat_grads)
+                decs = [codec.decode_packed(w, W, data_axes) for w in wires]
+                unpacked = codec.unpack(decs, pplan)
+            unpacked.update(coding.psum_fallback(flat_grads, flat_plans,
+                                                 data_axes))
+            grads = jax.tree.unflatten(
+                jax.tree.structure(params),
+                [unpacked[i] for i in range(len(flat_plans))])
         else:
+            with jax.named_scope("coded.encode"):
+                enc = jax.tree.map(
+                    lambda e, pl: e.astype(wire_dtype) if pl.coded else e,
+                    state, plans)
+            if ENC_CONSTRAINT:
+                flat_e, td = jax.tree.flatten(enc)
+                flat_s = td.flatten_up_to(enc_specs)
+                flat_e = [jax.lax.with_sharding_constraint(e, s)
+                          if (pl.coded and s is not None
+                              and "model" in tuple(s))
+                          else e
+                          for e, s, pl in zip(flat_e, flat_s, flat_plans)]
+                enc = td.unflatten(flat_e)
+
             def dec_one(e, pl):
                 if not pl.coded:
                     return wire.psum(e, data_axes)
@@ -506,50 +559,28 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     fn = body_psum if not codec.schedule.uses_encoding else body
 
     # --- pipelined three-phase bodies -----------------------------------
-    # Shared static tables: where every coded leaf lands in the wire
-    # buckets (fused-encode fold targets) and how the psum-fallback leaves
-    # + the masked loss scalar lay out in the flat (S,) side buffer.
-    if pipelined:
-        flat_pshapes = jax.tree.leaves(pshapes)
-        slot_items = [(bi, s) for bi, b in enumerate(pplan.buckets)
-                      for s in b.slots]
-        small_ix = [i for i, pl_ in enumerate(flat_plans) if not pl_.coded]
-        small_shapes = [tuple(flat_pshapes[i].shape) for i in small_ix]
-        small_sizes = [int(np.prod(sh)) for sh in small_shapes]
-
     def _encode_wire(params, lb, Ci, rho_i, mask_i):
         """One batch's backward + fused encode: scan the d subsets, folding
-        each subset gradient straight into the per-bucket f32 wire
-        accumulators (``Codec.encode_into`` — no materialise-then-pack
-        copy) and the rho-weighted psum-fallback accumulators.  Returns
-        (per-bucket wire buffers in the wire dtype, (S,) f32 side buffer =
-        concat(small-leaf flats) + [masked loss]).  Bit-identical to the
-        synchronous body's fold -> to_wire -> pack_bucket: the add order
-        per element is the same and the padding gaps stay exactly zero."""
+        each subset gradient straight into the wire buckets and the
+        rho-weighted psum-fallback accumulators (``packed_fold``, as the
+        synchronous body does).  Returns (per-bucket wire buffers in the
+        wire dtype, (S,) f32 side buffer = concat(small-leaf flats) +
+        [masked loss]).  Bit-identical to the synchronous body's wire: the
+        same fold, and the padding rows stay exactly zero."""
         def per_subset(carry, xs):
-            accs, smalls, loss_acc = carry
+            state, loss_acc = carry
             sub, cj, rj = xs
             with jax.named_scope("coded.grad"):
                 lval, g = jax.value_and_grad(loss_fn)(params, sub)
-            flat_g = jax.tree.leaves(g)
-            accs = list(accs)
             with jax.named_scope("coded.encode"):
-                for bi, slot in slot_items:
-                    accs[bi] = codec.encode_into(
-                        accs[bi], flat_g[slot.leaf_index].astype(jnp.float32),
-                        cj, slot)
-                smalls = tuple(sm + rj * flat_g[i].astype(jnp.float32)
-                               for sm, i in zip(smalls, small_ix))
-            return (tuple(accs), smalls, loss_acc + rj * lval), None
+                state = packed_fold(state, g, cj * mask_i, rj)
+            return (state, loss_acc + rj * lval), None
 
-        init = (tuple(jnp.zeros((b.size,), jnp.float32)
-                      for b in pplan.buckets),
-                tuple(jnp.zeros(sh, jnp.float32) for sh in small_shapes),
-                jnp.zeros((), jnp.float32))
-        (accs, smalls, loss_sum), _ = jax.lax.scan(per_subset, init,
-                                                   (lb, Ci, rho_i))
+        ((bufs, smalls), loss_sum), _ = jax.lax.scan(
+            per_subset, (packed_zero(), jnp.zeros((), jnp.float32)),
+            (lb, Ci, rho_i))
         with jax.named_scope("coded.encode"):
-            wires = tuple(codec.to_wire(a, mask_i) for a in accs)
+            wires = tuple(b.astype(wire_dtype) for b in bufs)
             side = jnp.concatenate([s_.reshape(-1) for s_ in smalls]
                                    + [(loss_sum * mask_i)[None]])
         return wires, side
@@ -705,9 +736,9 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     def make_pipeline(batch_shapes) -> PipelineFns:
         """Build the un-jitted fill/steady/drain triple for one batch shape.
 
-        Wire-state arrays are (n, L_b) / (n, S) with dim 0 split over the
-        data axes — each worker's shard is its own wire buffer, so the
-        state round-trips through jit without resharding.
+        Wire-state arrays are (n, rows_b, lanes_b) / (n, S) with dim 0 split
+        over the data axes — each worker's shard is its own wire buffer, so
+        the state round-trips through jit without resharding.
         """
         bspecs = sharding.batch_specs(batch_shapes, data_axes)
         dspec = P(data_axes if len(data_axes) > 1 else data_axes[0])

@@ -88,17 +88,24 @@ def test_pallas_backend_executes_kernels(monkeypatch):
     step is traced — the regression the dead use_kernels flag shipped with."""
     calls = {"encode": 0, "decode": 0}
     real_enc = coding_backends._encode_mod.coded_encode
+    real_acc = coding_backends._encode_mod.coded_encode_acc
     real_dec = coding_backends._decode_mod.coded_decode
 
     def spy_enc(G, C, **kw):
         calls["encode"] += 1
         return real_enc(G, C, **kw)
 
+    def spy_acc(acc, G, C, **kw):        # the packed step's encode-into-slot
+        calls["encode"] += 1
+        return real_acc(acc, G, C, **kw)
+
     def spy_dec(F, W, **kw):
         calls["decode"] += 1
         return real_dec(F, W, **kw)
 
     monkeypatch.setattr(coding_backends._encode_mod, "coded_encode", spy_enc)
+    monkeypatch.setattr(coding_backends._encode_mod, "coded_encode_acc",
+                        spy_acc)
     monkeypatch.setattr(coding_backends._decode_mod, "coded_decode", spy_dec)
 
     cfg = _linear_cfg()
@@ -148,7 +155,7 @@ def test_use_kernels_flag_is_gone():
 
 
 # ---------------------------------------------------------- unit-level parity
-@pytest.mark.parametrize("shape,gdim", [((64,), 0), ((6, 8, 5), 1),
+@pytest.mark.parametrize("shape,gdim", [((64,), 0), ((6, 8, 5), 0),
                                         ((16, 3), 0)])
 @pytest.mark.parametrize("backend", ["ref", "interpret"])
 def test_encode_leaf_backend_parity(shape, gdim, backend):

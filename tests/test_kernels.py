@@ -88,21 +88,48 @@ def test_kernel_end_to_end_roundtrip():
     np.testing.assert_allclose(got, Gfull.sum(0), rtol=1e-4, atol=1e-4)
 
 
-# ragged shapes: an R past one lane block that is no multiple of 128 (the
-# 1/8 qwen3 vocabulary), and 2-D lengths that need lane padding
-@pytest.mark.parametrize("shape", [(1, 2, 16, 18992), (2, 3, 40, 300),
-                                   (3, 2, 1000), (1, 2, 77)])
-def test_encode_acc_ragged_blocks(shape):
-    G = jnp.asarray(RNG.standard_normal(shape), jnp.float32)
+# a leaf's slot in a wire bucket, the leaf lifted as the codec lifts it:
+# its own plane (an R past one lane block that is no multiple of 128 — the
+# 1/8 qwen3 vocabulary — or 300 lanes), or flattened onto 128-lane rows
+# (1-D lengths); the grid covers the slot's rows alone, in place.  K >= 2:
+# at K = 1 XLA:CPU fuses interpret mode's acc + c * x into one FMA, an ulp
+# off the two-step spelling.
+@pytest.mark.parametrize("shape,row", [((1, 2, 16, 18992), 16),
+                                       ((2, 3, 40, 300), 32),
+                                       ((3, 2, 1000), 0), ((1, 2, 77), 48),
+                                       ((1, 2, 4096, 128), 64),
+                                       ((1, 3, 16, 300), 48)])
+def test_encode_acc_ragged_blocks(shape, row):
+    from repro.coding.layout import to_slot_plane
+    G = to_slot_plane(jnp.asarray(RNG.standard_normal(shape), jnp.float32),
+                      shape[2:], 2)
+    R = row + G.shape[2] + 16
     C = jnp.asarray(RNG.standard_normal(shape[:2]), jnp.float32)
-    acc = jnp.asarray(RNG.standard_normal(shape[2:]), jnp.float32)
-    want = acc + coded_encode(G, C, interpret=True)
-    got = coded_encode_acc(acc, G, C, interpret=True)
+    buf = jnp.asarray(RNG.standard_normal((R, G.shape[3])), jnp.float32)
+    got = np.asarray(coded_encode_acc(buf, G, C, row=row, interpret=True))
     # the fused fold is bit-identical to acc + encode (shared f32 sequence)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    oracle = ref.coded_encode_ref(G, C)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(acc + oracle),
-                               rtol=2e-5, atol=2e-5)
+    # on the slot's rows, and leaves every other row as it was
+    want = np.asarray(buf).copy()
+    rows = slice(row, row + G.shape[2])
+    want[rows] += np.asarray(coded_encode(G, C, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    oracle = np.asarray(buf).copy()
+    oracle[rows] += np.asarray(ref.coded_encode_ref(G, C))
+    np.testing.assert_allclose(got, oracle, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("A,B,bpe", [(8192, 12288, 12), (18992, 2048, 12),
+                                     (1024, 18992, 32), (262144, 128, 12),
+                                     (8, 300, 40)])
+def test_slot_tiles_divide_the_slot(A, B, bpe):
+    """Slot tiles divide the slot exactly (a block never leaves its rows or
+    the bucket's lanes), rows in 8s and lanes whole or in 128s, within the
+    budget unless a whole lane axis of no 128-multiple exceeds it."""
+    from repro.kernels.coded_encode import BLOCK_BYTES, slot_tiles
+    ta, tb = slot_tiles(A, B, bpe)
+    assert A % ta == 0 and ta % 8 == 0
+    assert B % tb == 0 and (tb == B or tb % 128 == 0)
+    assert ta * tb * bpe <= BLOCK_BYTES or (ta == 8 and tb == B)
 
 
 @pytest.mark.parametrize("n,m,L", [(4, 2, 600 * 128), (3, 1, 1024),

@@ -39,6 +39,88 @@ CODE = make_code(N, 3, 1, M)
 
 
 # ---------------------------------------------------------------- pack plan
+@pytest.mark.parametrize("shape,spec,model_size,n_split,gdim", [
+    # the leading dim is taken whenever it is usable, not the largest
+    ((4, 2048, 6144), None, None, 1, 0),
+    ((6, 8, 5), None, None, 1, 0),
+    # a size-1 model axis shards nothing: its entries count as replicated
+    ((4, 6144, 2048), (None, "model", None), 1, 1, 0),
+    ((18992, 2048), ("model", None), 1, 1, 0),
+    # ... but a real (or unknown) model axis keeps the dim whole-or-nothing
+    ((18992, 2048), ("model", None), 2, 1, 1),
+    ((18992, 2048), ("model", None), None, 1, 1),
+    # the old rule otherwise: a2a at 4 layers, n = 4, m = 2 needs 8 | dim
+    ((4, 2048, 6144), (None, None, "model"), 1, N, 2),
+    ((4, 16, 128, 2048), (None, "model", None, None), 1, N, 3),
+])
+def test_plan_takes_the_leading_dim(shape, spec, model_size, n_split, gdim):
+    plan = coding.plan_leaf(shape, spec, M, n_split, model_size)
+    assert plan.coded and plan.group_dim == gdim
+    assert plan.leads == (gdim == 0)
+
+
+def test_plan_follows_the_device_order():
+    """A leaf the device lays out with its dims swapped (an f32
+    (2048, 18992) lives as (18992, 2048)) groups on its device-leading
+    dim, and its encoding takes the dims in device order."""
+    plan = coding.plan_leaf((2048, 18992), None, M, order=(1, 0))
+    assert plan.group_dim == 1 and plan.leads and plan.order == (1, 0)
+    assert enc_shape((2048, 18992), plan, M) == (18992 // M, 2048)
+    # the row-major order is the default and is not stored
+    assert coding.plan_leaf((4, 64), None, M, order=(0, 1)).order == ()
+    assert coding.plan.device_order((3, 5, 7)) == (0, 1, 2)
+    g = jnp.asarray(RNG.standard_normal((8, 6)), jnp.float32)
+    p = coding.plan_leaf((8, 6), None, M, order=(1, 0))
+    x = coding.leaf_to_groups(g, p, M)
+    assert x.shape == (M, 3, 8)
+    np.testing.assert_array_equal(np.asarray(coding.groups_to_leaf(x, p)),
+                                  np.asarray(g))
+
+
+@pytest.mark.parametrize("shape", [(4, 64, 256), (4, 32, 16, 128),
+                                   (2, 24, 8, 384)])
+def test_leaf_groups_plane_roundtrip_is_a_reshape(shape):
+    """leaf -> groups -> slot plane -> leaf is the identity for stacked
+    [L, a, b] and [L, D, H, hd] leaves grouped on the layer dim, and its
+    jaxpr holds no transpose: every view is a reshape."""
+    from repro.coding.layout import from_slot_plane, slot_plane, to_slot_plane
+    plan = coding.plan_leaf(shape, None, M)
+    assert plan.group_dim == 0
+    es = enc_shape(shape, plan, M)
+    rows, lanes, free = slot_plane(es)
+    assert free and lanes == shape[-1]
+
+    def roundtrip(g):
+        x = to_slot_plane(coding.leaf_to_groups(g, plan, M), es, 1)
+        assert x.shape == (M, rows, lanes)
+        return coding.groups_to_leaf(from_slot_plane(x, es, 1), plan)
+
+    g = jnp.asarray(RNG.standard_normal(shape), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(roundtrip(g)), np.asarray(g))
+    prims = {e.primitive.name for e in jax.make_jaxpr(roundtrip)(g).eqns}
+    assert prims <= {"reshape"}, prims
+
+
+def test_pack_plan_buckets_by_lane_width_and_counts_free_share():
+    """Each lane width is its own bucket of whole-row slots; narrow or
+    unaligned planes take the flat 128-lane lift and are not copy-free."""
+    tree = {"mlp": jax.ShapeDtypeStruct((4, 64, 256), jnp.float32),
+            "attn": jax.ShapeDtypeStruct((4, 16, 8, 128), jnp.float32),
+            "gain": jax.ShapeDtypeStruct((4, 256), jnp.float32)}
+    plans = coding.plan_tree(tree, None, M)
+    pp = make_pack_plan(tree, plans, m=M, n=N)
+    assert sorted(b.lanes for b in pp.buckets) == [128, 256]
+    for b in pp.buckets:
+        assert b.rows % 16 == 0 and b.rows % N == 0
+        for s in b.slots:
+            assert s.row % 16 == 0 and s.row + s.rows <= b.rows
+    free = {s.leaf_index: s.free for b in pp.buckets for s in b.slots}
+    # tree order: attn, gain, mlp — the (2, 256) gain group is no 8-row plane
+    assert free == {0: True, 1: False, 2: True}
+    assert pp.free_share == (4 * 16 * 8 * 128 + 4 * 64 * 256) / M / (
+        pp.unpadded_elems)
+
+
 def test_enc_shape_moves_group_dim_first():
     plan = coding.LeafPlan(coded=True, group_dim=1)
     assert enc_shape((3, 8, 5), plan, m=2) == (4, 3, 5)
@@ -72,8 +154,9 @@ def test_pack_plan_buckets_by_model_sharding():
     tree = {"w1": jax.ShapeDtypeStruct((8, 16), jnp.float32),
             "w2": jax.ShapeDtypeStruct((8, 16), jnp.float32),
             "w3": jax.ShapeDtypeStruct((8, 4, 16), jnp.float32)}
-    # w1/w2 encode to (V, 16-model) — pattern (1,); w3's largest replicated
-    # dim is dim 0, so its encoding is (V, 4, 16-model) — pattern (2,)
+    # w1/w2 encode to (V, 16-model) — pattern (1,); w3 groups on its
+    # leading dim, so its encoding is (V, 4, 16-model) — pattern (2,); all
+    # three take the flat 128-lane lift (a 16-wide tail)
     specs = {"w1": P(None, "model"), "w2": P(None, "model"),
              "w3": P(None, None, "model")}
     plans = coding.plan_tree(tree, specs, M)
@@ -136,16 +219,19 @@ def test_pack_unpack_roundtrip_is_identity():
            for x, pl in zip(flat, flat_plans)]
     pp = make_pack_plan(tree, plans, m=M, n=N)
     buf = pack_bucket(enc, pp.buckets[0], jnp.float32)
-    assert buf.shape == (pp.buckets[0].size,)
+    assert buf.shape == pp.buckets[0].shape == (pp.buckets[0].rows,
+                                                pp.buckets[0].lanes)
     # fake a decode that replicates the buffer into m identical rows
     dec = jnp.stack([buf, buf])
     out = unpack_bucket(dec, pp.buckets[0])
+    flat_buf = buf.reshape(-1)
     for s, e, x in zip(pp.buckets[0].slots, enc, flat):
         got = out[s.leaf_index]
         assert got.shape == x.shape
         # each group's m copies came from the same encoding element
         np.testing.assert_array_equal(
-            np.asarray(jax.lax.slice_in_dim(buf, s.offset, s.offset + s.size)),
+            np.asarray(jax.lax.slice_in_dim(flat_buf, s.offset,
+                                            s.offset + s.size)),
             np.asarray(e).reshape(-1))
 
 

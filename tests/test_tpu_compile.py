@@ -10,10 +10,12 @@ coded step the way a chip run would, at no chip time:
 - every kernel at the shapes of the packed coded step of qwen3-1.7b at its
   published widths (d_model 2048, d_ff 6144), cut to 4 layers and 18992
   vocabulary rows, under code (4, 3, 1, 2): each coded leaf's encode, the
-  accumulating encode into a wire slot, the decode of a whole wire bucket
-  and of the widest 3-D leaf, and the fused decode-and-apply of a bucket;
+  accumulating encode into every slot of every ``(rows, lanes)`` wire
+  bucket, the decode of each whole bucket and of the widest 3-D leaf, and
+  the fused decode-and-apply of a bucket;
 - the whole one-chip step of ``chip_smoke.py`` (code (1, 1, 0, 1), AdamW,
-  4 x 2048 tokens) on the compiled kernels, which must fit the chip;
+  4 x 2048 tokens) on the compiled kernels, which must fit the chip and
+  hold no relayout of the coded gradient between backward and AdamW;
 - a model's forward and backward at the coded cell's attention shape
   (2 x 2048 tokens, 16 query and 8 KV heads of 128, bf16) on the fused
   splash attention kernels, with no (S, S) scores left in the program.
@@ -25,6 +27,7 @@ is off around the compiles (an entry written for a described chip cannot be
 read back without one).
 """
 import dataclasses
+import math
 import os
 import re
 
@@ -37,7 +40,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro import coding
 from repro.coding.backends import PallasBackend
-from repro.coding.layout import flatten_rest, leaf_to_groups
+from repro.coding.layout import leaf_to_groups, to_plane_view
 from repro.configs import get_config
 from repro.core import make_code
 from repro.data import CodedBatcher, make_synthetic_batch
@@ -112,13 +115,20 @@ def _compile_kernel(name, fn, *args):
     return compiled
 
 
-def _coded_leaves():
-    """(name, canonical (d, m, V[, R]) encode shape, LeafPlan) of every
-    distinct coded leaf of the packed step."""
+def _plans(codec, device):
+    """The step's shapes and leaf plans for ``codec`` on ``device``."""
     pshapes = jax.eval_shape(
         lambda: model_api.init(jax.random.PRNGKey(0), CFG))
+    plans = codec.plan(pshapes, sharding.param_specs(pshapes, 1),
+                       model_size=1, device=device)
+    return pshapes, plans
+
+
+def _coded_leaves(device):
+    """(name, canonical (d, m, A[, B]) encode shape, LeafPlan) of every
+    distinct coded leaf of the per-leaf step."""
     codec = coding.make_codec(CODE, schedule="a2a", backend="ref")
-    plans = codec.plan(pshapes, sharding.param_specs(pshapes, 1))
+    pshapes, plans = _plans(codec, device)
     seen, out = set(), []
     for (path, leaf), plan in zip(
             jax.tree_util.tree_leaves_with_path(pshapes),
@@ -126,31 +136,28 @@ def _coded_leaves():
                             is_leaf=lambda x: isinstance(x, coding.LeafPlan))):
         if not plan.coded:
             continue
-        G = jax.eval_shape(lambda g, p=plan: flatten_rest(
-            leaf_to_groups(g, p, CODE.m), 2)[None], leaf)
+        G = jax.eval_shape(lambda g, p=plan: to_plane_view(
+            leaf_to_groups(g, p, CODE.m), 1)[None], leaf)
         if G.shape not in seen:
             seen.add(G.shape)
             out.append((jax.tree_util.keystr(path), G.shape, plan))
     return out
 
 
-def _bucket(codec_schedule="gather"):
-    """The step's packed wire plan (one f32 bucket at a model axis of 1)."""
-    pshapes = jax.eval_shape(
-        lambda: model_api.init(jax.random.PRNGKey(0), CFG))
+def _buckets(device, codec_schedule="gather"):
+    """The packed step's wire buckets (model axis of 1), widest first."""
     codec = coding.make_codec(CODE, schedule=codec_schedule, backend="ref")
-    plans = codec.plan(pshapes, sharding.param_specs(pshapes, 1))
-    (bucket,) = codec.pack_plan(pshapes, plans).buckets
-    return bucket
+    pshapes, plans = _plans(codec, device)
+    pp = codec.pack_plan(pshapes, plans)
+    return sorted(pp.buckets, key=lambda b: -b.size)
 
 
 def test_encode_every_coded_leaf(one_chip, no_persistent_cache):
     """coded_encode (2-D and 3-D operands) at every coded leaf of the
-    step, including the 18992-row (ragged past 128 lanes) unembedding."""
-    leaves = _coded_leaves()
+    per-leaf step, including a narrow tail on the flat lift."""
+    leaves = _coded_leaves(next(iter(one_chip.device_set)))
     assert any(len(G) == 3 for _, G, _ in leaves)
     assert any(len(G) == 4 for _, G, _ in leaves)
-    assert any(18992 in G for _, G, _ in leaves)
     C = _sds((1, CODE.m), jnp.float32, one_chip)
     for name, G, _ in leaves:
         _compile_kernel("coded_encode", lambda g, c: coded_encode(g, c),
@@ -158,62 +165,69 @@ def test_encode_every_coded_leaf(one_chip, no_persistent_cache):
 
 
 def test_encode_acc_into_wire_slot(one_chip, no_persistent_cache):
-    """coded_encode_acc folds the widest 2-D and 3-D leaves into their f32
-    wire slots."""
-    leaves = _coded_leaves()
+    """coded_encode_acc folds every slot's (1, m, rows, lanes) plane into
+    its rows of the whole (rows, lanes) bucket, in place (no scratch the
+    size of the bucket), for every bucket of the coded cell's step."""
+    buckets = _buckets(next(iter(one_chip.device_set)))
+    assert len(buckets) >= 2 and all(b.rows % 16 == 0
+                                     for b in buckets)
     C = _sds((1, CODE.m), jnp.float32, one_chip)
-    for ndim in (3, 4):
-        _, G, _ = max((x for x in leaves if len(x[1]) == ndim),
-                      key=lambda x: np.prod(x[1]))
-        acc = _sds(G[2:], jnp.float32, one_chip)
-        _compile_kernel("coded_encode_acc",
-                        lambda a, g, c: coded_encode_acc(a, g, c),
-                        acc, _sds(G, jnp.float32, one_chip), C)
+    for b in buckets:
+        for s in {s.rows: s for s in b.slots}.values():
+            compiled = _compile_kernel(
+                "coded_encode_acc",
+                lambda a, g, c, r=s.row: coded_encode_acc(a, g, c, row=r),
+                _sds(b.shape, jnp.float32, one_chip),
+                _sds((1, CODE.m, s.rows, b.lanes), jnp.float32, one_chip), C)
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < b.size * 4 // 2, (b.key, s.rows)
 
 
 @pytest.mark.parametrize("schedule", ["gather", "a2a"])
 def test_decode_full_wire_bucket(one_chip, no_persistent_cache, schedule):
-    """coded_decode on the whole (n, L) bucket the gather schedule
-    contracts, and on the (n, L/n) slice a2a contracts."""
-    L = _bucket(schedule).size
-    assert L > 10**8                       # the step's whole coded gradient
-    width = L if schedule == "gather" else L // CODE.n
-    compiled = _compile_kernel(
-        "coded_decode",
-        lambda f, w: coded_decode(f, w, out_dtype=jnp.float32),
-        _sds((CODE.n, width), jnp.float32, one_chip),
-        _sds((CODE.n, CODE.m), jnp.float32, one_chip))
-    # an (m, L) result: m is never the lane axis, so no 64x padding
-    assert compiled.memory_analysis().output_size_in_bytes \
-        < 1.01 * CODE.m * width * 4
+    """coded_decode on every bucket's (n, rows, lanes) stack the gather
+    schedule contracts, and on the (n, rows/n, lanes) slice a2a
+    contracts — together the step's whole coded gradient, with no pad."""
+    buckets = _buckets(next(iter(one_chip.device_set)), schedule)
+    assert sum(b.size for b in buckets) > 10**8
+    for b in buckets:
+        rows = b.rows if schedule == "gather" else b.rows // CODE.n
+        compiled = _compile_kernel(
+            "coded_decode",
+            lambda f, w: coded_decode(f, w, out_dtype=jnp.float32),
+            _sds((CODE.n, rows, b.lanes), jnp.float32, one_chip),
+            _sds((CODE.n, CODE.m), jnp.float32, one_chip))
+        # an (m, rows, lanes) result: m is never the lane axis
+        assert compiled.memory_analysis().output_size_in_bytes \
+            < 1.01 * CODE.m * rows * max(b.lanes, 128) * 4
 
 
 def test_decode_widest_3d_leaf(one_chip, no_persistent_cache):
-    """coded_decode on the per-leaf path's widest (n, V, R) stack."""
-    _, G, _ = max((x for x in _coded_leaves() if len(x[1]) == 4),
-                  key=lambda x: np.prod(x[1]))
+    """coded_decode on the per-leaf path's widest (n, A, B) stack."""
+    _, G, _ = max((x for x in _coded_leaves(next(iter(one_chip.device_set)))
+                   if len(x[1]) == 4), key=lambda x: np.prod(x[1]))
     _compile_kernel("coded_decode", lambda f, w: coded_decode(f, w),
                     _sds((CODE.n,) + G[2:], jnp.float32, one_chip),
                     _sds((CODE.n, CODE.m), jnp.float32, one_chip))
 
 
 def test_decode_apply_full_bucket(one_chip, no_persistent_cache):
-    """coded_decode_apply (the fused decode + SGD-momentum apply) on one
-    whole bucket, its sum-of-squares partials written as vector blocks."""
-    L = _bucket().size
-    v = _sds((CODE.m, L), jnp.float32, one_chip)
+    """coded_decode_apply (the fused decode + SGD-momentum apply) on the
+    widest bucket, its sum-of-squares partials written as vector blocks."""
+    b = _buckets(next(iter(one_chip.device_set)))[0]
+    v = _sds((CODE.m,) + b.shape, jnp.float32, one_chip)
     _compile_kernel(
         "coded_decode_apply",
         lambda f, w, p, mu: coded_decode_apply(f, w, p, mu, lr=0.1,
                                                momentum=0.9, scale=0.25),
-        _sds((CODE.n, L), jnp.float32, one_chip),
+        _sds((CODE.n,) + b.shape, jnp.float32, one_chip),
         _sds((CODE.n, CODE.m), jnp.float32, one_chip), v, v)
 
 
-def test_one_chip_coded_step_fits(topo, no_persistent_cache):
+@pytest.fixture(scope="module")
+def one_chip_step(topo, no_persistent_cache):
     """The chip smoke's step — code (1, 1, 0, 1), AdamW, 4 x 2048 tokens,
-    compiled kernels — compiles for one chip, holds the kernels, and its
-    arguments plus scratch fit the chip's HBM."""
+    compiled kernels — compiled for one described chip, with its build."""
     code = make_code(1, 1, 0, 1)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
@@ -234,12 +248,48 @@ def test_one_chip_coded_step_fits(topo, no_persistent_cache):
              for s in ((code.n, code.m), (code.n,), (code.n, code.d))]
     with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    return arts, pshapes, compiled
+
+
+def test_one_chip_coded_step_fits(one_chip_step):
+    """The chip smoke's step compiles for one chip, holds the kernels —
+    the encode folding into the wire buckets — and its arguments plus
+    scratch fit the chip's HBM."""
+    _, _, compiled = one_chip_step
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') >= 2
-    assert _holds_kernel(hlo, "coded_encode")
+    assert _holds_kernel(hlo, "coded_encode_acc")
     assert _holds_kernel(hlo, "coded_decode")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+
+def _layout_passes(hlo: str, min_elems: int) -> list[str]:
+    """Every transpose, pad, copy or (relayout) reshape in compiled HLO —
+    fused or not — whose f32 result holds at least ``min_elems`` elements,
+    outside the model's forward and backward (``coded.grad``)."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = f32\[([\d,]*)\]\S* "
+                     r"(transpose|pad|copy|reshape)\(", line)
+        if not m or "coded.grad" in line:
+            continue
+        if math.prod(int(x) for x in m.group(2).split(",")) >= min_elems:
+            out.append(f"{m.group(3)} %{m.group(1)} f32[{m.group(2)}]")
+    return out
+
+
+def test_coded_gradient_keeps_its_layout(one_chip_step):
+    """From the end of backward to AdamW the coded gradient is only read
+    and written by the encode kernel, the exchange and the decode kernel:
+    the compiled step holds no transpose, pad, copy or relayout reshape of
+    an f32 buffer as large as the smallest coded MLP leaf, and every coded
+    element rides the copy-free path."""
+    arts, pshapes, compiled = one_chip_step
+    smallest_mlp = min(math.prod(x.shape) for x in
+                       jax.tree.leaves(pshapes["layers"]["mlp"]))
+    assert _layout_passes(compiled.as_text(), smallest_mlp) == []
+    assert arts.copy_free_share >= 0.99
 
 
 def test_model_attention_on_splash_kernels(one_chip, no_persistent_cache,
@@ -355,7 +405,7 @@ def test_deepseek_cell_step_fits(topo, no_persistent_cache, monkeypatch):
     with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
     hlo = compiled.as_text()
-    assert _holds_kernel(hlo, "coded_encode")
+    assert _holds_kernel(hlo, "coded_encode_acc")
     assert _holds_named_call(hlo, "splash_mqa_fwd_residuals")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
