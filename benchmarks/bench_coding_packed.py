@@ -4,14 +4,14 @@ The packed wire (`repro.coding.packing`) exists to collapse the per-step
 collective count from O(#coded leaves) to <= 2 per bucket; this bench proves
 and gates exactly that, plus the explicit padding the flat buffers add:
 
-  - compiles the real coded train step (packed and per-leaf) for a
+  - compiles the real coded train step for a
     multi-leaf LM on a (4 data x 1 model) host mesh and counts
     all-gather/all-to-all ops in the optimized HLO (`repro.launch.hlo_cost`
     — deterministic, hardware-independent, so it IS gated in CI);
   - reports the PackPlan's padded wire volume next to the schedule's
     `recv_elems_per_worker` prediction on the unpadded payload (the ratio
     is the whole padding overhead — gated close to 1);
-  - (full mode) measures packed vs per-leaf step wall-clock, ungated.
+  - (full mode) measures the packed step's wall-clock, ungated.
 
   PYTHONPATH=src python -m benchmarks.run coding_packed --quick
 """
@@ -50,10 +50,10 @@ CODE = make_code(N_WORKERS, 3, 1, 2)
 ARCH = "qwen3-1.7b"
 
 
-def _build(cfg, schedule: str, packed: bool):
+def _build(cfg, schedule: str):
     mesh = make_local_mesh(N_WORKERS, 1)
     opt = get_optimizer("sgd", 1e-2)
-    spec = coding.SchemeSpec(schedule=schedule, packed=packed)
+    spec = coding.SchemeSpec(schedule=schedule)
     arts = make_coded_train_step(cfg, CODE, mesh, opt, spec=spec)
     rng = np.random.default_rng(0)
     placed = jax.tree.map(jnp.asarray,
@@ -92,10 +92,8 @@ def bench_results(quick: bool = False) -> list[BenchResult]:
     pack_plan = None
 
     for schedule in schedules:
-        arts_p, opt, placed = _build(cfg, schedule, True)
-        arts_l, _, _ = _build(cfg, schedule, False)
+        arts_p, opt, placed = _build(cfg, schedule)
         cp = _collective_counts(arts_p, opt, placed, cfg)
-        cl = _collective_counts(arts_l, opt, placed, cfg)
         pack_plan = arts_p.pack_plan
         n_buckets = len(pack_plan.buckets)
         n_coded = pack_plan.num_coded_leaves
@@ -107,7 +105,6 @@ def bench_results(quick: bool = False) -> list[BenchResult]:
         if launches(cp) > bound:
             within_bound = 0.0
         metrics[f"collectives_per_step_packed_{schedule}"] = float(launches(cp))
-        metrics[f"collectives_per_step_perleaf_{schedule}"] = float(launches(cl))
 
         sched = get_schedule(schedule)
         pred = sched.recv_elems_per_worker(
@@ -117,19 +114,15 @@ def bench_results(quick: bool = False) -> list[BenchResult]:
         lines.append(
             f"coding_packed,schedule={schedule},buckets={n_buckets},"
             f"coded_leaves={n_coded},collectives_packed={launches(cp)},"
-            f"collectives_perleaf={launches(cl)},"
             f"recv_elems_padded={padded:.0f},recv_elems_pred={pred:.0f}")
 
         if not quick:
             policy = TimerPolicy(warmup=1, reps=8)
             t_p = _measured_step_s(arts_p, opt, placed, cfg, policy)
-            t_l = _measured_step_s(arts_l, opt, placed, cfg, policy)
             metrics[f"measured_step_s_packed_{schedule}"] = round(t_p, 5)
-            metrics[f"measured_step_s_perleaf_{schedule}"] = round(t_l, 5)
             lines.append(
                 f"coding_packed_timing,schedule={schedule},"
-                f"packed_s={t_p:.5f},perleaf_s={t_l:.5f},"
-                f"speedup={t_l / t_p:.3f}x")
+                f"packed_s={t_p:.5f}")
 
     metrics["packed_collectives_within_bound"] = within_bound
     metrics["padded_overhead"] = round(

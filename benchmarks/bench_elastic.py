@@ -149,7 +149,7 @@ def _planner_membership_check(npts: int) -> tuple[float, float]:
     fit = synthetic_fit(PARAMS, steps=200, seed=7)
     n = N_WORKERS
     recs = [StepRecord(step=i, d=DESIGN[0], s=DESIGN[1], m=DESIGN[2], k=n,
-                       loads=(DESIGN[0],) * n, schedule="gather", packed=True,
+                       loads=(DESIGN[0],) * n, schedule="gather",
                        compute_s=np.full(n, 1.0), comm_s=np.full(n, 1.0),
                        measured_step_s=0.02, compile_s=30.0 if i == 0 else 0.0)
             for i in range(8)]

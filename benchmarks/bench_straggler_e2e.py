@@ -20,7 +20,7 @@ x {ref, pallas}), each schedule's predicted wire volume
 cross-check of E[T_tot].
 
 The pipelined rows run the m>1 scheme again as the async double-buffered
-step (`pipelined=True`, fused decode+apply): its fill / steady / drain
+step (`pipelined=True`): its fill / steady / drain
 phases are measured separately and composed with the modeled phase waits —
 compute phase = E[compute wait] + measured fill, communication phase =
 E[comm wait] + measured drain, pipelined total = overlapped E[T_tot]
@@ -107,7 +107,7 @@ def best_triple_m_gt1(params: RuntimeParams, npts: int) -> tuple[int, int, int]:
 
 
 def _measure_scheme(cfg, code, schedule, backend, patterns, batch, params_init,
-                    packed: bool = True, partial: bool = False,
+                    partial: bool = False,
                     n_workers: int = N_WORKERS,
                     loss_out: list | None = None):
     """Mean measured wall-clock (s) of the jitted step across the patterns.
@@ -125,7 +125,7 @@ def _measure_scheme(cfg, code, schedule, backend, patterns, batch, params_init,
     mesh = make_local_mesh(n_workers, 1)
     opt = get_optimizer("sgd", 1e-2)
     spec = coding.SchemeSpec(schedule=schedule, backend=backend,
-                             packed=packed, partial=partial)
+                             partial=partial)
     arts = make_coded_train_step(cfg, code, mesh, opt, spec=spec)
     placed = jax.tree.map(jnp.asarray, CodedBatcher(code).place(batch))
     fn = arts.compiled(placed, donate=True)
@@ -171,8 +171,8 @@ def _measure_pipelined(cfg, code, schedule, backend, patterns, batch,
     """
     mesh = make_local_mesh(N_WORKERS, 1)
     opt = get_optimizer("sgd", 1e-2)
-    spec = coding.SchemeSpec(schedule=schedule, backend=backend, packed=True,
-                             pipelined=True, fuse_apply=True)
+    spec = coding.SchemeSpec(schedule=schedule, backend=backend,
+                             pipelined=True)
     arts = make_coded_train_step(cfg, code, mesh, opt, spec=spec)
     placed = jax.tree.map(jnp.asarray, CodedBatcher(code).place(batch))
     cp = arts.compiled_pipeline(placed, donate=True)
@@ -337,17 +337,6 @@ def bench_results(quick: bool = False) -> list[BenchResult]:
             lines.append(f"straggler_e2e_grid,schedule={schedule},"
                          f"backend={backend},measured_step_s={measured:.5f},"
                          f"predicted_recv_elems_per_worker={pred_elems:.0f}")
-    # per-leaf escape hatch next to the packed default (same code/schedule):
-    # isolates the per-collective launch overhead the packing removes
-    measured_pl = _measure_scheme(cfg, code, "gather", "ref", patterns,
-                                  batch, params_init, packed=False)
-    metrics["grid_measured_s_gather_ref_perleaf"] = round(measured_pl, 5)
-    grid_rows.append({"schedule": "gather", "backend": "ref",
-                      "packed": False, "measured_s": measured_pl,
-                      "predicted_recv_elems": get_schedule(
-                          "gather").recv_elems_per_worker(l, N_WORKERS, m)})
-    lines.append(f"straggler_e2e_grid,schedule=gather,backend=ref,"
-                 f"packed=False,measured_step_s={measured_pl:.5f}")
     # psum row: same (d,s,m) code — the rho-weighted all-reduce path with the
     # same d-fold subset compute, so the grid isolates the collective cost
     pred_psum = get_schedule("psum").recv_elems_per_worker(l, N_WORKERS, m)

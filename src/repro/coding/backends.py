@@ -60,21 +60,8 @@ class CodecBackend:
 
         The packed step's encode fold — one call per (subset, leaf) writes
         straight into the leaf's slot of the wire bucket instead of
-        materialising the per-leaf encoding for a later pack copy.  Must be
+        materialising the leaf's encoding for a later pack copy.  Must be
         bit-identical to the two-step spelling.
-        """
-        raise NotImplementedError
-
-    def decode_apply(self, F: jax.Array, W: jax.Array, P: jax.Array,
-                     MU: jax.Array, *, lr: float, momentum: float,
-                     scale: float):
-        """Fused decode + SGD-momentum apply over one packed bucket.
-
-        F (n, rows, B) x W (n, m) -> g = scale * decode; then
-        ``mu' = momentum * MU + g``, ``p' = P - lr * mu'`` on the
-        (m, rows, B) f32 bucket-layout views.  Returns
-        ``(p', mu', sum(g*g))`` — the
-        gradient-norm partial rides along so the step never rebuilds g.
         """
         raise NotImplementedError
 
@@ -102,13 +89,6 @@ class RefBackend(CodecBackend):
         cur = jax.lax.slice_in_dim(acc, row, row + enc.shape[0])
         return jax.lax.dynamic_update_slice_in_dim(acc, cur + enc, row, 0)
 
-    def decode_apply(self, F, W, P, MU, *, lr, momentum, scale):
-        """Reference decode + elementwise SGD-momentum apply (see
-        interface)."""
-        g = self.decode(F, W, out_dtype=jnp.float32) * scale
-        mu = momentum * MU + g
-        return P - lr * mu, mu, jnp.sum(g * g)
-
 
 @dataclasses.dataclass(frozen=True)
 class PallasBackend(CodecBackend):
@@ -133,12 +113,6 @@ class PallasBackend(CodecBackend):
         through ``input_output_aliases``, its grid on the slot's rows)."""
         return _encode_mod.coded_encode_acc(acc, G, C, row=row,
                                             interpret=self.interpret)
-
-    def decode_apply(self, F, W, P, MU, *, lr, momentum, scale):
-        """Fuse via the ``coded_decode_apply`` Pallas kernel."""
-        return _decode_mod.coded_decode_apply(
-            F, W, P, MU, lr=lr, momentum=momentum, scale=scale,
-            interpret=self.interpret)
 
 
 def _on_tpu() -> bool:

@@ -1,13 +1,14 @@
 """Static packing of coded-leaf encodings into bucketed wire buffers.
 
-The per-leaf decode path issues one ``all_gather``/``all_to_all`` (plus one
-skinny contraction) *per coded parameter leaf*; for zoo configs with
-dozens-to-hundreds of leaves the per-collective latency term alpha dominates
-exactly the way the paper's shifted-exponential T_comm model (Sec. VI)
-predicts.  This module computes, once at step-build time, a ``PackPlan``
-that lays every coded leaf's ``(V, *rest)`` encoding into a few wire
-buffers, so each train step issues O(1) collectives per *bucket* instead of
-per leaf and runs one large, aligned decode contraction over each bucket.
+Decoding leaf by leaf would issue one ``all_gather``/``all_to_all`` (plus
+one skinny contraction) *per coded parameter leaf*; for zoo configs with
+dozens-to-hundreds of leaves the per-collective latency term alpha would
+dominate exactly the way the paper's shifted-exponential T_comm model
+(Sec. VI) predicts.  This module computes, once at step-build time, a
+``PackPlan`` that lays every coded leaf's ``(V, *rest)`` encoding into a
+few wire buffers, so each train step issues O(1) collectives per *bucket*
+instead of per leaf and runs one large, aligned decode contraction over
+each bucket.
 
 A bucket is a 2-D ``(rows, lanes)`` f32 stack of rows, and each leaf's slot
 is a range of whole rows: the leaf's own kernel plane
@@ -47,8 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import wire
-from .layout import (ROW_ALIGN, from_slot_plane, groups_to_leaf,
-                     leaf_to_groups, slot_plane, to_slot_plane)
+from .layout import (ROW_ALIGN, from_slot_plane, groups_to_leaf, slot_plane,
+                     to_slot_plane)
 from .plan import LeafPlan
 
 PyTree = Any
@@ -64,8 +65,8 @@ def _round_up(x: int, k: int) -> int:
 
 def enc_shape(shape: Sequence[int], plan: LeafPlan, m: int) -> tuple[int, ...]:
     """The ``(V, *rest)`` encoding shape of a coded leaf (the shape
-    ``encode_leaf`` produces: grouping dim first and split by m, the rest
-    in device order)."""
+    of its slot in a wire bucket: grouping dim first and split by m, the
+    rest in device order)."""
     assert plan.coded
     moved = tuple(shape[d] for d in plan.group_perm(len(shape)))
     return (moved[0] // m,) + moved[1:]
@@ -266,33 +267,25 @@ def make_pack_plan(tree: PyTree, plans: PyTree, *, m: int, n: int,
 
 
 # ------------------------------------------------------------ traced phases
-def _stack_rows(parts_of, bucket: WireBucket, lead: tuple[int, ...],
-                dtype) -> jax.Array:
-    """Concatenate each slot's ``(*lead, rows, lanes)`` plane along the
-    rows, with zero rows at the alignment gaps and the tail."""
-    parts: list[jax.Array] = []
-    pos = 0
-    for s in bucket.slots:
-        if s.row > pos:
-            parts.append(jnp.zeros((*lead, s.row - pos, bucket.lanes), dtype))
-        parts.append(parts_of(s).astype(dtype))
-        pos = s.row + s.rows
-    if bucket.rows > pos:
-        parts.append(jnp.zeros((*lead, bucket.rows - pos, bucket.lanes),
-                               dtype))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-2)
-
-
 def pack_bucket(flat_leaves: Sequence[jax.Array], bucket: WireBucket,
                 dtype) -> jax.Array:
     """Lay the bucket's slot encodings (``(V, *rest)``, already in the wire
     dtype after ``Codec.to_wire``) into its ``(rows, lanes)`` buffer with
-    zero padding rows.  The train step encodes straight into the slots
-    instead (``Codec.encode_into``); this is the spelling for encodings
-    made elsewhere."""
-    return _stack_rows(
-        lambda s: to_slot_plane(flat_leaves[s.leaf_index], s.enc_shape, 0),
-        bucket, (), jnp.dtype(dtype))
+    zero rows at the alignment gaps and the tail.  The train step encodes
+    straight into the slots instead (``Codec.encode_into``); this is the
+    spelling for encodings made elsewhere."""
+    dtype = jnp.dtype(dtype)
+    parts: list[jax.Array] = []
+    pos = 0
+    for s in bucket.slots:
+        if s.row > pos:
+            parts.append(jnp.zeros((s.row - pos, bucket.lanes), dtype))
+        parts.append(to_slot_plane(flat_leaves[s.leaf_index], s.enc_shape,
+                                   0).astype(dtype))
+        pos = s.row + s.rows
+    if bucket.rows > pos:
+        parts.append(jnp.zeros((bucket.rows - pos, bucket.lanes), dtype))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-2)
 
 
 def psum_fallback(flat_leaves: Sequence[jax.Array], flat_plans,
@@ -315,34 +308,6 @@ def psum_fallback(flat_leaves: Sequence[jax.Array], flat_plans,
             flat_leaves[i].shape)
         off += sz
     return out
-
-
-def pack_param_groups(flat_leaves: Sequence[jax.Array],
-                      bucket: WireBucket, m: int) -> jax.Array:
-    """Lay the bucket's *parameter* (or optimizer-state) leaves out in the
-    decoded-buffer layout: an ``(m, rows, lanes)`` f32 view whose rows
-    ``[slot.row, slot.row + slot.rows)`` hold leaf ``slot.leaf_index``
-    exactly where ``unpack_bucket`` reads that leaf's decoded gradient.
-
-    This is the fused decode-plus-apply path's input: with params and
-    momentum in this layout, the per-bucket kernel can run the optimizer
-    update right after the decode contraction without unpacking.  Padding
-    rows are zeros (their decoded gradient is zero too, so the update
-    fixes them at zero)."""
-    return _stack_rows(
-        lambda s: to_slot_plane(leaf_to_groups(flat_leaves[s.leaf_index],
-                                               s.plan, m),
-                                s.enc_shape, 1),
-        bucket, (m,), jnp.float32)
-
-
-def unpack_param_groups(buf: jax.Array, bucket: WireBucket,
-                        flat_like: Sequence[Any]) -> dict[int, jax.Array]:
-    """Invert ``pack_param_groups``: slice the updated ``(m, rows, lanes)``
-    buffer back into leaf layouts, cast to each leaf's original dtype
-    (``flat_like`` supplies the dtypes).  Returns {leaf_index: leaf}."""
-    out = unpack_bucket(buf, bucket)
-    return {i: v.astype(flat_like[i].dtype) for i, v in out.items()}
 
 
 def unpack_bucket(decoded: jax.Array, bucket: WireBucket) -> dict[int, jax.Array]:

@@ -1,21 +1,16 @@
 """`SchemeSpec`: one frozen value object naming a complete coding scheme.
 
-The scheme levers — collective schedule, compute backend, packed wire,
-partial recovery, async pipelining, fused apply, wire dtype — historically
-travelled as seven loose kwargs duplicated across ``make_coded_train_step``,
-the ``Trainer``, the planner and the benches.  With the serving engine a
-*second* consumer of the same codec arrived, so the levers now live in one
-hashable dataclass that every consumer accepts:
+The scheme levers — collective schedule, compute backend, partial recovery,
+async pipelining, wire dtype — live in one hashable dataclass that every
+consumer of the codec accepts, the train step and the serving engine alike:
 
 >>> spec = SchemeSpec(schedule="a2a", encode_dtype="bfloat16")
->>> spec.replace(packed=False).packed
-False
+>>> spec.replace(partial=True).partial
+True
 
 ``make_coded_train_step(cfg, code, mesh, opt, spec=spec)``,
 ``Trainer(..., spec=spec)`` and ``CodedServer(..., spec=spec)`` all consume
-the same instance; the legacy kwargs keep working through
-:func:`resolve_scheme_spec` (a ``DeprecationWarning`` shim pinned
-bitwise-equivalent by ``tests/test_scheme_spec.py``).
+the same instance.
 
 What stays *out* of the spec: anything workload-specific (``grad_scale``)
 or cluster-specific (the code object, the mesh) — a spec is the reusable
@@ -24,16 +19,11 @@ or cluster-specific (the code object, the mesh) — a spec is the reusable
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any
 
 from .backends import CodecBackend
 from .codec import Codec, make_codec
 from .schedules import get_schedule
-
-# the seven levers the spec consolidates, in legacy-kwarg order
-SPEC_FIELDS = ("schedule", "backend", "packed", "partial", "pipelined",
-               "fuse_apply", "encode_dtype")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,26 +31,22 @@ class SchemeSpec:
     """Frozen bundle of every scheme lever shared by train and serve.
 
     schedule: collective choreography — "gather" | "a2a" | "psum" (the
-    uncoded baseline; see ``repro.coding.schedules``).
+    uncoded baseline; see ``repro.coding.schedules``).  The encoding
+    schedules carry every coded leaf on the bucketed wire buffers of
+    ``repro.coding.packing``.
 
     backend: codec compute backend — "auto" | "ref" | "pallas" |
     "interpret" or a :class:`~repro.coding.backends.CodecBackend` instance
     ("auto" resolves to the Pallas kernels on TPU, the einsum reference
     elsewhere).
 
-    packed: ride the bucketed flat wire buffers of ``repro.coding.packing``
-    (O(1) collectives per step); ``False`` is the per-leaf escape hatch.
-
     partial: build partial-recovery executables — straggler sets larger
     than the design ``s`` decode approximately with an ``err_factor``
     error certificate instead of raising.
 
     pipelined: the async stale-by-one train step (``repro.train.pipeline``);
-    requires ``packed=True`` and an encoding schedule.  Train-only: the
-    serving forward has no gradient pipeline to overlap.
-
-    fuse_apply: fuse decode with the SGD apply (pipelined-only; ``None``
-    resolves to the fully bit-exact unfused default).
+    requires an encoding schedule.  Train-only: the serving forward has no
+    gradient pipeline to overlap.
 
     encode_dtype: wire dtype of the transmitted encodings ("float32" |
     "bfloat16" | "float16").
@@ -68,39 +54,24 @@ class SchemeSpec:
 
     schedule: str = "gather"
     backend: str | CodecBackend = "auto"
-    packed: bool = True
     partial: bool = False
     pipelined: bool = False
-    fuse_apply: bool | None = None
     encode_dtype: str = "float32"
 
     def __post_init__(self):
-        """Reject structurally impossible lever combinations eagerly.
-
-        The messages match the historical ``make_coded_train_step`` raises
-        (tests pin them); checks that need more context — the optimizer
-        kind for ``fuse_apply``, backend resolution — stay with the
-        consumers.
-        """
+        """Reject structurally impossible lever combinations eagerly;
+        backend resolution stays with the consumers."""
         if self.pipelined:
-            if not self.packed:
-                raise ValueError(
-                    "pipelined=True requires packed=True: the wire state IS "
-                    "the PackPlan's bucketed flat buffers")
             if self.partial:
                 raise ValueError(
                     "pipelined partial-recovery is unsupported: the "
                     "err_factor certificate is computed from the same "
                     "step's subset gradients and cannot ride the "
                     "stale-by-one wire")
-            if (isinstance(self.schedule, str)
-                    and not get_schedule(self.schedule).uses_encoding):
+            if not self.uses_encoding:
                 raise ValueError(
                     "pipelined=True needs an encoding schedule (gather/"
                     "a2a); the psum baseline has no wire to double-buffer")
-        if self.fuse_apply and not self.pipelined:
-            raise ValueError("fuse_apply is a pipelined-step lever; "
-                             "pass pipelined=True")
 
     def replace(self, **changes: Any) -> "SchemeSpec":
         """A copy with the given levers changed (``dataclasses.replace``)."""
@@ -115,30 +86,3 @@ class SchemeSpec:
     def uses_encoding(self) -> bool:
         """Whether the schedule transmits coded encodings (psum does not)."""
         return get_schedule(self.schedule).uses_encoding
-
-
-def resolve_scheme_spec(spec: SchemeSpec | None, legacy: dict[str, Any],
-                        caller: str, stacklevel: int = 3) -> SchemeSpec:
-    """Merge the ``spec=`` argument with deprecated per-lever kwargs.
-
-    ``legacy`` maps lever name -> value-or-None (None = not given, the
-    kwargs' sentinel default).  Passing any lever alongside ``spec=`` is an
-    error (no silent precedence); passing levers without a spec emits one
-    ``DeprecationWarning`` and builds the equivalent spec — the shim path
-    pinned bitwise-identical to the spec path by ``tests/test_scheme_spec``.
-    """
-    given = {k: v for k, v in legacy.items() if v is not None}
-    if spec is not None:
-        if given:
-            raise TypeError(
-                f"{caller}: pass either spec=SchemeSpec(...) or the "
-                f"deprecated scheme kwargs, not both (got spec= and "
-                f"{sorted(given)})")
-        return spec
-    if given:
-        warnings.warn(
-            f"{caller}: the scheme kwargs {sorted(given)} are deprecated; "
-            f"pass spec=repro.coding.SchemeSpec(...) instead",
-            DeprecationWarning, stacklevel=stacklevel)
-        return SchemeSpec(**given)
-    return SchemeSpec()

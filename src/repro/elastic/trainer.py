@@ -124,7 +124,7 @@ class ElasticTrainer(Trainer):
     # ------------------------------------------------------- Trainer hooks
     def _step_partial(self, stragglers) -> bool:
         """Rung 1: force partial decode when the budget cannot cover."""
-        if self.partial:
+        if self.spec.partial:
             return True
         if (self.elastic.partial_failover
                 and len(stragglers) > self.code.s):
@@ -183,8 +183,8 @@ class ElasticTrainer(Trainer):
             if self._degraded:
                 # full recovery: every departure healed — swap back to the
                 # exact home scheme (its artifacts are still cached)
-                self._swap_code(self._home_code, self.schedule, self.packed,
-                                self.pipelined)
+                self._swap_code(self._home_code, self.spec.schedule,
+                                self.spec.pipelined)
                 self.elastic_events.append(
                     {"step": step, "action": "recover-home",
                      "n": self.code.n})
@@ -201,7 +201,7 @@ class ElasticTrainer(Trainer):
             code = self._degraded_code(dep)
             if (code is not None
                     and self._code_key(code) != self._code_key(self.code)):
-                self._swap_code(code, self.schedule, self.packed, False)
+                self._swap_code(code, self.spec.schedule, False)
                 self.elastic_events.append(
                     {"step": step, "action": "replan-degraded",
                      "departed": dep, "loads": code.loads, "s": code.s})
@@ -273,14 +273,13 @@ class ElasticTrainer(Trainer):
             self._mesh_caches[new_n] = (self.mesh_factory(new_n), {}, {})
         mesh, arts_cache, jitted = self._mesh_caches[new_n]
         code = self._resized_code(new_n)
-        key = (self._code_key(code), self.schedule, self.packed,
-               self.partial, False)
+        key = (self._code_key(code), self.spec.schedule, self.spec.partial,
+               False)
         if key not in arts_cache:
             from repro.train.coded_step import make_coded_train_step
             arts_cache[key] = make_coded_train_step(
                 self.cfg, code, mesh, self.optimizer,
-                spec=self.spec.replace(schedule=self.schedule,
-                                       packed=self.packed, pipelined=False))
+                spec=self.spec.replace(pipelined=False))
         self.elastic_events.append(
             {"step": self._step_count, "action": "prewarm", "n": new_n})
         return True
@@ -320,11 +319,10 @@ class ElasticTrainer(Trainer):
         with jax.sharding.set_mesh(self.mesh):
             self.params = jax.tree.map(jnp.asarray, state["params"])
             self.opt_state = jax.tree.map(jnp.asarray, state["opt_state"])
-        schedule = plan.schedule if plan is not None else self.schedule
-        packed = plan.packed if plan is not None else self.packed
+        schedule = plan.schedule if plan is not None else self.spec.schedule
         pipelined = (getattr(plan, "pipelined", False) if plan is not None
-                     else self.pipelined)
-        self._swap_code(code, schedule, packed, pipelined)
+                     else self.spec.pipelined)
+        self._swap_code(code, schedule, pipelined)
         self._home_code = code if plan is None else self._resized_code(new_n)
         self.tracker.resize(new_n, step)
         self.tracker.reactivate_all(step)

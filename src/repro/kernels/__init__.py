@@ -3,9 +3,9 @@
 ref.py.  The train step reaches them through ``repro.coding.backends``
 (``pallas`` compiled on a TPU, ``interpret`` anywhere)."""
 from . import ref
-from .coded_decode import coded_decode, coded_decode_apply
+from .coded_decode import coded_decode
 from .coded_encode import coded_encode, coded_encode_acc
 from .flash_attn import flash_attention, flash_attention_gqa
 
 __all__ = ["ref", "coded_encode", "coded_encode_acc", "coded_decode",
-           "coded_decode_apply", "flash_attention", "flash_attention_gqa"]
+           "flash_attention", "flash_attention_gqa"]

@@ -60,75 +60,3 @@ def coded_decode(F: jax.Array, W: jax.Array, *, interpret: bool = False,
     )(W.reshape(-1).astype(jnp.float32), X)
     return from_plane(out, rest)
 
-
-# ---------------------------------------------------------------- fused path
-def _decode_apply_kernel(n, m, rows, lr, momentum, scale, rolled,
-                         w_ref, f_ref, p_ref, mu_ref,
-                         pn_ref, mun_ref, ss_ref):
-    """w: (n*m,) SMEM, f: (n, TA, TB), p/mu: (m, TA, TB) -> p', mu', and
-    this block's (1, TB) partial of sum(g^2) (rows past ``rows`` — the
-    ragged last block's padding — are masked out of it; lanes past the
-    plane's are dropped when the partials are written)."""
-    ta = f_ref.shape[1]
-    row = (pl.program_id(0) * ta
-           + jax.lax.broadcasted_iota(jnp.int32, f_ref.shape[1:], 0))
-    ss = jnp.zeros((1, f_ref.shape[2]), jnp.float32)
-    for u in range(m):
-        g = weighted_sum(w_ref, f_ref, n, col=u, stride=m,
-                         rolled=rolled) * scale
-        mu = momentum * mu_ref[u] + g                   # SGD-momentum state
-        pn_ref[u] = p_ref[u] - lr * mu
-        mun_ref[u] = mu
-        g = jnp.where(row < rows, g, 0.0)
-        ss = ss + jnp.sum(g * g, axis=0, keepdims=True)
-    ss_ref[0] = ss
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("lr", "momentum", "scale", "interpret"))
-def coded_decode_apply(F: jax.Array, W: jax.Array, P: jax.Array,
-                       MU: jax.Array, *, lr: float, momentum: float,
-                       scale: float, interpret: bool = False):
-    """Fused decode + SGD-momentum apply for one packed wire bucket.
-
-    F: (n, L) or (n, rows, B) gathered wire stack; W: (n, m) decode
-    weights; P / MU: (m, L) or (m, rows, B) f32 bucket-layout views of the
-    params and momentum state (``repro.coding.packing.pack_param_groups``).
-    One pass computes
-
-        g   = scale * (W^T F)        (paper eq. 19-21 + grad scaling)
-        mu' = momentum * mu + g
-        p'  = p - lr * mu'
-
-    and returns ``(p', mu', sum(g*g))`` — the decode, the unpack-free
-    optimizer apply and the gradient-norm partial in a single kernel per
-    bucket, instead of decode -> unpack -> tree-wise update.  The decode
-    shares :func:`coded_decode`'s f32 sequence; the update matches the
-    unfused path's up to where the compiler fuses a multiply-add into one
-    FMA.  P/MU are aliased to the outputs (donated by the pipelined step).
-    """
-    n, m = W.shape
-    (X, rest), (Pl, _), (Ml, _) = (to_plane(F, 1), to_plane(P, 1),
-                                   to_plane(MU, 1))
-    _, A, B = X.shape
-    ta, tb = block_tiles(A, B, n * X.dtype.itemsize + 4 * m * 4)
-    grid = (pl.cdiv(A, ta), pl.cdiv(B, tb))
-    kern = functools.partial(_decode_apply_kernel, n, m, A, float(lr),
-                             float(momentum), float(scale), interpret)
-    plane = pl.BlockSpec((m, ta, tb), lambda i, j: (0, i, j))
-    pn, mun, ss = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[smem_spec(),
-                  pl.BlockSpec((n, ta, tb), lambda i, j: (0, i, j)),
-                  plane, plane],
-        out_specs=[plane, plane,
-                   pl.BlockSpec((1, 1, tb), lambda i, j: (i, 0, j))],
-        out_shape=[jax.ShapeDtypeStruct((m, A, B), jnp.float32),
-                   jax.ShapeDtypeStruct((m, A, B), jnp.float32),
-                   jax.ShapeDtypeStruct((grid[0], 1, B), jnp.float32)],
-        input_output_aliases={2: 0, 3: 1},
-        interpret=interpret,
-        name="coded_decode_apply",
-    )(W.reshape(-1).astype(jnp.float32), X, Pl, Ml)
-    return from_plane(pn, rest), from_plane(mun, rest), jnp.sum(ss)

@@ -49,21 +49,11 @@ def run_one(arch: str, shape: str, mesh_name: str, schedule: str,
     if "moe_einsum" in opts:
         from repro.models import moe as _moe
         _moe.DISPATCH = "einsum"
-    if "enc_constraint" in opts:
-        from repro.train import coded_step as _cs
-        _cs.ENC_CONSTRAINT = True
-        # the lever pins per-leaf encoding shardings through the collective;
-        # the packed wire flattens leaves into flat buckets before the
-        # collective, so the constraint only measures anything on the
-        # per-leaf wire — imply it rather than record a misleading A/B
-        opts.add("per_leaf_wire")
     if SHAPES[shape].kind == "train":
         kw["schedule"] = schedule
         kw["backend"] = backend
         if "bf16_wire" in opts:
             kw["encode_dtype"] = "bfloat16"
-        if "per_leaf_wire" in opts:     # packed wire off: one collective/leaf
-            kw["packed"] = False
         if "partial" in opts:           # partial-recovery step (err bound)
             kw["partial"] = True
         if "hetero" in opts:
@@ -104,7 +94,6 @@ def run_one(arch: str, shape: str, mesh_name: str, schedule: str,
                   f"l2={fit.params.lambda2:.3f}); lowering "
                   f"{top.describe()}", flush=True)
             kw["code"] = make_code(n, top.d, top.s, top.m)
-            kw["packed"] = top.packed
             rec["autotune_plans"] = [p.describe() for p in ranked[:5]]
             rec["autotune_fit"] = {"t1": fit.params.t1,
                                    "lambda1": fit.params.lambda1,
@@ -167,9 +156,9 @@ def main() -> None:
                     help="d,s,m triple for the gradient code (default 3,1,2)")
     ap.add_argument("--opt", default="",
                     help="comma list of levers: attn_remat, bf16_wire, "
-                         "moe_einsum, enc_constraint, per_leaf_wire, "
-                         "hetero (skewed-speed HeteroCode), partial "
-                         "(partial-recovery step with error certificate), "
+                         "moe_einsum, hetero (skewed-speed HeteroCode), "
+                         "partial (partial-recovery step with error "
+                         "certificate), "
                          "autotune (fit the Sec-VI model from synthetic "
                          "telemetry and lower the planner's top (d,s,m))")
     ap.add_argument("--tag", default="", help="tag for the result filename")

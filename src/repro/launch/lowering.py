@@ -75,8 +75,7 @@ def build_train_lowering(arch: str, shape_name: str, mesh, *,
                          schedule: str = "gather", code=None,
                          optimizer: str = "adamw",
                          encode_dtype: str = "float32",
-                         backend: str = "auto", packed: bool = True,
-                         partial: bool = False):
+                         backend: str = "auto", partial: bool = False):
     """Returns (jitted_fn, args) ready for .lower(*args)."""
     cfg = dryrun_config(arch)
     shape = SHAPES[shape_name]
@@ -84,7 +83,7 @@ def build_train_lowering(arch: str, shape_name: str, mesh, *,
     code = code or default_code(n)
     opt = get_optimizer(optimizer, 1e-3)
     spec = coding.SchemeSpec(schedule=schedule, encode_dtype=encode_dtype,
-                             backend=backend, packed=packed, partial=partial)
+                             backend=backend, partial=partial)
     arts = make_coded_train_step(cfg, code, mesh, opt, spec=spec)
 
     pshapes = jax.eval_shape(lambda: model_api.init(jax.random.PRNGKey(0), cfg))

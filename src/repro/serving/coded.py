@@ -124,8 +124,8 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
     ``spec`` is the same :class:`repro.coding.SchemeSpec` instance
     :func:`repro.train.coded_step.make_coded_train_step` accepts — one
     value object drives the scheme at train and serve time.  Serving
-    rejects the training-only levers (``pipelined`` / ``fuse_apply``): a
-    forward pass has no optimizer state to overlap or fuse into.
+    rejects the training-only lever ``pipelined``: a forward pass has no
+    optimizer update to overlap.
 
     ``batch_per_subset`` is ``b``, the requests per data subset; the
     engine batch is ``B = k * b`` with ``k = code.num_subsets`` and
@@ -134,11 +134,11 @@ def make_coded_forward(cfg, code: GradCode, mesh, *,
     prompt length (requests are padded to it; ignored by ``linear``).
     """
     spec = spec if spec is not None else coding.SchemeSpec()
-    if spec.pipelined or spec.fuse_apply:
+    if spec.pipelined:
         raise ValueError(
-            "pipelined/fuse_apply are train-step levers (they overlap or "
-            "fuse the optimizer update); the serving forward has neither — "
-            "build the CodedServer from a spec without them")
+            "pipelined is a train-step lever (it overlaps the optimizer "
+            "update); the serving forward has none — build the CodedServer "
+            "from a spec without it")
     data_axes = _data_axes(mesh)
     n = int(np.prod([mesh.shape[a] for a in data_axes]))
     if code.n != n:

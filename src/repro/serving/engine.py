@@ -245,7 +245,7 @@ class CodedServer:
     def _scheme_key(self) -> tuple:
         code = self.code
         return (code.n, code.d, code.s, code.m, self.spec.schedule,
-                self.spec.packed, self.spec.partial, str(self.spec.backend),
+                self.spec.partial, str(self.spec.backend),
                 self.spec.encode_dtype)
 
     @property
@@ -320,8 +320,8 @@ class CodedServer:
         self._served += 1
         if self._tuner is not None and times is not None:
             self._tuner.record(record_from_times(
-                self._served, code, self.spec.schedule, self.spec.packed,
-                times, n_drop=len(stragglers), measured_step_s=wall))
+                self._served, code, self.spec.schedule, times,
+                n_drop=len(stragglers), measured_step_s=wall))
             plan = self._tuner.maybe_replan(self._served)
             if plan is not None:
                 self._apply_plan(plan)

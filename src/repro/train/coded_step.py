@@ -12,10 +12,9 @@ subsets.  The step (manual over data axes, GSPMD-auto over 'model'):
      worker's coefficient rows C[i, j, :] (paper eq. 17/18 — never
      materializes the (d, l) partial-gradient matrix), scaled by the
      responder mask (stragglers transmit nothing; proves the decode is
-     independent of straggler payloads).  By default the fold writes
-     straight into the leaf's rows of the static ``PackPlan``'s
-     ``(rows, lanes)`` wire buckets (``Codec.encode_into``; ``packed=False``
-     keeps the per-leaf escape hatch),
+     independent of straggler payloads).  The fold writes straight into
+     the leaf's rows of the static ``PackPlan``'s ``(rows, lanes)`` wire
+     buckets (``Codec.encode_into``),
   3. decodes the summed gradient with the host-computed float64 weights W
      (zero rows at stragglers) via the gather or a2a schedule — one
      collective choreography + one fused contraction per bucket — and reads
@@ -66,11 +65,6 @@ from .pipeline import CompiledPipeline, PipelineFns
 
 PyTree = Any
 
-# §Perf lever: pin the coded encodings to their model sharding before the
-# manual collective (see _enc_spec below).  Default False = recorded baseline;
-# flipped by the dry-run's --opt enc_constraint.
-ENC_CONSTRAINT = False
-
 
 @dataclasses.dataclass(frozen=True)
 class StepArtifacts:
@@ -78,7 +72,7 @@ class StepArtifacts:
 
     Carries the jit-able step builder (``step(batch_shapes) -> (fn, in_specs,
     out_specs)``), the per-leaf coding plans, the bound ``Codec``, the static
-    ``PackPlan`` of the packed wire (None on the per-leaf path), the
+    ``PackPlan`` of the packed wire (None on the psum schedule), the
     per-worker subset-load vector (uniform codes: ``(d,) * n``; hetero codes:
     the plan's ragged loads), and whether the step was built in
     partial-recovery mode (the executable then takes a 7th ``err_factor``
@@ -94,8 +88,7 @@ class StepArtifacts:
     loads: tuple[int, ...] = ()
     partial: bool = False
     pipelined: bool = False
-    fuse_apply: bool = False
-    spec: "coding.SchemeSpec | None" = None  # the resolved scheme levers
+    spec: "coding.SchemeSpec | None" = None  # the scheme levers
     pipeline: Callable | None = None   # (batch_shapes) -> PipelineFns
     # memoized jitted executables, keyed by (batch signature, donate): the
     # bench's donated steady-state step and the autotuner's telemetry step
@@ -107,7 +100,7 @@ class StepArtifacts:
     def copy_free_share(self) -> float:
         """Share of the coded gradient's elements whose way from backward to
         the optimizer holds no relayout (``PackPlan.free_share``); 0 on the
-        per-leaf path."""
+        psum schedule, which has no wire."""
         return self.pack_plan.free_share if self.pack_plan else 0.0
 
     # ---- benchmark / driver hooks --------------------------------------
@@ -221,14 +214,7 @@ def pipelining_supported(mesh, schedule: str = "gather") -> bool:
 
 def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                           *, spec: coding.SchemeSpec | None = None,
-                          grad_scale: float | None = None,
-                          schedule: str | None = None,
-                          encode_dtype: str | None = None,
-                          backend: str | coding.CodecBackend | None = None,
-                          packed: bool | None = None,
-                          partial: bool | None = None,
-                          pipelined: bool | None = None,
-                          fuse_apply: bool | None = None) -> StepArtifacts:
+                          grad_scale: float | None = None) -> StepArtifacts:
     """Build the shard_map'd coded train step for one architecture.
 
     code: a uniform :class:`~repro.core.schemes.GradCode` or a heterogeneous
@@ -238,70 +224,42 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
 
     spec: a :class:`repro.coding.SchemeSpec` bundling every scheme lever —
     the same instance a ``CodedServer`` accepts, so train and serve run one
-    scheme from one value.  The per-lever kwargs below are the deprecated
-    spelling (``DeprecationWarning``; cannot be combined with ``spec=``)
-    and produce bitwise-identical artifacts to the equivalent spec.
+    scheme from one value (default ``SchemeSpec()``).  Its levers here:
+
+    - ``schedule`` / ``backend`` / ``encode_dtype`` bind the codec.  Coded
+      leaves ride the bucketed ``(rows, lanes)`` wire buffers of
+      ``repro.coding.packing`` — one collective choreography and one fused
+      decode contraction per bucket per step — and the psum-fallback
+      leaves a single flat all-reduce.  ``encode_dtype="bfloat16"`` halves
+      the collective bytes at ~3 decimal digits of gradient precision.
+    - ``partial``: build the step in partial-recovery mode — the
+      executable takes a 7th scalar input ``err_factor`` (from
+      ``make_step_inputs(..., partial=True)``, which then accepts straggler
+      sets *larger* than the design ``s`` instead of raising) and emits a
+      ``decode_err_bound`` metric: ``err_factor * sqrt(sum_j ||g_j||^2)``,
+      an upper bound on the L2 error of the least-squares decoded gradient
+      over the subsets that kept at least one live holder.
+    - ``pipelined``: additionally build the async three-phase step
+      (``StepArtifacts.pipeline`` / ``compiled_pipeline``): fill encodes
+      one batch into double-buffered wire-bucket state, steady decodes the
+      in-flight buffers (stale-by-one) while encoding the current batch at
+      pre-update params — the decode collective and the encode compute are
+      dataflow-independent, so XLA overlaps them — and drain retires the
+      last buffers.  The encode folds each subset gradient straight into
+      the wire buckets, as the synchronous step does.  Needs an encoding
+      schedule and ``partial=False`` (``SchemeSpec`` checks both); the
+      synchronous executable is still built and is byte-identical to the
+      non-pipelined build.  Parity contract: fill immediately followed by
+      drain == the synchronous step, bit for bit.
 
     grad_scale: decoded gradients are multiplied by this (default 1/k with
     k = ``code.num_subsets`` so the update equals uncoded *mean*-gradient
     descent when per-subset losses are means; the paper's linear workload
     uses sum losses and scale 1).  Workload-specific, hence not a spec
     lever.
-
-    encode_dtype: wire dtype of the transmitted encodings (the paper uses
-    f32; "bfloat16" halves the collective bytes at ~3 decimal digits of
-    gradient precision — a beyond-paper lever recorded in §Perf).
-
-    backend: codec compute backend — "auto" | "ref" | "pallas" | "interpret"
-    or a ``coding.CodecBackend`` instance.  (The pre-PR-1 ``use_kernels``
-    boolean is gone; ``SchemeSpec.backend`` is the one spelling.)
-
-    packed (default True): aggregate coded leaves through the bucketed flat
-    wire buffers of ``repro.coding.packing`` — O(1) collectives and one
-    fused decode contraction per bucket per step, and the psum-fallback
-    leaves ride a single flat all-reduce.  ``packed=False`` is the per-leaf
-    escape hatch (one collective + one skinny contraction per coded leaf),
-    bit-identical by construction.
-
-    partial (default False): build the step in partial-recovery mode — the
-    executable takes a 7th scalar input ``err_factor`` (from
-    ``make_step_inputs(..., partial=True)``, which then accepts straggler
-    sets *larger* than the design ``s`` instead of raising) and emits a
-    ``decode_err_bound`` metric: ``err_factor * sqrt(sum_j ||g_j||^2)``,
-    an upper bound on the L2 error of the least-squares decoded gradient
-    over the subsets that kept at least one live holder.
-
-    pipelined (default False): additionally build the async three-phase
-    step (``StepArtifacts.pipeline`` / ``compiled_pipeline``): fill
-    encodes one batch into double-buffered wire-bucket state, steady
-    decodes the in-flight buffers (stale-by-one) while encoding the
-    current batch at pre-update params — the decode collective and the
-    encode compute are dataflow-independent, so XLA overlaps them — and
-    drain retires the last buffers.  The encode folds each subset gradient
-    straight into the wire buckets (``Codec.encode_into``, the
-    accumulating encode kernel), as the synchronous step does.
-    Requires ``packed=True``, an encoding schedule (not psum) and
-    ``partial=False``; the synchronous executable is still built and is
-    byte-identical to the non-pipelined build.  Parity contract: fill
-    immediately followed by drain == the synchronous step, bit for bit.
-
-    fuse_apply: fuse the per-bucket decode contraction with the optimizer
-    update (``Codec.decode_apply_packed``: decode + SGD-momentum + param
-    write in one kernel on the gather schedule).  Only valid for
-    ``optimizer.kind == "sgd"``.  Params and momentum stay bit-identical
-    to the synchronous step (the kernel replicates its op sequence), but
-    the ``grad_norm`` metric sums squares in bucket order instead of leaf
-    order (~1e-6 relative drift), so the default (None) resolves to False
-    and the fully bit-exact path stays the default.  Pipelined-only.
     """
-    spec = coding.resolve_scheme_spec(
-        spec, dict(schedule=schedule, backend=backend, packed=packed,
-                   partial=partial, pipelined=pipelined,
-                   fuse_apply=fuse_apply, encode_dtype=encode_dtype),
-        caller="make_coded_train_step")
-    schedule, backend = spec.schedule, spec.backend
-    packed, partial, pipelined = spec.packed, spec.partial, spec.pipelined
-    encode_dtype, fuse_apply = spec.encode_dtype, spec.fuse_apply
+    spec = coding.SchemeSpec() if spec is None else spec
+    partial, pipelined = spec.partial, spec.pipelined
     data_axes = _data_axes(mesh)
     n = _axis_prod(mesh, data_axes)
     if code.n != n:
@@ -314,33 +272,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     if grad_scale is None:
         grad_scale = 1.0 if cfg.family == "linear" else 1.0 / k_subsets
 
-    codec = coding.make_codec(code, schedule=schedule, backend=backend,
-                              wire_dtype=encode_dtype)
+    codec = spec.make_codec(code)
     manual = sharding.manual_axes(mesh, data_axes, codec.backend)
-
-    if pipelined:
-        if not codec.schedule.uses_encoding:
-            raise ValueError(
-                "pipelined=True needs an encoding schedule (gather/a2a); "
-                "the psum baseline has no wire to double-buffer")
-        if not packed:
-            raise ValueError(
-                "pipelined=True requires packed=True: the wire state IS the "
-                "PackPlan's wire buckets")
-        if partial:
-            raise ValueError(
-                "pipelined partial-recovery is unsupported: the err_factor "
-                "certificate is computed from the same step's subset "
-                "gradients and cannot ride the stale-by-one wire")
-    fuse = False if fuse_apply is None else bool(fuse_apply)
-    if fuse and not pipelined:
-        raise ValueError("fuse_apply is a pipelined-step lever; "
-                         "pass pipelined=True")
-    if fuse and optimizer.kind != "sgd":
-        raise ValueError(
-            f"fuse_apply supports optimizer.kind='sgd' only (the fused "
-            f"kernel replicates the SGD-momentum rule); got "
-            f"{optimizer.kind or 'opaque'!r}")
 
     # --- shapes / specs ------------------------------------------------
     pshapes = jax.eval_shape(lambda: model_api.init(jax.random.PRNGKey(0), cfg))
@@ -355,7 +288,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
     # model sharding x lane width).  Computed once here; the step then
     # issues one collective choreography + one fused contraction per bucket.
     pplan = (codec.pack_plan(pshapes, plans, specs=pspecs, model_size=ms)
-             if packed and codec.schedule.uses_encoding else None)
+             if codec.schedule.uses_encoding else None)
     flat_plans = jax.tree.leaves(
         plans, is_leaf=lambda x: isinstance(x, coding.LeafPlan))
     wire_dtype = jnp.dtype(codec.wire_dtype)
@@ -390,22 +323,6 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                        for sm, i in zip(smalls, small_ix))
         return tuple(bufs), smalls
 
-    # §Perf lever (enc_constraint): the encoding of a model-sharded leaf can
-    # silently lose its 'model' sharding at the manual-collective boundary
-    # (GSPMD resharding — grok's 10 TB all-gather).  This computes the spec
-    # each encoding *should* keep: dims in ``LeafPlan.group_perm`` order,
-    # the grouping dim replicated, model entries preserved.
-    def _enc_spec(pl, spec):
-        if not pl.coded:
-            return None
-        entries = [e if e == "model" else None for e in tuple(spec)]
-        perm = pl.group_perm(len(entries))
-        return P(*([None] + [entries[d] for d in perm[1:]]))
-
-    enc_specs = jax.tree.map(
-        _enc_spec, plans, pspecs,
-        is_leaf=lambda x: isinstance(x, coding.LeafPlan))
-
     C = jnp.asarray(code.C, jnp.float32)           # (n, d, m) host constant
 
     def counts_zero():
@@ -435,18 +352,8 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
             # stragglers transmit nothing: the responder mask scales the
             # coefficient row, so a straggler's whole wire is zero
             cm = cj * mask_i
-
-            def fold(e, gleaf, pl):
-                if not pl.coded:
-                    return e + rj * gleaf.astype(jnp.float32)
-                return e + codec.encode_leaf(gleaf.astype(jnp.float32), cm,
-                                             pl)
-
             with jax.named_scope("coded.encode"):
-                if pplan is not None:
-                    state = packed_fold(state, g, cm, rj)
-                else:
-                    state = jax.tree.map(fold, state, g, plans)
+                state = packed_fold(state, g, cm, rj)
             if partial:
                 # rho-weighted subset gradient sumsq: psummed it becomes
                 # sum_j ||g_j||^2 over covered subsets — the certificate's
@@ -457,9 +364,7 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                         gss_acc + rj * gss), None
             return (state, cnt, loss_acc + rj * lval), None
 
-        init = (packed_zero() if pplan is not None
-                else jax.tree.map(codec.encoding_zero, params, plans),
-                counts_zero(), jnp.zeros((), jnp.float32))
+        init = (packed_zero(), counts_zero(), jnp.zeros((), jnp.float32))
         if partial:
             init = init + (jnp.zeros((), jnp.float32),)
         carry, _ = jax.lax.scan(per_subset, init, (lb, Ci, rho_i))
@@ -468,48 +373,24 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         else:
             state, cnt, loss_sum = carry
 
-        if pplan is not None:
-            # packed path: coded leaves ride the plan's buckets (one
-            # collective + one fused contraction each) and come back as row
-            # slices of the decoded buffers; the psum fallback leaves are
-            # summed through a single concatenated all-reduce instead of
-            # one per leaf.
-            bufs, smalls = state
-            flat_grads: list = [None] * len(flat_plans)
-            for i, sm in zip(small_ix, smalls):
-                flat_grads[i] = sm
-            with jax.named_scope("coded.encode"):
-                wires = [b.astype(wire_dtype) for b in bufs]
-            with jax.named_scope("coded.decode"):
-                decs = [codec.decode_packed(w, W, data_axes) for w in wires]
-                unpacked = codec.unpack(decs, pplan)
-            unpacked.update(coding.psum_fallback(flat_grads, flat_plans,
-                                                 data_axes))
-            grads = jax.tree.unflatten(
-                jax.tree.structure(params),
-                [unpacked[i] for i in range(len(flat_plans))])
-        else:
-            with jax.named_scope("coded.encode"):
-                enc = jax.tree.map(
-                    lambda e, pl: e.astype(wire_dtype) if pl.coded else e,
-                    state, plans)
-            if ENC_CONSTRAINT:
-                flat_e, td = jax.tree.flatten(enc)
-                flat_s = td.flatten_up_to(enc_specs)
-                flat_e = [jax.lax.with_sharding_constraint(e, s)
-                          if (pl.coded and s is not None
-                              and "model" in tuple(s))
-                          else e
-                          for e, s, pl in zip(flat_e, flat_s, flat_plans)]
-                enc = td.unflatten(flat_e)
-
-            def dec_one(e, pl):
-                if not pl.coded:
-                    return wire.psum(e, data_axes)
-                return codec.decode_leaf(e, W, pl, data_axes)
-
-            with jax.named_scope("coded.decode"):
-                grads = jax.tree.map(dec_one, enc, plans)
+        # coded leaves ride the plan's buckets (one collective + one fused
+        # contraction each) and come back as row slices of the decoded
+        # buffers; the psum fallback leaves are summed through a single
+        # concatenated all-reduce instead of one per leaf.
+        bufs, smalls = state
+        flat_grads: list = [None] * len(flat_plans)
+        for i, sm in zip(small_ix, smalls):
+            flat_grads[i] = sm
+        with jax.named_scope("coded.encode"):
+            wires = [b.astype(wire_dtype) for b in bufs]
+        with jax.named_scope("coded.decode"):
+            decs = [codec.decode_packed(w, W, data_axes) for w in wires]
+            unpacked = codec.unpack(decs, pplan)
+        unpacked.update(coding.psum_fallback(flat_grads, flat_plans,
+                                             data_axes))
+        grads = jax.tree.unflatten(
+            jax.tree.structure(params),
+            [unpacked[i] for i in range(len(flat_plans))])
         grads = jax.tree.map(lambda g_: g_ * grad_scale, grads)
         gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_) for g_ in jax.tree.leaves(grads)))
         # responders' view, normalised by the subset count (= n uniformly)
@@ -589,11 +470,10 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
         """Decode the in-flight wire + side buffers and apply the update:
         the synchronous step's phases 4-5 operating on state instead of
         locally produced encodings.  Op-for-op identical to the sync body
-        (bitwise parity) on the default path; with ``fuse_apply`` the coded
-        leaves ride the fused decode-plus-apply kernel instead."""
+        (bitwise parity)."""
         side_sum = wire.psum(side, data_axes)
         loss_global = side_sum[-1] / k_subsets
-        flat_params, ptd = jax.tree.flatten(params)
+        ptd = jax.tree.structure(params)
         small_grads: dict[int, jax.Array] = {}
         off = 0
         for i, sz, sh in zip(small_ix, small_sizes, small_shapes):
@@ -601,58 +481,18 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                               .reshape(sh) * grad_scale)
             off += sz
 
-        if not fuse:
-            flat_grads: list = [None] * len(flat_params)
-            with jax.named_scope("coded.decode"):
-                decs = [codec.decode_packed(w, W, data_axes) for w in wires]
-                for i, g_ in codec.unpack(decs, pplan).items():
-                    flat_grads[i] = g_ * grad_scale
-            for i, g_ in small_grads.items():
-                flat_grads[i] = g_
-            grads = ptd.unflatten(flat_grads)
-            gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_)
-                                 for g_ in jax.tree.leaves(grads)))
-            with jax.named_scope("coded.apply"):
-                new_params, new_opt = optimizer.update(grads, opt_state,
-                                                       params)
-        else:
-            hy = optimizer.hyper
-            flat_mu = ptd.flatten_up_to(opt_state["mu"])
-            p_bufs = codec.pack_params(flat_params, pplan)
-            mu_bufs = codec.pack_params(flat_mu, pplan)
-            new_p_bufs, new_mu_bufs, ss_parts = [], [], []
-            for w, pb, mb in zip(wires, p_bufs, mu_bufs):
-                with jax.named_scope("coded.decode"):
-                    pn, mn, ss = codec.decode_apply_packed(
-                        w, W, pb, mb, data_axes, lr=hy["lr"],
-                        momentum=hy["momentum"], scale=grad_scale)
-                new_p_bufs.append(pn)
-                new_mu_bufs.append(mn)
-                ss_parts.append(ss)
-            # small leaves ride the plain optimizer update (zero grads at
-            # coded positions — their state is overwritten from the fused
-            # buffers right below)
-            flat_gz = [small_grads.get(i,
-                                       jnp.zeros(flat_params[i].shape,
-                                                 jnp.float32))
-                       for i in range(len(flat_params))]
-            with jax.named_scope("coded.apply"):
-                new_params, new_opt = optimizer.update(
-                    ptd.unflatten(flat_gz), opt_state, params)
-            flat_np = ptd.flatten_up_to(new_params)
-            flat_nmu = ptd.flatten_up_to(new_opt["mu"])
-            for i, v in codec.unpack_params(new_p_bufs, pplan,
-                                            flat_params).items():
-                flat_np[i] = v
-            for i, v in codec.unpack_params(new_mu_bufs, pplan,
-                                            flat_mu).items():
-                flat_nmu[i] = v
-            new_params = ptd.unflatten(flat_np)
-            new_opt = {"mu": ptd.unflatten(flat_nmu)}
-            gnorm = jnp.sqrt(sum(ss_parts)
-                             + sum(jnp.sum(g_ * g_)
-                                   for g_ in small_grads.values()))
-
+        flat_grads: list = [None] * len(flat_plans)
+        with jax.named_scope("coded.decode"):
+            decs = [codec.decode_packed(w, W, data_axes) for w in wires]
+            for i, g_ in codec.unpack(decs, pplan).items():
+                flat_grads[i] = g_ * grad_scale
+        for i, g_ in small_grads.items():
+            flat_grads[i] = g_
+        grads = ptd.unflatten(flat_grads)
+        gnorm = jnp.sqrt(sum(jnp.sum(g_ * g_)
+                             for g_ in jax.tree.leaves(grads)))
+        with jax.named_scope("coded.apply"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         metrics = {"loss": loss_global[None], "grad_norm": gnorm[None]}
         return new_params, new_opt, metrics
 
@@ -780,6 +620,5 @@ def make_coded_train_step(cfg, code: GradCode, mesh, optimizer: Optimizer,
                          plans=plans, coded_fraction=coded_frac, codec=codec,
                          pack_plan=pplan,
                          loads=tuple(getattr(code, "loads", (code.d,) * n)),
-                         partial=partial, pipelined=pipelined,
-                         fuse_apply=fuse, spec=spec,
+                         partial=partial, pipelined=pipelined, spec=spec,
                          pipeline=make_pipeline if pipelined else None)

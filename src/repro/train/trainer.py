@@ -4,8 +4,8 @@ loop.
 
 Scheme levers arrive as one ``repro.coding.SchemeSpec``
 (``Trainer(spec=...)`` — the same instance a ``repro.serving.CodedServer``
-accepts); the legacy per-lever kwargs (``schedule``/``backend``/``packed``/
-``partial``/``pipelined``) fold into a spec with a ``DeprecationWarning``.
+accepts); ``Trainer.spec`` is the active scheme and the only place the
+trainer reads its levers from.
 
 Stragglers: each step draws a straggler set from the trainer's
 ``straggler_source`` (the ``repro.tune.StragglerSource`` protocol shared
@@ -23,10 +23,10 @@ straggler source (wrapping a ``(step, code) -> WorkerTimes`` callable such
 as ``repro.tune.DriftingSampler``; on a real cluster, worker heartbeats), the
 induced straggler set, and the measured step wall-clock — and every
 ``policy.interval`` steps refits the Section-VI shifted-exponential model
-and re-ranks the feasible (d, s, m) x schedule x packed space
-(``repro.tune``).  When the winning plan beats the active one past the
-hysteresis margin the trainer swaps codecs in place: code, schedule, wire
-format and batcher are replaced, and both the ``StepArtifacts`` and the
+and re-ranks the feasible (d, s, m) x schedule space (``repro.tune``).
+When the winning plan beats the active one past the hysteresis margin the
+trainer swaps codecs in place: code, schedule, wire layout and batcher are
+replaced, and both the ``StepArtifacts`` and the
 jitted executables are held in caches keyed by the scheme signature, so
 switching back to a previously used scheme reuses its compiled step instead
 of retracing.  ``partial=True`` is preserved across swaps (every cached
@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.coding import SchemeSpec, make_step_inputs, resolve_scheme_spec
+from repro.coding import SchemeSpec, make_step_inputs
 from repro.core import GradCode, make_code
 from repro.data import CodedBatcher
 from repro.optim import Optimizer
@@ -59,15 +59,9 @@ class Trainer:
     code: GradCode
     mesh: Any
     optimizer: Optimizer
-    # the scheme levers: one SchemeSpec (shared with CodedServer) — the
-    # per-lever fields below it are the deprecated spelling and fold into
-    # the spec with a DeprecationWarning
-    spec: SchemeSpec | None = None
-    schedule: str | None = None        # deprecated: SchemeSpec.schedule
-    backend: str | None = None         # deprecated: SchemeSpec.backend
-    packed: bool | None = None         # deprecated: SchemeSpec.packed
-    partial: bool | None = None        # deprecated: SchemeSpec.partial
-    pipelined: bool | None = None      # deprecated: SchemeSpec.pipelined
+    # the scheme levers: one SchemeSpec (shared with CodedServer); the
+    # autotuner and the elastic trainer replace it as they swap schemes
+    spec: SchemeSpec = dataclasses.field(default_factory=SchemeSpec)
     # the straggler process: one StragglerSource (shared with CodedServer's
     # hedging loop) — the three legacy fields map onto it
     straggler_source: Any | None = None
@@ -86,20 +80,6 @@ class Trainer:
         from repro.tune.stragglers import (FixedStragglers, NoStragglers,
                                            RandomStragglers, TimedSource,
                                            as_straggler_source)
-        self.spec = resolve_scheme_spec(
-            self.spec,
-            dict(schedule=self.schedule, backend=self.backend,
-                 packed=self.packed, partial=self.partial,
-                 pipelined=self.pipelined),
-            caller="Trainer")
-        # mutable mirrors of the active scheme (the autotuner swaps them and
-        # `self.spec` together through _apply_plan)
-        self.schedule = self.spec.schedule
-        self.backend = self.spec.backend
-        self.packed = self.spec.packed
-        self.partial = self.spec.partial
-        self.pipelined = self.spec.pipelined
-
         legacy_straggler = (self.straggler_mode is not None
                             or bool(self.fixed_stragglers)
                             or self.injector is not None)
@@ -149,8 +129,8 @@ class Trainer:
                 "a cluster heartbeat feed — the deprecated injector= "
                 "spelling also works)")
         self._arts_cache: dict[tuple, Any] = {}
-        self.arts = self._get_arts(self.code, self.schedule, self.packed,
-                                   self.pipelined)
+        self.arts = self._get_arts(self.code, self.spec.schedule,
+                                   self.spec.pipelined)
         self._driver: PipelineDriver | None = None
         self.batcher = CodedBatcher(self.code)
         key = jax.random.PRNGKey(self.seed)
@@ -219,16 +199,17 @@ class Trainer:
         key): the partial step takes an extra ``err_factor`` argument, so
         an executable compiled for one mode must never serve the other.
         """
-        return (self._code_key(self.code), self.schedule, self.packed,
-                self.partial if partial is None else bool(partial),
-                self.pipelined if pipelined is None else bool(pipelined))
+        return (self._code_key(self.code), self.spec.schedule,
+                self.spec.partial if partial is None else bool(partial),
+                self.spec.pipelined if pipelined is None
+                else bool(pipelined))
 
     @property
     def _scheme_sig(self) -> tuple:
         return self._sig()
 
-    def _get_arts(self, code, schedule: str, packed: bool,
-                  pipelined: bool = False, partial: bool | None = None):
+    def _get_arts(self, code, schedule: str, pipelined: bool = False,
+                  partial: bool | None = None):
         """StepArtifacts for a scheme, built once per signature (the compile
         cache's first layer; the jitted executables are the second).
 
@@ -238,12 +219,12 @@ class Trainer:
         of raising.  Partial artifacts are always synchronous
         (``SchemeSpec`` rejects pipelined+partial).
         """
-        part = self.partial if partial is None else bool(partial)
-        key = (self._code_key(code), schedule, packed, part, pipelined)
+        part = self.spec.partial if partial is None else bool(partial)
+        key = (self._code_key(code), schedule, part, pipelined)
         if key not in self._arts_cache:
             self._arts_cache[key] = make_coded_train_step(
                 self.cfg, code, self.mesh, self.optimizer,
-                spec=self.spec.replace(schedule=schedule, packed=packed,
+                spec=self.spec.replace(schedule=schedule,
                                        pipelined=pipelined, partial=part))
         return self._arts_cache[key]
 
@@ -268,10 +249,10 @@ class Trainer:
             fam = ("uniform" if k == self.code.n and len(set(loads)) == 1
                    else "hetero")
         return Plan(family=fam, d=self.code.d, s=self.code.s, m=self.code.m,
-                    k=k, loads=loads, schedule=self.schedule,
-                    packed=self.packed, predicted_wait_s=0.0,
-                    predicted_step_s=0.0, predicted_total_s=0.0,
-                    pipelined=self.pipelined, n0=n0)
+                    k=k, loads=loads, schedule=self.spec.schedule,
+                    predicted_wait_s=0.0, predicted_step_s=0.0,
+                    predicted_total_s=0.0, pipelined=self.spec.pipelined,
+                    n0=n0)
 
     def _code_for_plan(self, plan):
         """Materialise the scheme object a ranked plan selects."""
@@ -306,8 +287,7 @@ class Trainer:
                         speeds=speeds, loads=tuple(plan.loads))
         return HeteroCode(plan=hp, kind="poly" if n <= 20 else "random")
 
-    def _swap_code(self, code, schedule: str, packed: bool,
-                   pipelined: bool) -> None:
+    def _swap_code(self, code, schedule: str, pipelined: bool) -> None:
         """Swap the active codec in place (code, schedule, wire, batcher).
 
         A pipelined swap first drains the in-flight wire (its buffers were
@@ -319,13 +299,8 @@ class Trainer:
                 self.params, self.opt_state)
         self._driver = None
         self.code = code
-        self.schedule = schedule
-        self.packed = packed
-        self.pipelined = pipelined
-        self.spec = self.spec.replace(schedule=self.schedule,
-                                      packed=self.packed,
-                                      pipelined=self.pipelined)
-        self.arts = self._get_arts(code, schedule, packed, self.pipelined)
+        self.spec = self.spec.replace(schedule=schedule, pipelined=pipelined)
+        self.arts = self._get_arts(code, schedule, pipelined)
         self.batcher = CodedBatcher(code)
 
     def _apply_plan(self, plan) -> None:
@@ -338,12 +313,10 @@ class Trainer:
         """
         code = self._code_for_plan(plan)
         if plan.family in ("frc", "expander") and plan.s > code.s:
-            self.partial = True
             # approx plans are never pipelined; drop the flag in the same
             # replace (SchemeSpec rejects partial+pipelined)
             self.spec = self.spec.replace(partial=True, pipelined=False)
-        self._swap_code(code, plan.schedule,
-                        plan.packed, getattr(plan, "pipelined", False))
+        self._swap_code(code, plan.schedule, getattr(plan, "pipelined", False))
 
     @property
     def autotune_events(self) -> list[dict]:
@@ -399,7 +372,7 @@ class Trainer:
         the past-budget step then fails over to the approximate decode
         (with its ``decode_err_bound`` certificate) instead of raising.
         """
-        return bool(self.partial)
+        return self.spec.partial
 
     def _departed_workers(self) -> tuple[int, ...]:
         """Departed worker indices for the re-planner (subclass hook)."""
@@ -427,17 +400,18 @@ class Trainer:
             # drops to the sync path for this step only; when the trainer
             # is *configured* partial, pipelining is already off
             # (SchemeSpec rejects the combo)
-            pipelined = self.pipelined and not part
-            if (self.pipelined and not pipelined and self._driver is not None
+            pipelined = self.spec.pipelined and not part
+            if (self.spec.pipelined and not pipelined
+                    and self._driver is not None
                     and self._driver.in_flight):
                 # retire the in-flight update before stepping synchronously
                 # — its buffers are valid under the unchanged codec
                 self.params, self.opt_state, _ = self._driver.drain(
                     self.params, self.opt_state)
                 self._driver = None
-            arts = (self.arts if part == self.partial
-                    and pipelined == self.pipelined
-                    else self._get_arts(self.code, self.schedule, self.packed,
+            arts = (self.arts if part == self.spec.partial
+                    and pipelined == self.spec.pipelined
+                    else self._get_arts(self.code, self.spec.schedule,
                                         pipelined=pipelined, partial=part))
             fn = None
             fresh = False
@@ -499,7 +473,7 @@ class Trainer:
                 # cost either.
                 uncal = fresh or metrics is None
                 rec = record_from_times(self._step_count, self.code,
-                                        self.schedule, self.packed, times,
+                                        self.spec.schedule, times,
                                         measured_step_s=0.0 if uncal
                                         else wall,
                                         pipelined=pipelined,

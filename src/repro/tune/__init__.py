@@ -15,7 +15,7 @@ runtime:
               from observed timings (`fit_runtime_params`), cross-checked
               against the order-statistic math of
               ``repro.core.runtime_model`` (`crosscheck_waits`)
-  planner   — ranked search of the feasible (d, s, m) x schedule x packed
+  planner   — ranked search of the feasible (d, s, m) x schedule
               x {uniform, hetero} space by predicted ``E[T_tot]``,
               calibrated with measured step times (`rank_plans`, `Plan`)
   policy    — the control loop (`AutotunePolicy`, `Autotuner`): re-plan

@@ -169,7 +169,7 @@ def rank_serving_plans(fit, *, arrivals: PoissonArrivals,
                                  seed=seed + 7919 * d + 31 * m)
             waits = np.array([p.wait_s for p in pats])
             for schedule in schedules:
-                step = book.cost(d, n, (d,) * n, schedule, True)
+                step = book.cost(d, n, (d,) * n, schedule)
                 pool = waits + step
                 q = simulate_queue(pool, arrivals,
                                    batch_requests=batch_requests,

@@ -189,7 +189,7 @@ def synthetic_fit(params: RuntimeParams,
         wt = sampler.draw((d,) * n, n, m)
         slow, wait = wt.order_stat(s)
         records.append(_SR(step=t, d=d, s=s, m=m, k=n, loads=(d,) * n,
-                           schedule="gather", packed=True,
+                           schedule="gather",
                            compute_s=wt.compute_s, comm_s=wt.comm_s,
                            stragglers=slow, wait_s=wait))
     return fit_runtime_params(records)

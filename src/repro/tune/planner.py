@@ -3,7 +3,7 @@
 Given a :class:`~repro.tune.estimator.FitResult` the planner scores every
 reachable configuration
 
-    (d, s, m) on the optimal frontier  x  schedule  x  packed  x  family
+    (d, s, m) on the optimal frontier  x  schedule  x  family
 
 and returns a ranked list of :class:`Plan`.  Each plan's predicted cost is
 
@@ -16,7 +16,7 @@ triples, a Monte-Carlo mean (:func:`~repro.bench.straggler.
 draw_patterns_hetero`, which reduces to the same model) for
 heterogeneous-load plans — and ``predicted_step_s`` calibrates in the
 *measured* wall-clock of the jitted step from telemetry: the mean observed
-step time per ``(schedule, packed)`` configuration
+step time per schedule
 (:func:`step_cost_book`), falling back to the cheapest observed
 configuration for ones not yet tried.  Modeled wait and measured step cost
 live on the same axis (seconds), so the calibration is a straight sum.
@@ -58,7 +58,7 @@ PIPELINE_EPS = 1e-3
 
 @dataclasses.dataclass(frozen=True)
 class Plan:
-    """One ranked operating point: scheme + schedule + wire format + cost."""
+    """One ranked operating point: scheme + schedule + cost."""
 
     family: str    # uniform | hetero | frc | expander | chebyshev | rotation | block
     d: int                      # computation load (max per-worker for hetero)
@@ -67,7 +67,6 @@ class Plan:
     k: int                      # data subsets (n for uniform)
     loads: tuple[int, ...]      # per-worker subset counts
     schedule: str               # gather | a2a
-    packed: bool                # bucketed wire vs per-leaf collectives
     predicted_wait_s: float     # modeled cluster wait under the fit
     predicted_step_s: float     # calibrated measured step cost
     predicted_total_s: float    # wait + step: the ranking key
@@ -88,8 +87,7 @@ class Plan:
     def scheme_key(self) -> tuple:
         """Hashable identity of the codec this plan selects (sans costs)."""
         return (self.family, self.d, self.s, self.m, self.k, self.loads,
-                self.schedule, self.packed, self.pipelined, self.resize_to,
-                self.n0)
+                self.schedule, self.pipelined, self.resize_to, self.n0)
 
     def describe(self) -> str:
         """One-line human-readable summary."""
@@ -103,8 +101,7 @@ class Plan:
         if self.cond_bound:
             err += f",cond<={self.cond_bound:.3g}"
         return (f"{self.family}(d={self.d},s={self.s},m={self.m}"
-                f"{extra}{err}),{self.schedule},"
-                f"{'packed' if self.packed else 'per-leaf'}"
+                f"{extra}{err}),{self.schedule}"
                 f"{',pipelined' if self.pipelined else ''}{resize}: "
                 f"E[T]={self.predicted_total_s:.3f}s "
                 f"(wait {self.predicted_wait_s:.3f} "
@@ -118,9 +115,9 @@ class StepCostBook:
     (synthetic windows carry none).  Lookup order for a candidate plan:
 
     1. **exact**: the mean measurement of the identical scheme
-       ``(d, k, loads, schedule, packed)``;
+       ``(d, k, loads, schedule, pipelined)``;
     2. **per-config, per-load**: mean of ``measured / d`` over the
-       candidate's ``(schedule, packed)`` config, scaled by the
+       candidate's ``(schedule, pipelined)`` config, scaled by the
        candidate's ``d`` — a d=1 candidate is not charged the wall-clock
        of the d=4 step that produced the telemetry;
     3. **global per-load**: the same ratio pooled over every config
@@ -147,13 +144,13 @@ class StepCostBook:
         compile_walls: list[float] = []
         for r in records:
             pipe = bool(getattr(r, "pipelined", False))
-            key = (r.d, r.k, tuple(r.loads), r.schedule, r.packed, pipe)
+            key = (r.d, r.k, tuple(r.loads), r.schedule, pipe)
             if getattr(r, "compile_s", 0.0) > 0:
                 compile_walls.append(float(r.compile_s))
             if r.measured_step_s > 0:
                 compiled.add(key)
                 exact.setdefault(key, []).append(r.measured_step_s)
-                per_cfg.setdefault((r.schedule, r.packed, pipe), []).append(
+                per_cfg.setdefault((r.schedule, pipe), []).append(
                     r.measured_step_s / max(r.d, 1))
                 per_load.append(r.measured_step_s / max(r.d, 1))
         self._exact = {k: float(np.mean(v)) for k, v in exact.items()}
@@ -173,17 +170,16 @@ class StepCostBook:
         return self._compile_wall
 
     def cost(self, d: int, k: int, loads: tuple[int, ...], schedule: str,
-             packed: bool, pipelined: bool = False) -> float:
+             pipelined: bool = False) -> float:
         """Predicted measured-step seconds for a candidate scheme."""
-        key = (d, k, tuple(loads), schedule, packed, bool(pipelined))
+        key = (d, k, tuple(loads), schedule, bool(pipelined))
         if key in self._exact:
             return self._exact[key]
-        cfg = self._per_cfg.get((schedule, packed, bool(pipelined)))
+        cfg = self._per_cfg.get((schedule, bool(pipelined)))
         return (cfg if cfg is not None else self._global) * max(d, 1)
 
     def amortized_compile(self, d: int, k: int, loads: tuple[int, ...],
-                          schedule: str, packed: bool,
-                          pipelined: bool = False,
+                          schedule: str, pipelined: bool = False,
                           horizon: int = 200) -> float:
         """Per-step recompile charge for switching to a candidate scheme.
 
@@ -193,7 +189,7 @@ class StepCostBook:
         steps until the next re-plan).  With no compile observations the
         charge is 0.0 — the ranking degrades gracefully to cost-blind.
         """
-        key = (d, k, tuple(loads), schedule, packed, bool(pipelined))
+        key = (d, k, tuple(loads), schedule, bool(pipelined))
         if key in self._compiled or self._compile_wall <= 0:
             return 0.0
         return self._compile_wall / max(int(horizon), 1)
@@ -293,7 +289,7 @@ def score_plan(fit: FitResult, plan: Plan,
     else:
         wait = _hetero_wait(fit, plan.loads, plan.k, plan.s, plan.m,
                             mc_iters, seed, departed=dep)
-    step = book.cost(plan.d, plan.k, plan.loads, plan.schedule, plan.packed,
+    step = book.cost(plan.d, plan.k, plan.loads, plan.schedule,
                      plan.pipelined)
     return dataclasses.replace(plan, predicted_wait_s=wait,
                                predicted_step_s=step,
@@ -303,7 +299,6 @@ def score_plan(fit: FitResult, plan: Plan,
 def rank_plans(fit: FitResult, *,
                schedules: Sequence[str] = ("gather", "a2a"),
                families: Sequence[str] = ("uniform",),
-               packed_options: Sequence[bool] = (True,),
                pipelined_options: Sequence[bool] = (False,),
                cost_book: StepCostBook | None = None,
                min_s: int = 0,
@@ -333,7 +328,7 @@ def rank_plans(fit: FitResult, *,
     pipelining is a uniform-family knob (the hetero runtime stays
     synchronous).  Ties (e.g. two schedules with no measurements yet) break
     deterministically toward the earlier entry in ``schedules`` /
-    ``packed_options`` / ``pipelined_options``.
+    ``pipelined_options``.
 
     **Elastic membership** (all default-off, so the classic ranking is
     bit-identical when unused):
@@ -396,7 +391,6 @@ def rank_plans(fit: FitResult, *,
 
     candidates: list[tuple] = []     # (total, tiebreak, Plan)
     sched_rank = {sc: i for i, sc in enumerate(schedules)}
-    packed_rank = {pk: i for i, pk in enumerate(packed_options)}
     pipe_rank = {pi: i for i, pi in enumerate(pipelined_options)}
 
     def add(family, d, s, m, k, loads, waits, resize_to=None,
@@ -404,28 +398,25 @@ def rank_plans(fit: FitResult, *,
         # waits: {pipelined_flag: modeled wait} for the flags this scheme
         # supports (hetero and approx pass only {False: ...})
         for schedule in schedules:
-            for packed in packed_options:
-                for pipelined, wait in waits.items():
-                    if pipelined not in pipe_rank:
-                        continue   # scheme doesn't support this flag
-                    step = book.cost(d, k, loads, schedule, packed,
-                                     pipelined)
-                    if charge_compile or amortize_compile:
-                        step += book.amortized_compile(
-                            d, k, loads, schedule, packed, pipelined,
-                            horizon=replan_horizon)
-                    candidates.append((
-                        wait + step,
-                        (0 if resize_to is None else 1,
-                         sched_rank[schedule], packed_rank[packed],
-                         pipe_rank[pipelined]),
-                        Plan(family=family, d=d, s=s, m=m, k=k, loads=loads,
-                             schedule=schedule, packed=packed,
-                             predicted_wait_s=wait, predicted_step_s=step,
-                             predicted_total_s=wait + step,
-                             pipelined=pipelined, resize_to=resize_to,
-                             err_bound=err_bound, cond_bound=cond_bound,
-                             n0=n0)))
+            for pipelined, wait in waits.items():
+                if pipelined not in pipe_rank:
+                    continue   # scheme doesn't support this flag
+                step = book.cost(d, k, loads, schedule, pipelined)
+                if charge_compile or amortize_compile:
+                    step += book.amortized_compile(
+                        d, k, loads, schedule, pipelined,
+                        horizon=replan_horizon)
+                candidates.append((
+                    wait + step,
+                    (0 if resize_to is None else 1,
+                     sched_rank[schedule], pipe_rank[pipelined]),
+                    Plan(family=family, d=d, s=s, m=m, k=k, loads=loads,
+                         schedule=schedule, predicted_wait_s=wait,
+                         predicted_step_s=step,
+                         predicted_total_s=wait + step,
+                         pipelined=pipelined, resize_to=resize_to,
+                         err_bound=err_bound, cond_bound=cond_bound,
+                         n0=n0)))
 
     cond_ceiling = float("inf") if max_cond is None else float(max_cond)
 

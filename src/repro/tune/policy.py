@@ -44,7 +44,6 @@ class AutotunePolicy:
     min_samples: int = 8            # records required before the first fit
     schedules: tuple[str, ...] = ("gather", "a2a")
     families: tuple[str, ...] = ("uniform",)   # + "hetero" / "hetero!"
-    packed_options: tuple[bool, ...] = (True,)
     pipelined_options: tuple[bool, ...] = (False,)  # + True: async stale-1
     min_s: int = 0                  # floor on the straggler budget
     hetero_threshold: float = 1.15  # speed spread unlocking hetero plans
@@ -150,7 +149,6 @@ class Autotuner:
                     resize.append(new_n)
         ranked = rank_plans(
             fit, schedules=p.schedules, families=p.families,
-            packed_options=p.packed_options,
             pipelined_options=p.pipelined_options,
             cost_book=book, min_s=p.min_s,
             hetero_threshold=p.hetero_threshold, mc_iters=p.mc_iters,

@@ -4,7 +4,7 @@ The tuner's measure->fit->re-plan loop starts here.  Every training step
 produces one :class:`StepRecord` carrying
 
 - the **scheme signature** the step ran under (``d, s, m, k``, per-worker
-  ``loads``, schedule, packed flag) — the estimator needs it to normalise
+  ``loads``, schedule, pipelined flag) — the estimator needs it to normalise
   timings into per-subset / per-encoding samples, and the planner needs it
   to calibrate predicted step costs per configuration;
 - the per-worker **compute** and **communication** durations (seconds) —
@@ -86,7 +86,6 @@ class StepRecord:
     k: int                      # number of data subsets (n for uniform codes)
     loads: tuple[int, ...]      # per-worker subset counts
     schedule: str               # gather | a2a | psum
-    packed: bool                # bucketed flat wire vs per-leaf collectives
     compute_s: np.ndarray       # (n,) per-worker compute durations
     comm_s: np.ndarray          # (n,) per-worker communication durations
     stragglers: tuple[int, ...] = ()
@@ -113,8 +112,8 @@ def scheme_k(code) -> int:
     return int(getattr(code, "num_subsets", code.n))
 
 
-def record_from_times(step: int, code, schedule: str, packed: bool,
-                      times: WorkerTimes, n_drop: int | None = None,
+def record_from_times(step: int, code, schedule: str, times: WorkerTimes,
+                      n_drop: int | None = None,
                       measured_step_s: float = 0.0,
                       pipelined: bool = False,
                       compile_s: float = 0.0) -> StepRecord:
@@ -132,7 +131,7 @@ def record_from_times(step: int, code, schedule: str, packed: bool,
     return StepRecord(
         step=step, d=code.d, s=code.s, m=code.m,
         k=scheme_k(code), loads=scheme_loads(code),
-        schedule=schedule, packed=packed,
+        schedule=schedule,
         compute_s=times.compute_s, comm_s=times.comm_s,
         stragglers=slow, wait_s=wait, measured_step_s=measured_step_s,
         pipelined=pipelined, compile_s=compile_s)

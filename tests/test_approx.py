@@ -13,9 +13,9 @@ Five layers:
      exactly 0.0 without touching the generic least-squares solver, and the
      ``sample_straggler_sets`` trial driver honours its contract;
   4. full-step integration — both families ride the real jitted
-     ``make_coded_train_step(partial=True)`` on gather/a2a x packed/per-leaf,
-     the ``decode_err_bound`` metric matches the numpy-side certificate, and
-     packed and per-leaf wires agree bitwise-tight;
+     ``make_coded_train_step(partial=True)`` on gather/a2a, the
+     ``decode_err_bound`` metric matches the numpy-side certificate, and
+     the two schedules agree tightly;
   5. planner/trainer seam — ``rank_plans(approx_options=, max_err=)`` admits
      a candidate iff its bound clears the ceiling, and the trainer
      materialises the ranked construction and flips to partial mode.
@@ -276,7 +276,7 @@ def _linear_setup():
     return cfg, mesh, opt, batch, params
 
 
-def _run_step(code, schedule, stragglers, partial=False, packed=True):
+def _run_step(code, schedule, stragglers, partial=False):
     import jax
     import jax.numpy as jnp
 
@@ -287,8 +287,7 @@ def _run_step(code, schedule, stragglers, partial=False, packed=True):
     cfg, mesh, opt, batch, params = _linear_setup()
     arts = make_coded_train_step(
         cfg, code, mesh, opt,
-        spec=coding.SchemeSpec(schedule=schedule, partial=partial,
-                               packed=packed))
+        spec=coding.SchemeSpec(schedule=schedule, partial=partial))
     placed = jax.tree.map(jnp.asarray, CodedBatcher(code).place(batch))
     fn = arts.compiled(placed)
     inp = arts.step_inputs(stragglers)
@@ -323,14 +322,13 @@ def test_approx_step_full_response_matches_uncoded(code, schedule):
 
 
 @pytest.mark.parametrize("code", APPROX_CODES, ids=_IDS)
-@pytest.mark.parametrize("packed", [True, False], ids=["packed", "per-leaf"])
-def test_approx_step_completes_past_s(code, packed):
+@pytest.mark.parametrize("schedule", ["gather", "a2a"])
+def test_approx_step_completes_past_s(code, schedule):
     """Any straggler pattern past the structural budget still yields finite
     parameters plus a finite certified bound (expander: any straggler at
-    all — its exact budget is zero)."""
+    all — its exact budget is zero), on both schedules."""
     stragglers = tuple(range(code.s + 1))
-    got, metrics, _ = _run_step(code, "gather", stragglers, partial=True,
-                                packed=packed)
+    got, metrics, _ = _run_step(code, schedule, stragglers, partial=True)
     import jax
     bound = float(metrics["decode_err_bound"][0])
     assert np.isfinite(bound)
@@ -375,10 +373,10 @@ def test_decode_err_bound_metric_matches_numpy(code):
 @pytest.mark.parametrize("code", [APPROX_CODES[0], APPROX_CODES[3]],
                          ids=["frc", "expander"])
 def test_packed_vs_per_leaf_parity(code):
-    """The packed bucketed wire and the per-leaf collectives produce the
-    same update for the approx families (same straggler pattern)."""
-    a, ma, _ = _run_step(code, "gather", (0,), partial=True, packed=True)
-    b, mb, _ = _run_step(code, "gather", (0,), partial=True, packed=False)
+    """The gather and a2a schedules decode the approx families to the same
+    update and certificate (same straggler pattern)."""
+    a, ma, _ = _run_step(code, "gather", (0,), partial=True)
+    b, mb, _ = _run_step(code, "a2a", (0,), partial=True)
     assert _max_diff(a, b) < 1e-6
     assert float(ma["decode_err_bound"][0]) == pytest.approx(
         float(mb["decode_err_bound"][0]), rel=1e-5)
@@ -455,21 +453,21 @@ def test_trainer_applies_approx_plan_and_flips_partial():
     cfg = dataclasses.replace(get_config("logistic-paper"), d_model=64)
     tr = Trainer(cfg, make_code(N, 4, 2, 2), make_local_mesh(N, 1),
                  optimizer=get_optimizer("sgd", 1e-2))
-    assert not tr.partial
+    assert not tr.spec.partial
     plan = Plan(family="frc", d=2, s=3, m=1, k=N, loads=(2,) * N,
-                schedule="gather", packed=True, predicted_wait_s=0.0,
+                schedule="gather", predicted_wait_s=0.0,
                 predicted_step_s=0.0, predicted_total_s=0.0,
                 err_bound=make_frc(N, 1, 1).worst_err_bound(3))
     tr._apply_plan(plan)
     assert isinstance(tr.code, FractionalRepetitionCode)
-    assert tr.partial and tr.spec.partial and not tr.spec.pipelined
+    assert tr.spec.partial and not tr.spec.pipelined
     assert tr._current_plan().family == "frc"
     # the swapped-in trainer takes a real step past the structural budget
     m = tr.step(make_synthetic_batch(np.random.default_rng(0), cfg, 16, 0))
     assert np.isfinite(float(np.asarray(m["loss"]).ravel()[0]))
     # expander plans materialise the seeded graph that was ranked
     plan2 = Plan(family="expander", d=2, s=1, m=1, k=N, loads=(2,) * N,
-                 schedule="gather", packed=True, predicted_wait_s=0.0,
+                 schedule="gather", predicted_wait_s=0.0,
                  predicted_step_s=0.0, predicted_total_s=0.0)
     tr._apply_plan(plan2)
     assert isinstance(tr.code, ExpanderCode) and tr.code.seed == 0

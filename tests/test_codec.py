@@ -159,17 +159,32 @@ def test_use_kernels_flag_is_gone():
                                         ((16, 3), 0)])
 @pytest.mark.parametrize("backend", ["ref", "interpret"])
 def test_encode_leaf_backend_parity(shape, gdim, backend):
+    """``Codec.encode_into`` on each backend folds a leaf into its slot of a
+    one-slot zero bucket: the slot holds the tensordot oracle, every other
+    row stays zero."""
+    from repro.coding.layout import from_slot_plane
     g = jnp.asarray(RNG.standard_normal(shape), jnp.float32)
-    plan = coding.plan_leaf(shape, None, 2)
+    tree = {"g": jax.ShapeDtypeStruct(shape, jnp.float32)}
+    plans = coding.plan_tree(tree, None, CODE.m)
+    plan = plans["g"]
     assert plan.coded and plan.group_dim == gdim
+    codec = coding.make_codec(CODE, backend=backend)
+    pp = codec.pack_plan(tree, plans)
+    (bucket,) = pp.buckets
+    (slot,) = bucket.slots
     coef = jnp.asarray(RNG.standard_normal(2), jnp.float32)
-    got = coding.encode_leaf(g, coef, plan, coding.resolve_backend(backend))
+    buf = codec.encode_into(codec.bucket_zeros(pp)[0], g, coef, slot)
+    got = from_slot_plane(buf[slot.row:slot.row + slot.rows],
+                          slot.enc_shape, 0)
     # oracle: moveaxis + tensordot over the m leading blocks of the group dim
     x = jnp.moveaxis(g, plan.group_dim, 0)
     x = x.reshape(2, x.shape[0] // 2, *x.shape[1:])
     want = jnp.tensordot(coef, x, axes=[[0], [0]])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+    rest = np.asarray(buf).copy()
+    rest[slot.row:slot.row + slot.rows] = 0.0
+    assert not rest.any()
 
 
 @pytest.mark.parametrize("wire", [jnp.float32, jnp.bfloat16])

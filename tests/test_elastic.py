@@ -301,14 +301,14 @@ def test_score_plan_uncoverable_budget_prices_inf():
 
 def test_amortized_compile_charges_unmeasured_schemes_only():
     recs = [StepRecord(step=0, d=3, s=1, m=2, k=4, loads=(3,) * 4,
-                       schedule="gather", packed=True,
+                       schedule="gather",
                        compute_s=np.ones(4), comm_s=np.ones(4),
                        measured_step_s=0.05, compile_s=6.0)]
     book = step_cost_book(recs)
     # the measured scheme is warm in the executable cache: no charge
-    assert book.amortized_compile(3, 4, (3,) * 4, "gather", True) == 0.0
+    assert book.amortized_compile(3, 4, (3,) * 4, "gather") == 0.0
     # an unmeasured scheme pays the pooled compile wall over the horizon
-    charge = book.amortized_compile(2, 4, (2,) * 4, "gather", True,
+    charge = book.amortized_compile(2, 4, (2,) * 4, "gather",
                                     horizon=60)
     assert charge == pytest.approx(6.0 / 60)
 

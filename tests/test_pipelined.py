@@ -9,10 +9,6 @@ synchronous SGD only by the documented one-step gradient staleness: a
 steady call decodes the *previous* batch's wire (producing exactly the sync
 update for that batch) while encoding the current batch at the pre-update
 params.
-
-The fused decode-plus-apply variant (`fuse_apply=True`, SGD only) keeps
-params and momentum bit-identical; only its `grad_norm` metric reduces in
-bucket order instead of leaf order (documented ~1e-6 drift).
 """
 import dataclasses
 
@@ -163,37 +159,6 @@ def test_steady_applies_previous_batch_gradient():
     assert _tree_max_diff(ms, out[2]) == 0.0
 
 
-def test_fused_apply_parity():
-    """fuse_apply=True (SGD-only fused decode+momentum+apply kernel):
-    params and momentum stay bit-identical to the sync step; the grad_norm
-    metric may drift ~1e-6 (bucket-order vs leaf-order reduction)."""
-    opt = get_optimizer("sgd", 1e-2)
-    cfg, arts_s = _build("gather", "ref", opt)
-    _, arts_p = _build("gather", "ref", opt, pipelined=True,
-                       fuse_apply=True)
-    assert arts_p.fuse_apply
-    batches = _batches(cfg, seed=4)
-    params = model_api.init(jax.random.PRNGKey(9), cfg)
-    ps = pp = params
-    os_ = op = opt.init(params)
-    fn = arts_s.compiled(batches[0])
-    drv = PipelineDriver(arts_p, donate=False)
-    for batch, strag in zip(batches, STRAGGLER_SETS):
-        inp = arts_s.step_inputs(strag)
-        ps, os_, ms = fn(ps, os_, batch, inp["W"], inp["mask"], inp["rho"])
-        pp, op, _ = drv.step(pp, op, batch, inp["W"], inp["mask"],
-                             inp["rho"])
-        pp, op, mp = drv.drain(pp, op)
-        assert _tree_max_diff(ps, pp) == 0.0        # params bitwise
-        assert _tree_max_diff(os_, op) == 0.0       # momentum bitwise
-        np.testing.assert_allclose(
-            np.asarray(mp["grad_norm"]), np.asarray(ms["grad_norm"]),
-            rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(
-            np.asarray(mp["loss"]), np.asarray(ms["loss"]),
-            rtol=1e-5, atol=1e-6)
-
-
 # --------------------------------------------------------------- validation
 def test_pipelined_builder_validation():
     cfg = _cfg()
@@ -203,21 +168,10 @@ def test_pipelined_builder_validation():
         make_coded_train_step(cfg, CODE, mesh, sgd,
                               spec=coding.SchemeSpec(schedule="psum",
                                                      pipelined=True))
-    with pytest.raises(ValueError, match="packed"):
-        make_coded_train_step(cfg, CODE, mesh, sgd,
-                              spec=coding.SchemeSpec(packed=False,
-                                                     pipelined=True))
     with pytest.raises(ValueError, match="partial"):
         make_coded_train_step(cfg, CODE, mesh, sgd,
                               spec=coding.SchemeSpec(partial=True,
                                                      pipelined=True))
-    with pytest.raises(ValueError, match="pipelined"):
-        make_coded_train_step(cfg, CODE, mesh, sgd,
-                              spec=coding.SchemeSpec(fuse_apply=True))
-    with pytest.raises(ValueError, match="sgd"):
-        make_coded_train_step(
-            cfg, CODE, mesh, get_optimizer("nag", 1e-3),
-            spec=coding.SchemeSpec(pipelined=True, fuse_apply=True))
 
 
 def test_pipelining_supported_predicate():
@@ -279,11 +233,11 @@ def test_trainer_swap_drains_in_flight_pipeline():
     assert tr._driver is not None and tr._driver.in_flight
     params_before = jax.tree.map(np.asarray, tr.params)
     plan = Plan(family="uniform", d=3, s=1, m=2, k=N, loads=(3,) * N,
-                schedule="gather", packed=True, predicted_wait_s=0.0,
+                schedule="gather", predicted_wait_s=0.0,
                 predicted_step_s=0.0, predicted_total_s=0.0,
                 pipelined=False)
     tr._apply_plan(plan)
-    assert tr._driver is None and not tr.pipelined
+    assert tr._driver is None and not tr.spec.pipelined
     # the pending (3rd) gradient was applied by the drain, not dropped
     assert _tree_max_diff(params_before, tr.params) > 0.0
     after = [tr.step(fixed)["loss"] for _ in range(2)]

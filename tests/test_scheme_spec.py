@@ -1,25 +1,22 @@
 """SchemeSpec: the one value object for every scheme lever.
 
-Pins the API-redesign contracts: the spec path and the deprecated kwarg
-path build bitwise-identical steps; spec= and kwargs cannot be mixed; the
-spec's validation reproduces the historical error messages; and the
-Trainer's legacy straggler fields map onto the StragglerSource protocol
-with deprecation warnings.
+Pins the API contracts: the spec holds exactly the five levers; the
+per-lever kwargs it replaced are gone from ``make_coded_train_step`` and
+the ``Trainer`` (``TypeError``); the spec's validation reproduces the
+historical error messages; and the Trainer's legacy straggler fields map
+onto the StragglerSource protocol with deprecation warnings.
 """
 import dataclasses
 import warnings
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.coding as coding
 from repro.configs import get_config
 from repro.core import make_code
-from repro.data import CodedBatcher, make_synthetic_batch
+from repro.data import make_synthetic_batch
 from repro.launch.mesh import make_local_mesh
-from repro.models import api as model_api
 from repro.optim import get_optimizer
 from repro.train.coded_step import make_coded_train_step
 from repro.train.trainer import Trainer
@@ -28,6 +25,11 @@ from repro.tune import (FixedStragglers, NoStragglers, RandomStragglers,
 
 CODE = make_code(4, 3, 1, 2)
 
+# the per-lever kwargs SchemeSpec replaced; neither builder accepts them
+REMOVED_KWARGS = {"schedule": "a2a", "backend": "ref", "packed": False,
+                  "partial": True, "pipelined": True, "fuse_apply": True,
+                  "encode_dtype": "bfloat16"}
+
 
 def _linear_cfg():
     return dataclasses.replace(get_config("logistic-paper"), d_model=64)
@@ -35,65 +37,37 @@ def _linear_cfg():
 
 # ----------------------------------------------------------- the value object
 def test_spec_is_frozen_and_replace_works():
-    spec = coding.SchemeSpec(schedule="a2a", packed=False)
+    assert [f.name for f in dataclasses.fields(coding.SchemeSpec)] == [
+        "schedule", "backend", "partial", "pipelined", "encode_dtype"]
+    spec = coding.SchemeSpec(schedule="a2a", partial=False)
     with pytest.raises(dataclasses.FrozenInstanceError):
         spec.schedule = "gather"
-    spec2 = spec.replace(packed=True)
-    assert spec2.schedule == "a2a" and spec2.packed and not spec.packed
+    spec2 = spec.replace(partial=True)
+    assert spec2.schedule == "a2a" and spec2.partial and not spec.partial
 
 
 def test_spec_validation_reproduces_historical_messages():
-    with pytest.raises(ValueError, match="packed"):
-        coding.SchemeSpec(pipelined=True, packed=False)
     with pytest.raises(ValueError, match="partial"):
         coding.SchemeSpec(pipelined=True, partial=True)
     with pytest.raises(ValueError, match="encoding"):
         coding.SchemeSpec(pipelined=True, schedule="psum")
-    with pytest.raises(ValueError, match="pipelined"):
-        coding.SchemeSpec(fuse_apply=True)
+    with pytest.raises(ValueError, match="encoding"):
+        coding.SchemeSpec(pipelined=True,
+                          schedule=coding.get_schedule("psum"))
 
 
 def test_spec_and_kwargs_cannot_mix():
+    """The per-lever kwargs are gone: each raises ``TypeError``, with or
+    without ``spec=``."""
     cfg = _linear_cfg()
     mesh = make_local_mesh(4, 1)
     opt = get_optimizer("sgd", 1e-2)
-    with pytest.raises(TypeError, match="not both"):
-        make_coded_train_step(cfg, CODE, mesh, opt,
-                              spec=coding.SchemeSpec(), schedule="a2a")
-
-
-def _run_one_step(arts):
-    cfg = _linear_cfg()
-    rng = np.random.default_rng(5)
-    batch = make_synthetic_batch(rng, cfg, 16, 0)
-    placed = jax.tree.map(jnp.asarray, CodedBatcher(CODE).place(batch))
-    fn = arts.compiled(placed)
-    params = model_api.init(jax.random.PRNGKey(7), cfg)
-    opt = get_optimizer("sgd", 1e-2)
-    inp = arts.step_inputs([2])
-    return fn(params, opt.init(params), placed, inp["W"], inp["mask"],
-              inp["rho"])
-
-
-def test_legacy_kwargs_build_bitwise_identical_step():
-    """Acceptance criterion: the deprecation-shim path and the spec path
-    produce bitwise-identical StepArtifacts outputs."""
-    cfg = _linear_cfg()
-    mesh = make_local_mesh(4, 1)
-    opt = get_optimizer("sgd", 1e-2)
-    spec = coding.SchemeSpec(schedule="a2a", backend="ref", packed=False,
-                             encode_dtype="bfloat16")
-    via_spec = make_coded_train_step(cfg, CODE, mesh, opt, spec=spec)
-    with pytest.warns(DeprecationWarning, match="scheme kwargs"):
-        via_kwargs = make_coded_train_step(
-            cfg, CODE, mesh, opt, schedule="a2a", backend="ref",
-            packed=False, encode_dtype="bfloat16")
-    assert via_kwargs.spec == spec
-    p_a, o_a, m_a = _run_one_step(via_spec)
-    p_b, o_b, m_b = _run_one_step(via_kwargs)
-    for xa, xb in zip(jax.tree.leaves((p_a, o_a, m_a)),
-                      jax.tree.leaves((p_b, o_b, m_b))):
-        np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
+    for name, value in REMOVED_KWARGS.items():
+        with pytest.raises(TypeError, match=name):
+            make_coded_train_step(cfg, CODE, mesh, opt, **{name: value})
+        with pytest.raises(TypeError, match=name):
+            make_coded_train_step(cfg, CODE, mesh, opt,
+                                  spec=coding.SchemeSpec(), **{name: value})
 
 
 def test_spec_threads_through_step_artifacts():
@@ -167,19 +141,21 @@ def test_trainer_rejects_source_plus_legacy_fields():
 
 
 def test_trainer_spec_kwarg_and_legacy_kwargs():
+    """The Trainer reads its scheme from ``spec`` alone: the per-lever
+    kwargs raise ``TypeError``, and no mirror fields remain."""
     cfg = _linear_cfg()
     mesh = make_local_mesh(4, 1)
     opt = get_optimizer("sgd", 1e-2)
     spec = coding.SchemeSpec(schedule="a2a", backend="ref")
     tr = Trainer(cfg=cfg, code=CODE, mesh=mesh, optimizer=opt, spec=spec)
-    assert tr.spec == spec and tr.schedule == "a2a"
-    with pytest.warns(DeprecationWarning, match="scheme kwargs"):
-        tr2 = Trainer(cfg=cfg, code=CODE, mesh=mesh, optimizer=opt,
-                      schedule="a2a", backend="ref")
-    assert tr2.spec == spec
-    with pytest.raises(TypeError, match="not both"):
-        Trainer(cfg=cfg, code=CODE, mesh=mesh, optimizer=opt, spec=spec,
-                schedule="gather")
+    assert tr.spec == spec and tr.spec.schedule == "a2a"
+    assert tr.arts.spec.schedule == "a2a"
+    fields = {f.name for f in dataclasses.fields(Trainer)}
+    assert not fields & set(REMOVED_KWARGS)
+    for name, value in REMOVED_KWARGS.items():
+        with pytest.raises(TypeError, match=name):
+            Trainer(cfg=cfg, code=CODE, mesh=mesh, optimizer=opt,
+                    **{name: value})
 
 
 def test_trainer_runs_one_step_from_spec():

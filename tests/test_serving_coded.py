@@ -263,7 +263,7 @@ def test_coded_server_rejects_train_only_levers():
     mesh = make_local_mesh(4, 1)
     params = _rand_params(cfg)
     srv = CodedServer(cfg, CODE, mesh, params,
-                      spec=coding.SchemeSpec(pipelined=True, packed=True))
+                      spec=coding.SchemeSpec(pipelined=True))
     with pytest.raises(ValueError, match="pipelined"):
         srv.artifacts  # noqa: B018 — building the forward is the test
     with pytest.raises(ValueError, match="timed straggler_source"):
@@ -315,7 +315,7 @@ def test_serving_autotuner_adopts_better_plan():
     code = make_code(4, 1, 0, 1)       # start uncoded-ish: d=1
     for t in range(8):
         times = sampler(t, code)
-        tuner.record(record_from_times(t, code, "gather", True, times,
+        tuner.record(record_from_times(t, code, "gather", times,
                                        measured_step_s=0.01))
     assert tuner.due()
     plan = tuner.maybe_replan(8)
@@ -323,7 +323,7 @@ def test_serving_autotuner_adopts_better_plan():
     assert tuner.current is plan
     for t in range(8, 16):
         times = sampler(t, code)
-        tuner.record(record_from_times(t, code, "gather", True, times,
+        tuner.record(record_from_times(t, code, "gather", times,
                                        measured_step_s=0.01))
     again = tuner.maybe_replan(16)
     assert again is None               # no drift -> hysteresis holds

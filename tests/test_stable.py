@@ -12,9 +12,9 @@ Six layers:
   3. decode feasibility — P @ W hits the exact-reconstruction target
      (B_F . E = I_m) on every sampled responder set of each family;
   4. full-step integration — every family rides the real jitted
-     ``make_coded_train_step`` on gather/a2a, packed and per-leaf wires
-     agree *bitwise*, and the pipelined fill+drain path reproduces the
-     synchronous step bit for bit;
+     ``make_coded_train_step`` on gather/a2a, a dropped worker still
+     decodes to the uncoded psum step's update, and the pipelined
+     fill+drain path reproduces the synchronous step bit for bit;
   5. planner/trainer seam — ``rank_plans(stable_options=, max_cond=)``
      admits a candidate iff its certificate clears the ceiling, the gate
      also covers the uniform family, and the trainer materialises the
@@ -288,7 +288,7 @@ def _linear_setup():
     return cfg, mesh, opt, batch, params
 
 
-def _run_step(code, schedule, stragglers, packed=True):
+def _run_step(code, schedule, stragglers):
     import jax
     import jax.numpy as jnp
 
@@ -299,7 +299,7 @@ def _run_step(code, schedule, stragglers, packed=True):
     cfg, mesh, opt, batch, params = _linear_setup()
     arts = make_coded_train_step(
         cfg, code, mesh, opt,
-        spec=coding.SchemeSpec(schedule=schedule, packed=packed))
+        spec=coding.SchemeSpec(schedule=schedule))
     placed = jax.tree.map(jnp.asarray, CodedBatcher(code).place(batch))
     fn = arts.compiled(placed)
     inp = arts.step_inputs(stragglers)
@@ -326,15 +326,15 @@ def test_stable_step_full_response_matches_uncoded(code, schedule):
 @pytest.mark.parametrize("code", STABLE_CODES, ids=_IDS)
 @pytest.mark.parametrize("schedule", ["gather", "a2a"])
 def test_stable_packed_vs_per_leaf_bitwise(code, schedule):
-    """The packed bucketed wire and the per-leaf collectives produce the
-    *bitwise identical* update for every stable family — same straggler
-    pattern, both schedules, params and optimizer state alike."""
-    a, oa, ma = _run_step(code, schedule, (2,), packed=True)
-    b, ob, mb = _run_step(code, schedule, (2,), packed=False)
-    assert _max_diff(a, b) == 0.0
-    assert _max_diff(oa, ob) == 0.0
-    assert float(np.asarray(ma["loss"]).ravel()[0]) == \
-        float(np.asarray(mb["loss"]).ravel()[0])
+    """With worker 2 dropped, the packed wire decodes every stable family to
+    the uncoded psum step's update — both schedules, params, optimizer
+    state and loss alike (``tests/test_coded_train.py``'s tolerances)."""
+    a, oa, ma = _run_step(code, schedule, (2,))
+    b, ob, mb = _run_step(make_code(N, 1, 0, 1), "psum", ())
+    assert _max_diff(a, b) < 5e-4
+    assert _max_diff(oa, ob) < 5e-4
+    assert abs(float(np.asarray(ma["loss"]).ravel()[0])
+               - float(np.asarray(mb["loss"]).ravel()[0])) < 1e-4
 
 
 @pytest.mark.parametrize("code", STABLE_CODES, ids=_IDS)
@@ -443,7 +443,7 @@ def test_trainer_applies_stable_plan():
 
     def mk(family, d, s, m, n0=None, cond=50.0):
         return Plan(family=family, d=d, s=s, m=m, k=N, loads=(d,) * N,
-                    schedule="gather", packed=True, predicted_wait_s=0.0,
+                    schedule="gather", predicted_wait_s=0.0,
                     predicted_step_s=0.0, predicted_total_s=0.0,
                     cond_bound=cond, n0=n0)
 

@@ -11,8 +11,7 @@ coded step the way a chip run would, at no chip time:
   published widths (d_model 2048, d_ff 6144), cut to 4 layers and 18992
   vocabulary rows, under code (4, 3, 1, 2): each coded leaf's encode, the
   accumulating encode into every slot of every ``(rows, lanes)`` wire
-  bucket, the decode of each whole bucket and of the widest 3-D leaf, and
-  the fused decode-and-apply of a bucket;
+  bucket, and the decode of each whole bucket and of the widest 3-D leaf;
 - the whole one-chip step of ``chip_smoke.py`` (code (1, 1, 0, 1), AdamW,
   4 x 2048 tokens) on the compiled kernels, which must fit the chip and
   hold no relayout of the coded gradient between backward and AdamW;
@@ -44,8 +43,7 @@ from repro.coding.layout import leaf_to_groups, to_plane_view
 from repro.configs import get_config
 from repro.core import make_code
 from repro.data import CodedBatcher, make_synthetic_batch
-from repro.kernels import (coded_decode, coded_decode_apply, coded_encode,
-                           coded_encode_acc)
+from repro.kernels import coded_decode, coded_encode, coded_encode_acc
 from repro.models import api as model_api, common as cm
 from repro.optim import get_optimizer
 from repro.train import sharding
@@ -126,7 +124,7 @@ def _plans(codec, device):
 
 def _coded_leaves(device):
     """(name, canonical (d, m, A[, B]) encode shape, LeafPlan) of every
-    distinct coded leaf of the per-leaf step."""
+    distinct coded leaf, each on its own plane."""
     codec = coding.make_codec(CODE, schedule="a2a", backend="ref")
     pshapes, plans = _plans(codec, device)
     seen, out = set(), []
@@ -153,8 +151,8 @@ def _buckets(device, codec_schedule="gather"):
 
 
 def test_encode_every_coded_leaf(one_chip, no_persistent_cache):
-    """coded_encode (2-D and 3-D operands) at every coded leaf of the
-    per-leaf step, including a narrow tail on the flat lift."""
+    """coded_encode (2-D and 3-D operands) at every coded leaf, each on
+    its own plane, including a narrow tail on the flat lift."""
     leaves = _coded_leaves(next(iter(one_chip.device_set)))
     assert any(len(G) == 3 for _, G, _ in leaves)
     assert any(len(G) == 4 for _, G, _ in leaves)
@@ -203,25 +201,12 @@ def test_decode_full_wire_bucket(one_chip, no_persistent_cache, schedule):
 
 
 def test_decode_widest_3d_leaf(one_chip, no_persistent_cache):
-    """coded_decode on the per-leaf path's widest (n, A, B) stack."""
+    """coded_decode on the widest 3-D leaf's own (n, A, B) stack."""
     _, G, _ = max((x for x in _coded_leaves(next(iter(one_chip.device_set)))
                    if len(x[1]) == 4), key=lambda x: np.prod(x[1]))
     _compile_kernel("coded_decode", lambda f, w: coded_decode(f, w),
                     _sds((CODE.n,) + G[2:], jnp.float32, one_chip),
                     _sds((CODE.n, CODE.m), jnp.float32, one_chip))
-
-
-def test_decode_apply_full_bucket(one_chip, no_persistent_cache):
-    """coded_decode_apply (the fused decode + SGD-momentum apply) on the
-    widest bucket, its sum-of-squares partials written as vector blocks."""
-    b = _buckets(next(iter(one_chip.device_set)))[0]
-    v = _sds((CODE.m,) + b.shape, jnp.float32, one_chip)
-    _compile_kernel(
-        "coded_decode_apply",
-        lambda f, w, p, mu: coded_decode_apply(f, w, p, mu, lr=0.1,
-                                               momentum=0.9, scale=0.25),
-        _sds((CODE.n,) + b.shape, jnp.float32, one_chip),
-        _sds((CODE.n, CODE.m), jnp.float32, one_chip), v, v)
 
 
 @pytest.fixture(scope="module")

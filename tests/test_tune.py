@@ -65,7 +65,7 @@ def test_fit_normalises_across_mixed_schemes():
         d, s, m = [(4, 2, 2), (3, 1, 2), (1, 0, 1)][t % 3]
         wt = sampler.draw((d,) * 4, 4, m)
         records.append(record_from_times(
-            t, _FakeCode(4, d, s, m), "gather", True, wt))
+            t, _FakeCode(4, d, s, m), "gather", wt))
     fit = fit_runtime_params(records)
     assert abs(fit.params.t2 - params.t2) / params.t2 < 0.10
     assert abs(fit.params.t1 - params.t1) / params.t1 < 0.20
@@ -80,7 +80,7 @@ def test_fit_estimates_speed_vector():
     for t in range(500):
         wt = sampler.draw((3,) * 4, 4, 2)
         records.append(record_from_times(
-            t, _FakeCode(4, 3, 1, 2), "gather", True, wt))
+            t, _FakeCode(4, 3, 1, 2), "gather", wt))
     fit = fit_runtime_params(records)
     rel = speeds / speeds.mean()
     assert np.allclose(fit.speeds, rel, rtol=0.15)
@@ -95,7 +95,7 @@ def test_crosscheck_agrees_for_exact_fit():
     for t in range(1500):
         wt = sampler.draw((4,) * 4, 4, 2)
         records.append(record_from_times(
-            t, _FakeCode(4, 4, 2, 2), "gather", True, wt))
+            t, _FakeCode(4, 4, 2, 2), "gather", wt))
     fit = FitResult(params=params, speeds=np.ones(4), n_steps=0, n_samples=0)
     assert crosscheck_waits(fit, records, npts=30_000) < 0.05
 
@@ -174,18 +174,18 @@ def test_planner_pipelined_candidates_use_overlapped_model():
 
 def test_step_cost_book_keys_on_pipelined():
     """A pipelined steady-state measurement must not calibrate the sync
-    twin (and vice versa): the book keys per (schedule, packed, pipelined)."""
+    twin (and vice versa): the book keys per (schedule, pipelined)."""
     recs = [
         StepRecord(step=0, d=3, s=1, m=2, k=4, loads=(3,) * 4,
-                   schedule="gather", packed=True, compute_s=np.zeros(4),
+                   schedule="gather", compute_s=np.zeros(4),
                    comm_s=np.zeros(4), measured_step_s=3.0),
         StepRecord(step=1, d=3, s=1, m=2, k=4, loads=(3,) * 4,
-                   schedule="gather", packed=True, compute_s=np.zeros(4),
+                   schedule="gather", compute_s=np.zeros(4),
                    comm_s=np.zeros(4), measured_step_s=1.0, pipelined=True),
     ]
     book = step_cost_book(recs)
-    assert book.cost(3, 4, (3,) * 4, "gather", True) == pytest.approx(3.0)
-    assert book.cost(3, 4, (3,) * 4, "gather", True,
+    assert book.cost(3, 4, (3,) * 4, "gather") == pytest.approx(3.0)
+    assert book.cost(3, 4, (3,) * 4, "gather",
                      pipelined=True) == pytest.approx(1.0)
 
 
@@ -195,10 +195,10 @@ def test_planner_step_cost_calibration_breaks_ties():
                       n_samples=0)
     recs = [
         StepRecord(step=0, d=3, s=1, m=2, k=8, loads=(3,) * 8,
-                   schedule="gather", packed=True, compute_s=np.zeros(8),
+                   schedule="gather", compute_s=np.zeros(8),
                    comm_s=np.zeros(8), measured_step_s=5.0),
         StepRecord(step=1, d=3, s=1, m=2, k=8, loads=(3,) * 8,
-                   schedule="a2a", packed=True, compute_s=np.zeros(8),
+                   schedule="a2a", compute_s=np.zeros(8),
                    comm_s=np.zeros(8), measured_step_s=0.010),
     ]
     ranked = rank_plans(exact, schedules=("gather", "a2a"), npts=8_000,
@@ -215,22 +215,22 @@ def test_step_cost_book_exact_and_load_scaled_fallback():
                                           ("a2a", 2, 0.0)]):
         recs.append(StepRecord(
             step=i, d=d, s=1, m=1, k=4, loads=(d,) * 4, schedule=sched,
-            packed=True, compute_s=np.zeros(4), comm_s=np.zeros(4),
+            compute_s=np.zeros(4), comm_s=np.zeros(4),
             measured_step_s=wall))
     book = step_cost_book(recs)
     assert len(book) == 2   # zero-wall record contributes nothing new
     # exact scheme hit: the mean of its own measurements
-    assert book.cost(3, 4, (3,) * 4, "gather", True) == pytest.approx(2.0)
-    assert book.cost(2, 4, (2,) * 4, "a2a", True) == pytest.approx(2.0)
+    assert book.cost(3, 4, (3,) * 4, "gather") == pytest.approx(2.0)
+    assert book.cost(2, 4, (2,) * 4, "a2a") == pytest.approx(2.0)
     # unseen d, known config: per-load mean (2.0/3) scaled by the new d —
     # a d=1 candidate is NOT charged the d=3 step's wall-clock
-    assert book.cost(1, 4, (1,) * 4, "gather", True) == pytest.approx(2 / 3)
+    assert book.cost(1, 4, (1,) * 4, "gather") == pytest.approx(2 / 3)
     # unseen config: global per-load mean ((1/3 + 3/3 + 2/2) / 3) * d
-    assert book.cost(1, 4, (1,) * 4, "psum", True) == pytest.approx(
+    assert book.cost(1, 4, (1,) * 4, "psum") == pytest.approx(
         (1 / 3 + 1.0 + 1.0) / 3)
     # empty book: free
     from repro.tune import StepCostBook
-    assert StepCostBook().cost(4, 4, (4,) * 4, "gather", True) == 0.0
+    assert StepCostBook().cost(4, 4, (4,) * 4, "gather") == 0.0
 
 
 # ------------------------------------------------------- telemetry plumbing
@@ -258,7 +258,7 @@ def test_telemetry_log_capacity_and_window():
     for t in range(25):
         log.append(StepRecord(
             step=t, d=3, s=1, m=2, k=4, loads=(3,) * 4, schedule="gather",
-            packed=True, compute_s=np.zeros(4), comm_s=np.zeros(4)))
+            compute_s=np.zeros(4), comm_s=np.zeros(4)))
     assert len(log) == 10
     assert [r.step for r in log.window(3)] == [22, 23, 24]
     assert log.records[0].step == 15
@@ -281,7 +281,7 @@ def test_drifting_sampler_phases():
 # ------------------------------------------------------------ control loop
 def _mk_plan(d, s, m, schedule="gather"):
     return Plan(family="uniform", d=d, s=s, m=m, k=4, loads=(d,) * 4,
-                schedule=schedule, packed=True, predicted_wait_s=0.0,
+                schedule=schedule, predicted_wait_s=0.0,
                 predicted_step_s=0.0, predicted_total_s=0.0)
 
 
@@ -296,7 +296,7 @@ def test_autotuner_holds_then_switches_under_drift():
     switched_at = None
     for t in range(40):
         wt = drift(t, code)
-        tuner.record(record_from_times(t, code, "gather", True, wt))
+        tuner.record(record_from_times(t, code, "gather", wt))
         new = tuner.maybe_replan(t)
         if new is not None:
             switched_at = t
@@ -321,7 +321,7 @@ def test_autotuner_rejects_implausible_fit():
     sampler = ShiftedExpSampler(params, seed=1)
     code = _FakeCode(4, 4, 2, 2)
     for t in range(12):
-        tuner.record(record_from_times(t, code, "gather", True,
+        tuner.record(record_from_times(t, code, "gather",
                                        sampler(t, code)))
         assert tuner.maybe_replan(t) is None
     rejected = [e for e in tuner.events if e.get("rejected_fit")]
@@ -345,7 +345,7 @@ def test_autotuner_rescorees_current_outside_search_space():
     sampler = ShiftedExpSampler(params, seed=2)
     code = _FakeCode(4, 4, 2, 2)
     for t in range(8):
-        tuner.record(record_from_times(t, code, "gather", True,
+        tuner.record(record_from_times(t, code, "gather",
                                        sampler(t, code)))
         assert tuner.maybe_replan(t) is None
     assert tuner.current.schedule == "a2a"   # held
@@ -361,11 +361,11 @@ def test_autotuner_not_due_before_min_samples():
         RuntimeParams(n=4, lambda1=1.0, lambda2=1.0, t1=1.0, t2=1.0), seed=0)
     code = _FakeCode(4, 3, 1, 2)
     for t in range(5):
-        tuner.record(record_from_times(t, code, "gather", True,
+        tuner.record(record_from_times(t, code, "gather",
                                        sampler(t, code)))
         assert not tuner.due()
         assert tuner.maybe_replan(t) is None
-    tuner.record(record_from_times(5, code, "gather", True,
+    tuner.record(record_from_times(5, code, "gather",
                                    sampler(5, code)))
     assert tuner.due()
 
@@ -485,4 +485,4 @@ def test_trainer_autotune_partial_interop():
         assert "decode_err_bound" in m
         assert np.isfinite(m["decode_err_bound"])
     assert any(e["switched"] for e in tr.autotune_events)
-    assert all(k[3] is True for k in tr._arts_cache)  # partial flag in key
+    assert all(k[2] is True for k in tr._arts_cache)  # partial flag in key
